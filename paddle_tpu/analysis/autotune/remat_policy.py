@@ -161,4 +161,6 @@ def _total_compute(res) -> int:
 
 def plan_remat_lowered(lowered, **kw) -> RematPlan:
     """Compile and plan against the optimized module text."""
-    return plan_remat(lowered.compile().as_text(), **kw)
+    from ..liveness import compile_for_memory
+
+    return plan_remat(compile_for_memory(lowered).as_text(), **kw)

@@ -30,7 +30,7 @@ import re
 from dataclasses import dataclass
 from typing import Dict, Iterable, List, Mapping, Optional, Sequence, Tuple, Union
 
-from jax import core as jax_core
+from jax.extend import core as jax_core
 
 from .findings import Report
 from .hlo_ir import BRANCHES_RE as _BRANCHES_RE

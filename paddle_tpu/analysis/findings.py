@@ -1,11 +1,11 @@
-"""Findings taxonomy for the sharding & communication static analyzer.
+"""Findings catalogue for the sharding & communication static analyzer.
 
 Every lint (jaxpr level or HLO level) reports through a common ``Finding``
 record so downstream consumers — ``bench.py --lint``, ``scripts/lint_gate.sh``,
 tests — can rank, count, and diff results without caring which level produced
 them.
 
-Finding codes (the stable taxonomy; gates key on these strings):
+Finding codes (the stable catalogue; gates key on these strings):
 
 ========================  =====  ========================================
 code                      level  meaning
@@ -144,7 +144,7 @@ SEVERITY_RANK = {"high": 0, "medium": 1, "low": 2}
 
 @dataclass
 class Finding:
-    code: str                 # taxonomy code, see module docstring
+    code: str                 # catalogue code, see module docstring
     severity: str             # "high" | "medium" | "low"
     message: str              # one-line human description
     where: str = ""           # arg path / HLO instruction name
@@ -188,7 +188,7 @@ class Report:
             key=lambda f: (SEVERITY_RANK.get(f.severity, 3), -f.bytes, f.code))
 
     def counts(self) -> Dict[str, int]:
-        """Findings per taxonomy code (what the lint gate diffs)."""
+        """Findings per catalogue code (what the lint gate diffs)."""
         out: Dict[str, int] = {}
         for f in self.findings:
             out[f.code] = out.get(f.code, 0) + 1

@@ -22,7 +22,7 @@ their audited byte credit; ``plan.apply()`` is a context manager that flips
 the ``kernels.emit`` activation table for the duration of a fused run
 (what ``bench.py --fuse`` and the autotuner's ``fuse=auto`` axis use).
 
-Finding codes (the ``fuse-*`` rows of the taxonomy):
+Finding codes (the ``fuse-*`` rows of the catalogue):
 
 ========================== ======================================================
 ``fuse-unmatched-site``    a flagged candidate has no emitter site — the
@@ -129,7 +129,8 @@ def _match_site(cand: Dict, sites: Dict[str, object]) -> Optional[str]:
 
 def plan_transform(audit_or_candidates, *, sites=None, verify: bool = True,
                    interpret: Optional[bool] = None,
-                   admission: bool = True) -> TransformPlan:
+                   admission: bool = True, shapes=None,
+                   device=None) -> TransformPlan:
     """Run the transformer pass over an audit (or its ``pallas_candidates()``
     list) and return the :class:`TransformPlan`.
 
@@ -137,6 +138,11 @@ def plan_transform(audit_or_candidates, *, sites=None, verify: bool = True,
     MLP region matches ``fuse_swiglu_mlp`` — activating the seam substitutes
     all of them), so verification and admission run once per *site* while the
     byte credit accrues per *candidate*.
+
+    ``shapes`` (``emit.llama_site_shapes``) gives the widths the program
+    calls each site with; a site named there is also compiled at those
+    widths for ``device`` (``emit.compile_refusal``), and what the TPU's
+    compiler refuses is a ``fuse-admission-rejected`` in its own words.
     """
     from ..kernels import emit, registry
 
@@ -166,6 +172,18 @@ def plan_transform(audit_or_candidates, *, sites=None, verify: bool = True,
                     where=name,
                     suggestion="site stays on the stock path; fix the "
                                "emission or raise the VMEM budget")
+                code = "fuse-admission-rejected"
+        if code is None and admission and name in (shapes or {}):
+            words = emit.compile_refusal(name, *shapes[name], device=device)
+            if words:
+                plan.report.add(
+                    "fuse-admission-rejected", "high",
+                    f"the TPU compiler refused emitted kernel(s) for site "
+                    f"{name} at the program's widths: {words}",
+                    where=name,
+                    suggestion="site stays on the stock path; the emitted "
+                               "backward keeps every operand in one VMEM "
+                               "block")
                 code = "fuse-admission-rejected"
         if code is None and verify:
             vrep = emit.verify_site(name, interpret=(

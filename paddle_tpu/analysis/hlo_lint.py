@@ -82,6 +82,14 @@ class HloModuleInfo:
                 out.append((op, ins))
         return out
 
+    def _is_layout_fusion(self, ins: HloInstr) -> bool:
+        """A one-operand fusion whose output has its operand's byte size:
+        XLA wraps the layout ``copy`` feeding a custom call in one."""
+        if ins.opcode != "fusion" or len(ins.operands) != 1:
+            return False
+        src = self.instrs.get(ins.operands[0])
+        return src is not None and src.bytes_out == ins.bytes_out
+
     def ancestors(self, name: str, through: Iterable[str] = _PASS_OPS,
                   limit: int = 64) -> List[HloInstr]:
         """Instructions feeding ``name`` through pass-through ops only."""
@@ -96,7 +104,7 @@ class HloModuleInfo:
             seen.add(op_name)
             ins = self.instrs[op_name]
             found.append(ins)
-            if ins.opcode in through:
+            if ins.opcode in through or self._is_layout_fusion(ins):
                 frontier.extend(ins.operands)
         return found
 

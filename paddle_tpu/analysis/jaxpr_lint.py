@@ -25,7 +25,7 @@ from typing import Any, Iterator, List, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import core as jax_core
+from jax.extend import core as jax_core
 
 from .findings import Report
 

@@ -411,10 +411,25 @@ def xla_peak_bytes(compiled) -> Optional[Tuple[int, object]]:
     return int(peak), ma
 
 
+def compile_for_memory(lowered):
+    """``lowered.compile()`` under a memory-minimizing schedule.
+
+    XLA's CPU backend schedules for concurrency by default and hoists a
+    rematerialized forward next to the original, so remat frees nothing
+    there; the memory analyses state what a scheduler that minimizes
+    memory, like the TPU's, does."""
+    import jax
+
+    if jax.default_backend() != "cpu":
+        return lowered.compile()
+    return lowered.compile(compiler_options={
+        "xla_cpu_enable_concurrency_optimized_scheduler": False})
+
+
 def analyze_lowered(lowered) -> Tuple[LivenessResult, Optional[int]]:
     """Compile, sweep the optimized text, and return
     ``(LivenessResult, xla_peak_or_None)``."""
-    compiled = lowered.compile()
+    compiled = compile_for_memory(lowered)
     res = analyze_text(compiled.as_text())
     xp = xla_peak_bytes(compiled)
     return res, (xp[0] if xp else None)

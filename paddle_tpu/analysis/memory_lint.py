@@ -1,6 +1,6 @@
 """Static HBM lint on top of the liveness sweep (``analysis/liveness.py``).
 
-Finding codes (see ``findings.py`` for the full taxonomy):
+Finding codes (see ``findings.py`` for the full catalogue):
 
 * ``mem-over-budget`` — modeled peak-resident bytes exceed the declared
   per-device HBM budget.  The check the serving tier and auto-parallel

@@ -11,7 +11,7 @@ kernel runs and corrupts output instead of erroring.  This module proves or
 refutes each invariant **without executing on hardware**, from the traced
 ``pallas_call`` equations alone.
 
-Checks and their taxonomy codes (see :mod:`.findings` for the report API):
+Checks and their catalogue codes (see :mod:`.findings` for the report API):
 
 =========================  ================================================
 ``krn-write-race``         two grid points that differ along a ``parallel``
@@ -75,6 +75,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 from jax import core as jax_core
+from jax.extend import core as jex_core
 
 from .findings import Report
 
@@ -232,7 +233,7 @@ def _affine_injective(resolution) -> bool:
 
 def _find_pallas_eqns(jaxpr, out: list) -> list:
     """Recursively collect pallas_call eqns through pjit/custom_vjp/etc."""
-    if isinstance(jaxpr, jax_core.ClosedJaxpr):
+    if isinstance(jaxpr, jex_core.ClosedJaxpr):
         jaxpr = jaxpr.jaxpr
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "pallas_call":
@@ -241,15 +242,14 @@ def _find_pallas_eqns(jaxpr, out: list) -> list:
         for sub in eqn.params.values():
             subs = sub if isinstance(sub, (tuple, list)) else (sub,)
             for s in subs:
-                if isinstance(s, (jax_core.Jaxpr, jax_core.ClosedJaxpr)):
+                if isinstance(s, (jex_core.Jaxpr, jex_core.ClosedJaxpr)):
                     _find_pallas_eqns(s, out)
     return out
 
 
 def _int_block_shape(block_shape) -> Tuple[int, ...]:
-    # squeezed dims appear as a sentinel ("mapped") — they consume one index
-    # and contribute one element
-    return tuple(b if isinstance(b, int) else 1 for b in block_shape)
+    # a squeezed dim consumes one index and contributes one element
+    return tuple(int(getattr(b, "block_size", 1)) for b in block_shape)
 
 
 def _classify_index_jaxpr(cj, n_axes: int, grid: Tuple[int, ...]):
@@ -265,7 +265,7 @@ def _classify_index_jaxpr(cj, n_axes: int, grid: Tuple[int, ...]):
     if not jx.eqns:
         dims = []
         for ov in jx.outvars:
-            if isinstance(ov, jax_core.Literal):
+            if isinstance(ov, jex_core.Literal):
                 dims.append(("const", int(ov.val)))
             elif ov in axis_vars:
                 dims.append(("axis", axis_vars.index(ov)))
@@ -299,7 +299,7 @@ def _scratch_space(aval) -> str:
 def _union_taint(taint: dict, invars) -> frozenset:
     out: frozenset = frozenset()
     for v in invars:
-        if isinstance(v, jax_core.Var):
+        if isinstance(v, jex_core.Var):
             out = out | taint.get(v, frozenset())
     return out
 
@@ -335,7 +335,7 @@ def _carried_scratch(kernel_jaxpr, scratch_vars: list,
     def union_derives(dmap, invars):
         out: frozenset = frozenset()
         for v in invars:
-            if isinstance(v, jax_core.Var):
+            if isinstance(v, jex_core.Var):
                 out = out | dmap.get(v, frozenset())
         return out
 
@@ -374,17 +374,17 @@ def _carried_scratch(kernel_jaxpr, scratch_vars: list,
                 if not isinstance(ov, jax_core.DropVar):
                     taint[ov] = t
         if prim in ("get", "swap"):
-            ident = {v: v for v in eqn.invars if isinstance(v, jax_core.Var)}
+            ident = {v: v for v in eqn.invars if isinstance(v, jex_core.Var)}
             scan_stmt(eqn, pos, derives, ident, None)
         elif prim == "cond":
             pred = eqn.invars[0]
-            g = taint.get(pred, frozenset()) if isinstance(pred, jax_core.Var) \
+            g = taint.get(pred, frozenset()) if isinstance(pred, jex_core.Var) \
                 else frozenset()
             for branch in eqn.params.get("branches", ()):
-                bj = branch.jaxpr if isinstance(branch, jax_core.ClosedJaxpr) \
+                bj = branch.jaxpr if isinstance(branch, jex_core.ClosedJaxpr) \
                     else branch
                 remap = {bv: ov for bv, ov in zip(bj.invars, eqn.invars[1:])
-                         if isinstance(ov, jax_core.Var)}
+                         if isinstance(ov, jex_core.Var)}
                 bmap = {bv: derives.get(ov, frozenset())
                         for bv, ov in remap.items()}
                 for be in bj.eqns:
@@ -423,11 +423,10 @@ def spec_from_eqn(eqn, name: str = "") -> KernelSpec:
     n_scratch = int(getattr(gm, "num_scratch_operands", 0))
 
     if not name:
-        nsi = params.get("name_and_src_info")
-        name = getattr(nsi, "name", None) or str(nsi) or "pallas_call"
+        name = params.get("name") or "pallas_call"
 
     def block_use(bm, label):
-        sds = bm.array_shape_dtype
+        sds = bm.array_aval
         space = str(getattr(bm.block_aval, "memory_space", "")).lower()
         if "any" in space:
             return BlockUse(tuple(sds.shape), sds.dtype,
@@ -443,7 +442,7 @@ def spec_from_eqn(eqn, name: str = "") -> KernelSpec:
                for i, bm in enumerate(bms[n_in:n_in + n_out])]
 
     kj = params.get("jaxpr")
-    if isinstance(kj, jax_core.ClosedJaxpr):
+    if isinstance(kj, jex_core.ClosedJaxpr):
         kj = kj.jaxpr
     scratch: List[ScratchUse] = []
     carried: List[Tuple[int, frozenset]] = []
@@ -463,8 +462,7 @@ def spec_from_eqn(eqn, name: str = "") -> KernelSpec:
 
     sem = None
     cp = params.get("compiler_params") or {}
-    mosaic = cp.get("mosaic", cp) if isinstance(cp, dict) else {}
-    ds = mosaic.get("dimension_semantics") if isinstance(mosaic, dict) else None
+    ds = getattr(cp.get("mosaic_tpu"), "dimension_semantics", None)
     if ds is not None:
         sem = tuple(str(s) for s in ds)
 
@@ -748,17 +746,11 @@ def check_kernel(fn, *args, vmem_budget: Optional[int] = None,
 
     The public entry point (also re-exported as ``analysis.check_kernel``):
     traces abstractly — nothing executes, so it runs on CPU against kernels
-    that only compile for TPU.  The report's meta carries the kernel count
-    and the per-kernel modeled VMEM bytes."""
+    that only compile for TPU.  A kernel that cannot be traced raises: a
+    trace failure is a broken kernel, not a finding.  The report's meta
+    carries the kernel count and the per-kernel modeled VMEM bytes."""
     rep = Report()
-    try:
-        specs = extract_kernel_specs(fn, *args, **kwargs)
-    except Exception as e:
-        rep.meta["trace_error"] = repr(e)
-        rep.add("krn-dynamic-index", "low",
-                f"could not trace kernel: {e!r}",
-                where=getattr(fn, "__name__", type(fn).__name__))
-        return rep
+    specs = extract_kernel_specs(fn, *args, **kwargs)
     vm: Dict[str, int] = {}
     for spec in specs:
         r = lint_kernel_spec(spec, vmem_budget=vmem_budget)

@@ -169,7 +169,7 @@ class ReshardPlan:
                 f"peak={self.peak_bytes}B bound={self.bound_bytes}B {tag}")
 
     def findings(self):
-        """Report the plan through the analyzer's findings taxonomy.
+        """Report the plan through the analyzer's findings catalogue.
 
         An unbounded plan (all-gather fallback, or a phase program whose
         peak broke the 2x-shard bound) becomes a ``reshard-unbounded``

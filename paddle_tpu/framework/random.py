@@ -20,11 +20,10 @@ class Generator:
     """A splittable PRNG stream.
 
     Key construction is lazy: ``jax.random.key`` initializes the JAX backend, and
-    ``import paddle_tpu`` must never do that (a wedged accelerator plugin would
-    hang every import, including the pure process-management launcher). The key is
-    built on first use instead. Mirrors the fake-device CI philosophy of the
-    reference (``paddle/phi/backends/custom/fake_cpu_device.h``): framework code
-    paths must not require live hardware.
+    ``import paddle_tpu`` must never do that — a chip belongs to the first process
+    that touches JAX, so an import that did would make the pure process-management
+    launcher take the chip from the child it starts. The key is built on first use
+    instead.
     """
 
     def __init__(self, seed: int = 0):

@@ -170,7 +170,13 @@ def _worker_main(channel_name: str, spec_bytes: bytes, control_name=None):
     With ``control_name`` (persistent_workers): instead of one baked batch
     plan, the worker LOOPS — each epoch's plan arrives as a pickled record
     on the control channel; closing the control channel shuts it down."""
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")  # never grab the TPU
+    # a chip belongs to one process: the worker never takes it, whatever
+    # JAX_PLATFORMS it inherits.  Unpickling this function has already
+    # imported jax, which read the environment then — so pin the config too
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    import jax
+
+    jax.config.update("jax_platforms", "cpu")
     spec = pickle.loads(spec_bytes)
     ch = _Channel(channel_name)
     ctrl = _Channel(control_name) if control_name else None

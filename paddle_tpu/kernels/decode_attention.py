@@ -267,8 +267,8 @@ def _pallas_decode_fused(q, k_cache, v_cache, lengths, sm_scale: float,
             grid=(B,),
             in_specs=[
                 pl.BlockSpec((1, hk, rep, d), lambda b, *_: (b, 0, 0, 0)),
-                pl.BlockSpec(memory_space=pltpu.ANY),   # k cache stays in HBM
-                pl.BlockSpec(memory_space=pltpu.ANY),   # v cache stays in HBM
+                pl.BlockSpec(memory_space=pl.ANY),   # k cache stays in HBM
+                pl.BlockSpec(memory_space=pl.ANY),   # v cache stays in HBM
             ],
             out_specs=pl.BlockSpec((1, hk, rep, d), lambda b, *_: (b, 0, 0, 0)),
             scratch_shapes=[
@@ -470,8 +470,8 @@ def _pallas_paged_decode(q, k_pool, v_pool, block_table, lengths, sm_scale,
             grid=(B, hk),
             in_specs=[
                 pl.BlockSpec((1, 1, rep, d), lambda b, g, *_: (b, g, 0, 0)),
-                pl.BlockSpec(memory_space=pltpu.ANY),   # k pool stays in HBM
-                pl.BlockSpec(memory_space=pltpu.ANY),   # v pool stays in HBM
+                pl.BlockSpec(memory_space=pl.ANY),   # k pool stays in HBM
+                pl.BlockSpec(memory_space=pl.ANY),   # v pool stays in HBM
             ],
             out_specs=pl.BlockSpec((1, 1, rep, d), lambda b, g, *_: (b, g, 0, 0)),
             scratch_shapes=[
@@ -554,8 +554,8 @@ def _pallas_paged_decode_fused(q, k_pool, v_pool, block_table, lengths,
             grid=(B,),
             in_specs=[
                 pl.BlockSpec((1, hk, rep, d), lambda b, *_: (b, 0, 0, 0)),
-                pl.BlockSpec(memory_space=pltpu.ANY),
-                pl.BlockSpec(memory_space=pltpu.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
             ],
             out_specs=pl.BlockSpec((1, hk, rep, d), lambda b, *_: (b, 0, 0, 0)),
             scratch_shapes=[
@@ -633,8 +633,8 @@ def paged_chunk_attention(q, k_pool, v_pool, block_table, ctx_lengths,
     context and chunk through one table walk.  Chunk token ``j`` attends
     cache positions ``<= ctx_lengths[b] + j`` — pad-tail rows past the true
     chunk length only ever attend positions the caller later masks or
-    overwrites.  Gather-based (XLA) path; a streamed Pallas variant is a
-    RECAPTURE item."""
+    overwrites.  Gather-based (XLA) path; there is no streamed Pallas
+    variant yet."""
     nb, hk, bs, d = k_pool.shape
     B, S, h, _ = q.shape
     if sm_scale is None:

@@ -46,7 +46,7 @@ from .rms_norm import _largest_divisor
 
 __all__ = [
     "FusionSite", "SITES", "active", "activate", "make_fused", "verify_site",
-    "verified_activation",
+    "verified_activation", "compile_refusal", "llama_site_shapes",
 ]
 
 _FUSE_PRESETS = ("tiny", "small", "base", "longctx")
@@ -60,9 +60,9 @@ def _race_injected() -> bool:
 def _resolve_interpret(interpret: Optional[bool]) -> bool:
     if interpret is not None:
         return bool(interpret)
-    from . import use_pallas
+    from ..framework import flags
 
-    return not use_pallas()  # no TPU: run emitted kernels via the interpreter
+    return bool(flags.get_flag("pallas_interpret"))
 
 
 # ---------------------------------------------------------------------------
@@ -116,6 +116,7 @@ def _row_block_call(ref, row_args, full_args, n_row_outs, interpret,
     primitive sequence, fused.  Row-independence of every site's math makes
     the blocked result bit-identical to the unblocked reference."""
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     n = row_args[0].shape[0]
     br = _largest_divisor(n, block_cap)
@@ -153,8 +154,8 @@ def _row_block_call(ref, row_args, full_args, n_row_outs, interpret,
         # registry admission rail must refuse this before the first call
         out_specs[0] = pl.BlockSpec((br,) + abstract[0].shape[1:],
                                     lambda i: (0, 0))
-        kwargs["compiler_params"] = dict(
-            mosaic=dict(dimension_semantics=("parallel",)))
+        kwargs["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=("parallel",))
     outs = pl.pallas_call(
         kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
         out_shape=out_shape, interpret=interpret, **kwargs,
@@ -467,11 +468,77 @@ def verify_site(name: str, interpret: bool = True):
     return rep
 
 
-def verified_activation(interpret: Optional[bool] = None) -> Dict[str, Callable]:
+def llama_site_shapes(cfg, rows: int) -> Dict[str, Tuple[tuple, dict]]:
+    """``site -> (arg shapes, statics)`` at the widths a Llama config calls
+    each seam with (``rows`` = batch x seq): what :func:`compile_refusal`
+    asks the chip's compiler about."""
+    act, par = jnp.dtype(cfg.dtype), jnp.dtype(cfg.pdtype)
+    H, I, V = cfg.hidden_size, cfg.intermediate_size, cfg.vocab_size
+    eps = cfg.rms_norm_eps
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    tied = cfg.tie_word_embeddings
+    return {
+        "fuse_swiglu_mlp": (
+            (sds((rows, H), act), sds((H, 2 * I), par), sds((I, H), par)),
+            dict(intermediate_size=I)),
+        "fuse_add_rms_norm": (
+            (sds((rows, H), act), sds((rows, H), act), sds((H,), par)),
+            dict(epsilon=eps)),
+        "fuse_rms_norm_head": (
+            (sds((rows, H), act), sds((H,), par),
+             sds((V, H) if tied else (H, V), par)),
+            dict(epsilon=eps, transpose=tied)),
+    }
+
+
+def compile_refusal(name: str, args, static, device=None) -> Optional[str]:
+    """Ask the TPU's compiler for the site's forward and backward kernels at
+    the shapes the program will call them with.  Returns the compiler's
+    words when it refuses either, else None.
+
+    ``device`` is a TPU device, attached or described
+    (``jax.experimental.topologies``); default ``jax.devices()[0]``.  The
+    backward holds every operand in one VMEM block, so a site that passes
+    the registry's lint at its example shapes can still be refused at real
+    widths — nothing but the compiler can say.  On a device that is not a
+    TPU there is nothing to ask."""
+    from jax.sharding import SingleDeviceSharding
+
+    site = SITES[name]
+    device = device if device is not None else jax.devices()[0]
+    if device.platform != "tpu":
+        return None
+    on = SingleDeviceSharding(device)
+
+    def placed(s):
+        return jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=on)
+
+    args = tuple(placed(a) for a in args)
+    outs = jax.eval_shape(lambda *a: site.ref(*a, **static), *args)
+    cts = tuple(placed(o) for o in
+                (outs if isinstance(outs, tuple) else (outs,)))
+    try:
+        jax.jit(lambda *a: _fwd_call(site, a, False, **static)
+                ).lower(*args).compile()
+        jax.jit(lambda p, c: _bwd_call(site, p, c, False, **static)
+                ).lower(args, cts).compile()
+    except Exception as e:  # the refusal, whatever its type, is the answer
+        words = " ".join(str(e).split())
+        return f"{type(e).__name__}: {words[:400]}"
+    return None
+
+
+def verified_activation(interpret: Optional[bool] = None, shapes=None,
+                        device=None) -> Dict[str, Callable]:
     """Activation table of every site whose emitted kernels pass registry
-    admission AND replay bit-exact (``verify_site``) — what a ``fuse=auto``
-    plan substitutes at run time.  Inadmissible or divergent sites are left
-    on the stock path; the reject-and-report findings for them live in
+    admission AND replay bit-exact (``verify_site``) AND, where ``shapes``
+    (see :func:`llama_site_shapes`) names the site, compile for ``device``
+    at those shapes — what a ``fuse=auto`` plan substitutes at run time.
+    Inadmissible or divergent sites are left on the stock path; the
+    reject-and-report findings for them live in
     ``analysis.fusion_transform.plan_transform``."""
     table: Dict[str, Callable] = {}
     for name in SITES:
@@ -480,7 +547,10 @@ def verified_activation(interpret: Optional[bool] = None) -> Dict[str, Callable]
             registry.admit(name + "_bwd")
         except registry.KernelRejected:
             continue
-        if verify_site(name, interpret=_resolve_interpret(interpret)):
+        if name in (shapes or {}) and compile_refusal(
+                name, *shapes[name], device=device):
+            continue
+        if verify_site(name, interpret=True):
             continue
         table[name] = make_fused(name, interpret=interpret)
     return table

@@ -153,6 +153,7 @@ def _build_injected_write_race():
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     def kernel(x_ref, o_ref):
         o_ref[...] = x_ref[...] * 2.0
@@ -166,8 +167,8 @@ def _build_injected_write_race():
             # parallel, and blocks 1..3 are never written (coverage hole)
             out_specs=pl.BlockSpec((8, 128), lambda i: (0, 0)),
             out_shape=jax.ShapeDtypeStruct((32, 128), jnp.float32),
-            compiler_params=dict(mosaic=dict(
-                dimension_semantics=("parallel",))),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel",)),
         )(x)
 
     return fn, (jax.ShapeDtypeStruct((32, 128), jnp.float32),)
@@ -200,8 +201,8 @@ def _build_injected_parallel_carry():
             scratch_shapes=[pltpu.VMEM((8, 128), jnp.float32)],
             # the scratch carries across axis 1 (reset only at i == 0);
             # declaring that axis parallel is exactly the ssd_scan bug class
-            compiler_params=dict(mosaic=dict(
-                dimension_semantics=("parallel", "parallel"))),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel")),
         )(x)
 
     return fn, (jax.ShapeDtypeStruct((2, 32, 128), jnp.float32),)

@@ -98,8 +98,12 @@ def _largest_divisor(n, cap):
     return 1
 
 
-def rms_norm(x, weight=None, epsilon: float = 1e-6, interpret: bool = False):
-    from . import use_pallas
+def rms_norm(x, weight=None, epsilon: float = 1e-6, interpret: bool = False,
+             shard=None):
+    """``shard``: ``(jax Mesh, PartitionSpec of x)`` where ``x`` is laid out
+    over several devices — the kernel then runs per shard (rows are
+    independent; the last dim must be whole)."""
+    from . import per_shard, use_pallas
 
     kernel_ok = x.shape[-1] % 128 == 0
     if interpret and not kernel_ok:
@@ -108,7 +112,9 @@ def rms_norm(x, weight=None, epsilon: float = 1e-6, interpret: bool = False):
     if (use_pallas() or interpret) and kernel_ok:
         registry.ensure_admitted("rms_norm")
         w = weight if weight is not None else jnp.ones((x.shape[-1],), x.dtype)
-        return _rms_norm_pallas(x, w, epsilon, interpret)
+        return per_shard(
+            lambda x, w: _rms_norm_pallas(x, w, epsilon, interpret),
+            shard, 1, 1)(x, w)
     return _rms_norm_ref(x, weight, epsilon)
 
 

@@ -63,13 +63,14 @@ def ssd_chunk_outputs(s, x, b, c, la):
     decode-from-state parity).
     """
     L = x.shape[0]
-    cum = jnp.cumsum(la)                              # [L], inclusive
     ti = jax.lax.broadcasted_iota(jnp.int32, (L, L), 0)
     si = jax.lax.broadcasted_iota(jnp.int32, (L, L), 1)
     mask = si <= ti
+    cum = _cumsum_col(la, mask)                       # [L, 1], inclusive
+    cum_t = jnp.broadcast_to(cum, (L, L))             # [t, s] = cum[t]
     # log prod_{u=s+1..t} a_u; clamp masked entries BEFORE exp so the upper
     # triangle (positive log-sums) can't overflow into inf*0 = nan grads
-    seg = jnp.where(mask, cum[:, None] - cum[None, :], 0.0)
+    seg = jnp.where(mask, cum_t - cum_t.T, 0.0)
     cb = jax.lax.dot_general(c, b, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)   # [L, L]
     m = jnp.where(mask, cb * jnp.exp(seg), 0.0)
@@ -77,15 +78,27 @@ def ssd_chunk_outputs(s, x, b, c, la):
                             preferred_element_type=jnp.float32)    # [L, P]
     inter = jax.lax.dot_general(c, s, (((1,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-    return y + jnp.exp(cum)[:, None] * inter
+    return y + jnp.exp(cum) * inter
+
+
+def _cumsum_col(la, mask):
+    """Inclusive cumulative sum of ``la`` ([L] or [1, L]) as a column
+    [L, 1], by a masked row sum: Mosaic lowers neither ``cumsum`` nor
+    1-D vectors, and at one chunk the [L, L] pass is noise beside the
+    matmuls."""
+    L = mask.shape[0]
+    row = jnp.broadcast_to(la.reshape(1, L), (L, L))  # [t, s] = la[s]
+    return jnp.sum(jnp.where(mask, row, 0.0), axis=1, keepdims=True)
 
 
 def ssd_chunk_state(s, x, b, la):
     """State after one chunk:  S' = (prod a) S + sum_s (prod_{u>s} a_u) B_s x_s^T."""
-    cum = jnp.cumsum(la)
-    total = cum[-1]
-    w = jnp.exp(total - cum)                          # [L]
-    bw = b * w[:, None]                               # [L, N]
+    L = x.shape[0]
+    ti = jax.lax.broadcasted_iota(jnp.int32, (L, L), 0)
+    si = jax.lax.broadcasted_iota(jnp.int32, (L, L), 1)
+    cum = _cumsum_col(la, si <= ti)                   # [L, 1]
+    total = cum[L - 1:L]                              # [1, 1]
+    bw = b * jnp.exp(total - cum)                     # [L, N]
     ds = jax.lax.dot_general(bw, x, (((0,), (0,)), ((), ())),
                              preferred_element_type=jnp.float32)   # [N, P]
     return jnp.exp(total) * s + ds
@@ -175,7 +188,7 @@ def _ssd_scan_call(x, b, c, la, *, chunk, interpret):
         xc = x_ref[0]
         bc = b_ref[0]
         cc = c_ref[0]
-        lc = la_ref[0]
+        lc = la_ref[0]                                # [1, chunk]
         y_ref[0] = ssd_chunk_outputs(s, xc, bc, cc, lc)
         s_new = ssd_chunk_state(s, xc, bc, lc)
         s_acc[...] = s_new
@@ -189,7 +202,9 @@ def _ssd_scan_call(x, b, c, la, *, chunk, interpret):
             pl.BlockSpec((1, chunk, P), lambda g, ci: (g, ci, 0)),
             pl.BlockSpec((1, chunk, N), lambda g, ci: (g, ci, 0)),
             pl.BlockSpec((1, chunk, N), lambda g, ci: (g, ci, 0)),
-            pl.BlockSpec((1, chunk), lambda g, ci: (g, ci)),
+            # la rides as a [G, 1, T] view: a (1, chunk) block of [G, T]
+            # has a second-to-last dim of 1, which the TPU lowering refuses
+            pl.BlockSpec((1, 1, chunk), lambda g, ci: (g, 0, ci)),
         ],
         out_specs=[
             pl.BlockSpec((1, chunk, P), lambda g, ci: (g, ci, 0)),
@@ -206,10 +221,10 @@ def _ssd_scan_call(x, b, c, la, *, chunk, interpret):
         # scratch-carry check certifies exactly this declaration
         # (tests/test_pallas_lint.py proves the ("parallel", "parallel")
         # variant is refused).
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(x, b, c, la)
+    )(x, b, c, la[:, None, :])
     return y, s
 
 
@@ -243,6 +258,13 @@ def ssd_scan(x, b, c, la, *, chunk: int = 64,
     projections, ``la`` [G, T] fp32 log-decay; ``T`` must be a multiple of
     ``chunk``.  Returns ``(y [G, T, P], s_final [G, N, P])`` — bit-identical
     to :func:`ssd_scan_reference` (interpret mode is the CPU proof).
+
+    On the chip ``chunk`` must be a multiple of 128 (or equal ``T``): the
+    log-decay block is ``(1, 1, chunk)`` of a ``[G, 1, T]`` view and the
+    lowering wants its last dim lane-aligned.  The v5e's compiler takes
+    128, 256 and 512 at the 8B shape (G 64, T 2048, P 64, N 128) and
+    refuses 64 (block shape) and 2048 (VMEM); ``tests/test_chip_compile.py``
+    keeps the 128 case.  Smaller chunks run in interpret mode only.
     """
     if x.shape[1] % chunk:
         raise ValueError(f"T={x.shape[1]} not a multiple of chunk={chunk}")

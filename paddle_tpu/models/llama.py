@@ -174,16 +174,34 @@ def _place_all_params(layer, mesh: Optional[ProcessMesh]):
     shard_layer(layer, mesh)
 
 
+def _batch_axes(mesh: ProcessMesh):
+    axes = tuple(n for n in ("dp", "sharding") if n in mesh.dim_names) or None
+    return axes[0] if isinstance(axes, tuple) and len(axes) == 1 else axes
+
+
+def _hidden_shard(mesh: Optional[ProcessMesh], sequence_parallel: bool):
+    """``(jax Mesh, spec)`` of the residual stream [B, S, hidden]: batch over
+    'dp', optionally seq over 'mp'; None without a mesh."""
+    if mesh is None:
+        return None
+    seq_axis = "mp" if (sequence_parallel and "mp" in mesh.dim_names) else None
+    return mesh.jax_mesh, PartitionSpec(_batch_axes(mesh), seq_axis, None)
+
+
+def _heads_shard(mesh: Optional[ProcessMesh]):
+    """``(jax Mesh, spec)`` of q/k/v [B, S, heads, head_dim]: batch over
+    'dp', heads over 'mp'; None without a mesh."""
+    if mesh is None:
+        return None
+    return mesh.jax_mesh, PartitionSpec(
+        _batch_axes(mesh), None, "mp" if "mp" in mesh.dim_names else None, None)
+
+
 def _constrain_hidden(x, mesh: Optional[ProcessMesh], sequence_parallel: bool):
     """Residual-stream constraint: batch over 'dp', optionally seq over 'mp'."""
     if mesh is None:
         return x
-    batch_axes = tuple(n for n in ("dp", "sharding") if n in mesh.dim_names) or None
-    if isinstance(batch_axes, tuple) and len(batch_axes) == 1:
-        batch_axes = batch_axes[0]
-    seq_axis = "mp" if (sequence_parallel and "mp" in mesh.dim_names) else None
-    spec = PartitionSpec(batch_axes, seq_axis, None)
-    sharding = NamedSharding(mesh.jax_mesh, spec)
+    sharding = NamedSharding(*_hidden_shard(mesh, sequence_parallel))
 
     def g(h):
         if isinstance(h, jax.core.Tracer):
@@ -198,7 +216,7 @@ def _constrain_hidden(x, mesh: Optional[ProcessMesh], sequence_parallel: bool):
 # ---------------------------------------------------------------------------
 
 class LlamaRMSNorm(Layer):
-    def __init__(self, config: LlamaConfig):
+    def __init__(self, config: LlamaConfig, mesh: Optional[ProcessMesh] = None):
         super().__init__()
         from ..nn.initializer import Constant
 
@@ -206,9 +224,10 @@ class LlamaRMSNorm(Layer):
             [config.hidden_size], dtype=config.pdtype,
             default_initializer=Constant(1.0))
         self.epsilon = config.rms_norm_eps
+        self._shard = _hidden_shard(mesh, config.sequence_parallel)
 
     def forward(self, x):
-        return F.rms_norm(x, self.weight, self.epsilon)
+        return F.rms_norm(x, self.weight, self.epsilon, shard=self._shard)
 
 
 def attention_fn(hidden, w_qkv, w_o, cos, sin, cfg: LlamaConfig, position_ids=None,
@@ -230,7 +249,8 @@ def attention_fn(hidden, w_qkv, w_o, cos, sin, cfg: LlamaConfig, position_ids=No
         o = ring_attention(q, k, v, mesh=mesh,
                            axis_name=cfg.context_parallel_axis, causal=True)
     elif cfg.use_flash_attention:
-        o = fa_mod.flash_attention(q, k, v, causal=True)
+        o = fa_mod.flash_attention(q, k, v, causal=True,
+                                   shard=_heads_shard(mesh))
     else:
         rep = h // hk
         o = fa_mod._attention_reference(
@@ -451,9 +471,9 @@ class LlamaMLP(Layer):
 class LlamaDecoderLayer(Layer):
     def __init__(self, config: LlamaConfig, mesh: Optional[ProcessMesh]):
         super().__init__()
-        self.input_layernorm = LlamaRMSNorm(config)
+        self.input_layernorm = LlamaRMSNorm(config, mesh)
         self.self_attn = LlamaAttention(config, mesh)
-        self.post_attention_layernorm = LlamaRMSNorm(config)
+        self.post_attention_layernorm = LlamaRMSNorm(config, mesh)
         if config.moe_num_experts > 1:
             from ..incubate.moe import MoELayer
 
@@ -527,7 +547,7 @@ class LlamaModel(Layer):
         _shard_param(self.embed_tokens, mesh, 0)  # vocab-parallel
         self.layers = LayerList([LlamaDecoderLayer(config, mesh)
                                  for _ in range(config.num_hidden_layers)])
-        self.norm = LlamaRMSNorm(config)
+        self.norm = LlamaRMSNorm(config, mesh)
         cos, sin = rope_mod.rope_freqs(config.head_dim, config.max_position_embeddings,
                                        config.rope_theta)
         self.register_buffer("rope_cos", Tensor(cos), persistable=False)

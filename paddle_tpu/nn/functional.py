@@ -620,12 +620,14 @@ def layer_norm(x, normalized_shape, weight=None, bias=None, epsilon=1e-05, name=
     return apply_op("layer_norm", f, args, {})
 
 
-def rms_norm(x, weight=None, epsilon=1e-6, name=None):
-    """Root-mean-square norm — routed to the Pallas kernel on TPU."""
+def rms_norm(x, weight=None, epsilon=1e-6, name=None, shard=None):
+    """Root-mean-square norm — routed to the Pallas kernel on TPU.
+    ``shard``: see ``kernels.rms_norm.rms_norm``."""
     from ..kernels import rms_norm as _krms
 
     args = (_t(x),) + ((_t(weight),) if weight is not None else ())
-    return apply_op("rms_norm", lambda *xs: _krms.rms_norm(*xs, epsilon=epsilon), args, {})
+    return apply_op("rms_norm", lambda *xs: _krms.rms_norm(
+        *xs, epsilon=epsilon, shard=shard), args, {})
 
 
 def batch_norm(x, running_mean, running_var, weight=None, bias=None, training=False, momentum=0.9, epsilon=1e-05, data_format="NCHW", use_global_stats=None, name=None):

@@ -19,7 +19,7 @@ into the runtimes:
   to a JSON postmortem artifact on every injected-fault path so chaos
   tests can assert the victim and the recovery sequence.
 
-Naming taxonomy (events, spans and metrics share one namespace scheme —
+Naming catalogue (events, spans and metrics share one namespace scheme —
 ``<layer>.<noun-or-verb>``, label args carry the identity):
 
 ===========================  ====================================================

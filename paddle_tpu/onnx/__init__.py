@@ -50,7 +50,7 @@ class _Converter:
         return nm
 
     def name_of(self, var) -> str:
-        from jax._src import core
+        from jax.extend import core
 
         if isinstance(var, core.Literal):
             return self.add_init(np.asarray(var.val), "lit")
@@ -295,7 +295,7 @@ class _Converter:
 
     def _op_dynamic_slice(self, eqn):
         # constant start indices (the common traced case) -> Slice
-        from jax._src import core
+        from jax.extend import core
 
         starts = []
         for v in eqn.invars[1:]:
@@ -569,7 +569,7 @@ class _Converter:
         for cv, cval in zip(inner.constvars, closed.consts):
             self.names[cv] = self.add_init(_np_of(cval), "c")
         self.convert_jaxpr_body(inner)
-        from jax._src import core
+        from jax.extend import core
 
         for outer, innerv in zip(eqn.outvars, inner.outvars):
             if isinstance(innerv, core.Literal):
@@ -592,7 +592,7 @@ class _Converter:
         self._inline(eqn, eqn.params["call_jaxpr"])
 
     def _op_remat(self, eqn):
-        from jax._src import core
+        from jax.extend import core
 
         closed = core.ClosedJaxpr(eqn.params["jaxpr"], ())
         self._inline(eqn, closed)
@@ -605,7 +605,7 @@ class _Converter:
         stacked inputs; ys re-stack with Concat.  (The alternative — ONNX
         Loop — trades graph size for a subgraph encoding few runtimes
         optimize; unrolling keeps the exporter self-contained.)"""
-        from jax._src import core
+        from jax.extend import core
 
         p = eqn.params
         closed = p["jaxpr"]

@@ -109,11 +109,12 @@ class Optimizer:
         return False  # AdamW overrides
 
     def _fused_leaf(self, p32, g32, slots, lr, step, apply_decay, out_dtype,
-                    interpret):
+                    interpret, sharding=None):
         """Optional single-pass fused kernel for one leaf's update (weight
         decay + moments + step + model-dtype cast in one HBM pass).  Returns
         ``(p32_new, slots_new, p_out)`` or None to use the reference
-        expressions.  Adam/AdamW override (``kernels/adamw.py``)."""
+        expressions.  Adam/AdamW override (``kernels/adamw.py``).
+        ``sharding``: the parameter's own, where it is known."""
         return None
 
     # -- cross-replica sharded weight update (ZeRO-1, arXiv:2004.13336) --------
@@ -217,6 +218,8 @@ class Optimizer:
         from ..kernels.adamw import fused_enabled
 
         fused_on, interpret = fused_enabled()
+        shardings = [getattr(p._data, "sharding", None)
+                     for p in self._parameter_list]
         # fused + shard_update compose for both kernel modes: interpret
         # discharges to plain HLO (GSPMD partitions it), and the compiled
         # Mosaic custom call routes through shard_map in Adam._fused_leaf
@@ -238,7 +241,8 @@ class Optimizer:
                 if fused_on:
                     res = self._fused_leaf(p32, g32, slots, lr, step,
                                            apply_decay=not no_decay[i],
-                                           out_dtype=p.dtype, interpret=interpret)
+                                           out_dtype=p.dtype, interpret=interpret,
+                                           sharding=shardings[i])
                 if res is not None:
                     p32_new, slots_new, p_out = res
                 else:
@@ -362,7 +366,12 @@ class Optimizer:
         fused_on, interpret = fused_enabled()  # composes with _wus, see _build_update_fn
         overlap = self._wus_overlap_active()
 
+        shardings = []   # per flat leaf, read off the concrete params below
+
         def init_fn(params):
+            shardings[:] = [getattr(p, "sharding", None)
+                            for p in jax.tree.leaves(params)]
+
             def per_leaf(p):
                 slots = self_ref._init_slots(p)
                 if self_ref._multi_precision and _is_float(p.dtype) and p.dtype != jnp.float32:
@@ -372,7 +381,7 @@ class Optimizer:
             return jax.tree.map(per_leaf, params)
 
         def update_fn(params, grads, state, lr, step):
-            def per_leaf(p, g, s):
+            def per_leaf(p, g, s, sharding):
                 p32 = s.get("master", p.astype(jnp.float32) if p.dtype != jnp.float32 else p)
                 g32 = self_ref._wus_constrain(g.astype(jnp.float32))
                 p32 = self_ref._wus_constrain(p32)
@@ -381,7 +390,8 @@ class Optimizer:
                 if fused_on:
                     res = self_ref._fused_leaf(p32, g32, slots, lr, step,
                                                apply_decay=True,
-                                               out_dtype=p.dtype, interpret=interpret)
+                                               out_dtype=p.dtype, interpret=interpret,
+                                               sharding=sharding)
                 if res is not None:
                     p32_new, slots_new, p_out = res
                 else:
@@ -401,7 +411,10 @@ class Optimizer:
             flat_p, treedef = jax.tree.flatten(params)
             flat_g = treedef.flatten_up_to(grads)
             flat_s = treedef.flatten_up_to(state)
-            outs = [per_leaf(p, g, s) for p, g, s in zip(flat_p, flat_g, flat_s)]
+            leaf_sh = (shardings if len(shardings) == len(flat_p)
+                       else [None] * len(flat_p))
+            outs = [per_leaf(p, g, s, sh)
+                    for p, g, s, sh in zip(flat_p, flat_g, flat_s, leaf_sh)]
             new_p = treedef.unflatten([o[0] for o in outs])
             new_s = treedef.unflatten([o[1] for o in outs])
             return new_p, new_s
@@ -459,7 +472,7 @@ class Adam(Optimizer):
         return p_new, {"m": m, "v": v}
 
     def _fused_leaf(self, p32, g32, slots, lr, step, apply_decay, out_dtype,
-                    interpret):
+                    interpret, sharding=None):
         if type(self)._update is not Adam._update:
             return None  # NAdam/RAdam override the math — no fused kernel
         if set(slots) != {"m", "v"} or p32.dtype != jnp.float32:
@@ -473,27 +486,28 @@ class Adam(Optimizer):
             beta1=self._beta1, beta2=self._beta2, epsilon=self._epsilon,
             weight_decay=self._weight_decay, decoupled=self._decoupled_decay(),
             apply_decay=apply_decay, out_dtype=out_dtype, interpret=interpret)
+        # GSPMD has no partitioning rule for the Mosaic custom call, so the
+        # per-shard world is entered explicitly (kernels.per_shard): the
+        # update is purely elementwise, so each device runs the kernel on
+        # its own shard, bit-exact vs the unsharded kernel
+        # (tests/test_fused_adamw.py).  Under ZeRO-1 the shard is the slot's;
+        # otherwise it is the parameter's own layout.
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from ..kernels import per_shard
+
+        shard = None
         if self._wus is not None:
-            # ZeRO-1 composition: GSPMD has no partitioning rule for the
-            # Mosaic custom call, so enter the per-shard world explicitly —
-            # shard_map hands each device its slot shard and the kernel runs
-            # on shard-local data.  Bit-exact vs the unsharded kernel: the
-            # update is purely elementwise (tests/test_fused_adamw.py).
-            from jax.sharding import PartitionSpec as P
-
-            from ..framework.shard_map_compat import shard_map
-
             mesh, axis = self._wus
             spec = _wus_partition_spec(p32.shape, mesh.shape[axis], axis)
-            if spec != P():   # replicated leaves run the kernel as-is
-                fn = shard_map(kernel, mesh=mesh,
-                               in_specs=(spec, spec, spec, spec, P(), P()),
-                               out_specs=(spec, spec, spec, spec),
-                               check_vma=False)
-                p_new, m, v, p_out = fn(p32, g32, slots["m"], slots["v"],
-                                        lr, step)
-                return p_new, {"m": m, "v": v}, p_out
-        p_new, m, v, p_out = kernel(p32, g32, slots["m"], slots["v"], lr, step)
+            if spec != P():   # replicated leaves fall through
+                shard = (mesh, spec)
+        if (shard is None and not interpret
+                and isinstance(sharding, NamedSharding)
+                and sharding.mesh.size > 1):
+            shard = (sharding.mesh, sharding.spec)
+        p_new, m, v, p_out = per_shard(kernel, shard, 4, 2)(
+            p32, g32, slots["m"], slots["v"], lr, step)
         return p_new, {"m": m, "v": v}, p_out
 
 

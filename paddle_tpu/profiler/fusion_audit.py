@@ -42,8 +42,8 @@ the ``kernels.registry`` admission seam.
 Beyond the three per-record shapes, the auditor groups records into **source
 regions**: connected components of the dataflow graph whose instructions
 trace back to the same Python source file (XLA keeps ``metadata={...
-source_file= source_line=}`` through optimization, including through AD — a
-region therefore spans a reference op's forward *and* backward instructions).
+stack_frame_id=}`` through optimization, including through AD — a region
+therefore spans a reference op's forward *and* backward instructions).
 A region's byte win is the analytic-minimum model applied to the whole
 group::
 
@@ -98,8 +98,7 @@ _FREE_OPS = {
 }
 
 _KIND_RE = re.compile(r"kind=k(\w+)")
-_META_RE = re.compile(
-    r'metadata=\{[^}]*?source_file="([^"]+)"[^}]*?source_line=(\d+)')
+_FRAME_RE = re.compile(r"metadata=\{[^}]*?stack_frame_id=(\d+)")
 _OPNAME_RE = re.compile(r'op_name="([^"]*)"')
 _SCOPE_RE = re.compile(r"jit\((\w+)\)")
 # jit scopes that name the step itself, not a fusible sub-region
@@ -267,6 +266,39 @@ def _while_trip_count(text: str, cond_name: str) -> int:
     return 1
 
 
+def _stack_frame_sources(text: str) -> Dict[int, Tuple[str, int]]:
+    """``stack_frame_id -> (source basename, line)`` from the module header.
+
+    XLA keeps each instruction's Python origin as ``stack_frame_id=N`` and
+    the frames in four tables ahead of the first computation (``FileNames``,
+    ``FunctionNames``, ``FileLocations``, ``StackFrames``)."""
+    tables: Dict[str, Dict[int, str]] = {}
+    current = None
+    for raw in text.splitlines():
+        line = raw.strip()
+        if line in ("FileNames", "FunctionNames", "FileLocations",
+                    "StackFrames"):
+            current = tables.setdefault(line, {})
+        elif current is not None and line[:1].isdigit():
+            idx, _, rest = line.partition(" ")
+            current[int(idx)] = rest
+        elif line.endswith("{"):
+            break                         # first computation: header is over
+    def field(s, key):
+        m = re.search(key + r"=(\d+)", s)
+        return int(m.group(1)) if m else 0
+    out: Dict[int, Tuple[str, int]] = {}
+    for fid, frame in tables.get("StackFrames", {}).items():
+        loc = tables.get("FileLocations", {}).get(
+            field(frame, "file_location_id"), "")
+        fname = tables.get("FileNames", {}).get(
+            field(loc, "file_name_id"), "").strip('"')
+        if fname:
+            out[fid] = (fname.replace("\\", "/").rsplit("/", 1)[-1],
+                        field(loc, "line"))
+    return out
+
+
 def audit_hlo_text(text: str) -> FusionAudit:
     """Audit the ENTRY computation of an optimized HLO text dump.
 
@@ -277,6 +309,7 @@ def audit_hlo_text(text: str) -> FusionAudit:
     count, so fusible regions inside an accumulation loop stay on the
     pallas worklist and audit totals stay comparable across accum settings.
     """
+    frames = _stack_frame_sources(text)
     sizes: Dict[str, int] = {}       # scaled: per-use traffic of one step
     base_sizes: Dict[str, int] = {}  # unscaled shape bytes
     records: List[FusionRecord] = []
@@ -326,10 +359,9 @@ def audit_hlo_text(text: str) -> FusionAudit:
             mk = _KIND_RE.search(tail)
             if mk:
                 rec.kind = mk.group(1)
-            mm = _META_RE.search(tail)
-            if mm:
-                rec.source = mm.group(1).replace("\\", "/").rsplit("/", 1)[-1]
-                rec.source_line = int(mm.group(2))
+            mm = _FRAME_RE.search(tail)
+            if mm and int(mm.group(1)) in frames:
+                rec.source, rec.source_line = frames[int(mm.group(1))]
             mo = _OPNAME_RE.search(tail)
             if mo:
                 scopes = [s for s in _SCOPE_RE.findall(mo.group(1))
@@ -499,7 +531,7 @@ def _build_regions(records, by_name, consumers, free_src, sizes):
 
 def audit_compiled(compiled) -> Optional[FusionAudit]:
     """Audit a jax ``Compiled`` object (returns None if the backend does not
-    expose optimized HLO text, e.g. some TPU plugin builds)."""
+    expose optimized HLO text)."""
     try:
         text = compiled.as_text()
     except Exception:

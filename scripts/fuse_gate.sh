@@ -59,7 +59,9 @@ fi
 
 check() {  # check <preset> <timeout-s> <extra bench args...>
     local preset="$1" budget="$2"; shift 2
-    gate_bench "$preset" "$budget" --fuse "$@" || return
+    # off the chip the emitted kernels run only where the flag asks for the
+    # interpreter (kernels/emit.py never picks it by itself)
+    FLAGS_pallas_interpret=1 gate_bench "$preset" "$budget" --fuse "$@" || return
     gate_diff "$preset" "$DROP_SLACK" <<PY
 import json, os, sys
 exec(os.environ["GATE_PY_COMMON"])

@@ -1,6 +1,7 @@
 #!/bin/bash
-# Manual post-revival measurement sweep (run AFTER the watcher's RECAPTURE
-# sweep finishes so the two don't contend for the chip):
+# Manual on-chip measurement sweep: sequential bench.py commands, one
+# process on the chip at a time.  Run it on the machine that holds the chip
+# (through the chip tool: one call, results under chiprun_out/):
 #   1. gradient-accumulation sweep on the base preset (the next MFU lever:
 #      one AdamW pass per k micro-batches; bf16 accumulator fits HBM).
 #      CPU-mesh proxy ladder (tiny, scan-measured step time, 2026-08-05):
@@ -14,11 +15,11 @@
 #      optimizer win quoted in PERF.md is re-measured here)
 #   3. serving-engine run at the post-rework SHA (batched prefill + sampling)
 #   4. an on-chip smoke of the sampling program (has only ever run on CPU)
-# Results append to BENCH_ACCUM_SWEEP.jsonl (NOT the driver cache: the accum
-# rows change the preset's global-batch semantics; promote the winner into
-# BENCH_TPU_CACHE.jsonl only deliberately, with its "accum" field visible).
+# Results append to chiprun_out/BENCH_ACCUM_SWEEP.jsonl (the accum rows
+# change the preset's global-batch semantics: keep the "accum" field visible).
 cd "$(dirname "$0")/.." || exit 1
-OUT=BENCH_ACCUM_SWEEP.jsonl
+mkdir -p chiprun_out
+OUT=chiprun_out/BENCH_ACCUM_SWEEP.jsonl
 for args in "--accum 2 --grad-dtype bfloat16" "--accum 4 --grad-dtype bfloat16" "--accum 4"; do
     echo "[revival] base $args" >&2
     line=$(timeout 2400 python bench.py --preset base --device tpu $args 2>/dev/null | tail -1)
@@ -49,14 +50,14 @@ for args in "--pp 2 --pp-runtime both --pp-schedule zb" \
     line=$(timeout 2400 python bench.py --device tpu $args 2>/dev/null | tail -1)
     [ -n "$line" ] && echo "$line" >> "$OUT" && echo "$line" | head -c 200 >&2 && echo >&2
 done
-# observability recapture: the MPMD A/B again, but dumping the span trace
+# observability: the MPMD A/B again, but dumping the span trace
 # (+ a jax.profiler XLA capture via --otrace-xla) so the on-chip per-stage
 # timeline and its trace-vs-analytic bubble crosscheck land as artifacts;
-# open /tmp/revival_otrace.json in ui.perfetto.dev, the .xla dir in
+# open chiprun_out/revival_otrace.json in ui.perfetto.dev, the .xla dir in
 # tensorboard.  CPU-proxy rel_err (2026-08-06): pp2 0.064, pp4 ~0.000.
-echo "[revival] pp --otrace (obs recapture)" >&2
+echo "[revival] pp --otrace (obs)" >&2
 line=$(timeout 2400 python bench.py --device tpu --pp 4 --pp-runtime mpmd \
-       --pp-schedule zb --otrace /tmp/revival_otrace.json --otrace-xla \
+       --pp-schedule zb --otrace chiprun_out/revival_otrace.json --otrace-xla \
        2>/dev/null | tail -1)
 [ -n "$line" ] && echo "$line" >> "$OUT" && echo "$line" | head -c 200 >&2 && echo >&2
 echo "[revival] serve (post-rework)" >&2

@@ -1,0 +1,162 @@
+"""The main path's Pallas kernels, compiled by the TPU's own compiler for a
+described (not attached) v5e at the widths the ``base`` and ``serve``
+presets run: what Mosaic refuses on the chip it refuses here, at no chip
+time.  Interpret-mode tests cannot see tiling, VMEM or layout refusals.
+
+Nothing executes and nothing is timed.  The topology is described inside a
+module-scoped fixture (never at import: every xdist worker imports this
+file, and only one process at a time may load the TPU library), and all
+the compiles live in this one file so one worker owns the library.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+import paddle_tpu.kernels as kernels
+from paddle_tpu.kernels import adamw, decode_attention, flash_attention
+from paddle_tpu.kernels import rms_norm as rms_norm_mod
+from paddle_tpu.kernels import ssd_scan as ssd_mod
+
+BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
+
+# base preset: batch 3 x seq 2048, hidden 2048, intermediate 5632,
+# 16 query / 8 kv heads x 128; serve preset: max_batch 16, 256 blocks of 128
+B, S, HID, INTER, H, HK, D = 3, 2048, 2048, 5632, 16, 8, 128
+SB, NB, BS, CTX = 16, 256, 128, 1024
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def kernel_branch(monkeypatch):
+    # the wrappers ask jax.default_backend(), which is the CPU here
+    monkeypatch.setattr(kernels, "use_pallas", lambda: True)
+
+
+def _compile(one_chip, fn, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+def _flash_fwd_bwd(one_chip, b, s):
+    def loss(q, k, v):
+        o = flash_attention.flash_attention(q, k, v, causal=True)
+        return jnp.sum(o.astype(F32))
+
+    _compile(one_chip, jax.grad(loss, argnums=(0, 1, 2)),
+             ((b, s, H, D), BF16), ((b, s, HK, D), BF16), ((b, s, HK, D), BF16))
+
+
+def test_flash_fwd_bwd_resident(one_chip, kernel_branch):
+    assert flash_attention._resident_ok(S, D, 2)
+    _flash_fwd_bwd(one_chip, B, S)
+
+
+def test_flash_fwd_bwd_streaming(one_chip, kernel_branch):
+    # the longctx preset's sequence: K/V page through VMEM
+    assert not flash_attention._resident_ok(16384, D, 2)
+    _flash_fwd_bwd(one_chip, 1, 16384)
+
+
+def test_rms_norm_fwd_bwd(one_chip, kernel_branch):
+    def loss(x, w):
+        return jnp.sum(rms_norm_mod.rms_norm(x, w).astype(F32))
+
+    # the backward is jnp: keep the value, or the forward kernel is dead code
+    _compile(one_chip, jax.value_and_grad(loss, argnums=(0, 1)),
+             ((B, S, HID), BF16), ((HID,), BF16))
+
+
+def test_adamw_update(one_chip):
+    def step(p, g, m, v, lr, t):
+        return adamw.adamw_update(p, g, m, v, lr, t, beta1=0.9, beta2=0.95,
+                                  epsilon=1e-8, weight_decay=0.1,
+                                  out_dtype="bfloat16")
+
+    z = ((HID, INTER), F32)
+    _compile(one_chip, step, z, z, z, z, ((), F32), ((), I32))
+
+
+_DENSE = (((SB, 1, H, D), BF16), ((SB, CTX, HK, D), BF16),
+          ((SB, CTX, HK, D), BF16), ((SB,), I32))
+_PAGED = (((SB, 1, H, D), BF16), ((NB, HK, BS, D), BF16),
+          ((NB, HK, BS, D), BF16), ((SB, NB // SB), I32), ((SB,), I32))
+
+
+@pytest.mark.parametrize("fn,shapes", [
+    pytest.param(
+        lambda q, k, v, n: decode_attention._pallas_decode(q, k, v, n, 0.1),
+        _DENSE, id="decode_mmha"),
+    pytest.param(
+        lambda q, k, v, n: decode_attention._pallas_decode_fused(
+            q, k, v, n, 0.1, block_k=256), _DENSE, id="decode_mmha_fused"),
+    pytest.param(
+        lambda q, k, v, t, n: decode_attention._pallas_paged_decode(
+            q, k, v, t, n, 0.1), _PAGED, id="paged_decode"),
+    pytest.param(
+        lambda q, k, v, t, n: decode_attention._pallas_paged_decode_fused(
+            q, k, v, t, n, 0.1), _PAGED, id="paged_decode_fused"),
+])
+def test_decode_kernels(one_chip, fn, shapes):
+    _compile(one_chip, fn, *shapes)
+
+
+def test_decode_wrappers_pick_the_fused_kernels(one_chip, kernel_branch):
+    # at the serve preset's shapes the public wrappers must reach a kernel
+    _compile(one_chip, decode_attention.masked_multihead_attention, *_DENSE)
+    _compile(one_chip, decode_attention.paged_decode_attention, *_PAGED)
+
+
+def test_ssd_scan(one_chip):
+    # ssd_8b_config: 64 heads, state 128, head dim 64, chunk 128; seq 2048
+    G, T, P, N, chunk = 64, 2048, 64, 128, 128
+
+    def fn(x, b, c, la):
+        return ssd_mod.ssd_scan(x, b, c, la, chunk=chunk)
+
+    _compile(one_chip, fn, ((G, T, P), F32), ((G, T, N), F32),
+             ((G, T, N), F32), ((G, T), F32))
+
+
+def test_emitted_kernels_refused_at_base_widths(topo):
+    """kernels/emit.py's backward keeps every operand in one VMEM block and
+    its matmuls accumulate in the operand dtype: at the base preset's
+    widths (rows 3 x 2048, bf16) the compiler refuses all three sites, and
+    the refusal takes the transformer's own fuse-admission-rejected route,
+    in the compiler's words, with nothing accepted."""
+    from paddle_tpu.analysis.fusion_transform import plan_transform
+    from paddle_tpu.kernels import emit
+    from paddle_tpu.models.llama import LlamaConfig
+
+    cfg = LlamaConfig(vocab_size=32000, hidden_size=HID,
+                      intermediate_size=INTER, num_hidden_layers=12,
+                      num_attention_heads=H, num_key_value_heads=HK,
+                      dtype="bfloat16", param_dtype="float32")
+    cands = [{"name": f"region:{site}", "pattern": s.pattern,
+              "bytes_saved": 1 << 20, "source": (s.match_sources or ("",))[0],
+              "op_hints": list(s.match_hints)}
+             for site, s in emit.SITES.items()]
+    plan = plan_transform(cands, shapes=emit.llama_site_shapes(cfg, B * S),
+                          device=topo.devices[0], verify=False)
+    assert not plan.accepted, plan.describe()
+    refused = plan.report.by_code("fuse-admission-rejected")
+    assert {f.where for f in refused} == set(emit.SITES), plan.describe()
+    words = " ".join(f.message for f in refused)
+    assert "Expected matmul acc to be 32-bit" in words
+    assert "vmem" in words

@@ -146,13 +146,31 @@ def test_pallas_candidates_worklist():
 META_HLO = """\
 HloModule meta, entry_computation_layout={(f32[1024,1024]{1,0})->f32[1024,1024]{1,0}}
 
+FileNames
+1 "/repo/models/mlp.py"
+2 "/repo/models/other.py"
+
+FunctionNames
+1 "silu"
+2 "other"
+
+FileLocations
+1 {file_name_id=1 function_name_id=1 line=10 end_line=10 column=4 end_column=20}
+2 {file_name_id=1 function_name_id=1 line=11 end_line=11 column=4 end_column=20}
+3 {file_name_id=2 function_name_id=2 line=3 end_line=3 column=4 end_column=20}
+
+StackFrames
+1 {file_location_id=1 parent_frame_id=1}
+2 {file_location_id=2 parent_frame_id=2}
+3 {file_location_id=3 parent_frame_id=3}
+
 ENTRY %main.9 (p0: f32[1024,1024], p1: f32[1024,1024]) -> f32[1024,1024] {
   %p0 = f32[1024,1024]{1,0} parameter(0)
   %p1 = f32[1024,1024]{1,0} parameter(1)
-  %a = f32[1024,1024]{1,0} fusion(%p0, %p1), kind=kLoop, calls=%body, metadata={op_name="jit(step)/jit(silu)/mul" source_file="/repo/models/mlp.py" source_line=10}
+  %a = f32[1024,1024]{1,0} fusion(%p0, %p1), kind=kLoop, calls=%body, metadata={op_name="jit(step)/jit(silu)/mul" stack_frame_id=1}
   %bc = f32[1024,1024]{1,0} bitcast(%a)
-  %b = f32[1024,1024]{1,0} fusion(%bc, %p1), kind=kLoop, calls=%body, metadata={op_name="jit(step)/jit(silu)/add" source_file="/repo/models/mlp.py" source_line=11}
-  ROOT %c = f32[1024,1024]{1,0} fusion(%b), kind=kLoop, calls=%body, metadata={op_name="jit(step)/other" source_file="/repo/models/other.py" source_line=3}
+  %b = f32[1024,1024]{1,0} fusion(%bc, %p1), kind=kLoop, calls=%body, metadata={op_name="jit(step)/jit(silu)/add" stack_frame_id=2}
+  ROOT %c = f32[1024,1024]{1,0} fusion(%b), kind=kLoop, calls=%body, metadata={op_name="jit(step)/other" stack_frame_id=3}
 }
 """
 

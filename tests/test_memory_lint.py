@@ -1,4 +1,4 @@
-"""Memory liveness lint: every ``mem-*`` taxonomy code must fire on a
+"""Memory liveness lint: every ``mem-*`` catalogue code must fire on a
 seeded defect, a clean program must stay silent, and the liveness-modeled
 peak must agree with XLA's own ``memory_analysis()`` within tolerance on a
 battery of program shapes.  Everything compiles toy programs — nothing

@@ -1,4 +1,4 @@
-"""Pallas kernel verifier: every ``krn-*`` taxonomy code must fire on a
+"""Pallas kernel verifier: every ``krn-*`` catalogue code must fire on a
 seeded defect, every shipped kernel must lint clean through the registry,
 and the admission seam must refuse a defective registered kernel *before*
 its first call.  Everything traces abstractly — no kernel executes except
@@ -148,13 +148,13 @@ def test_seeded_dynamic_index_advisory():
     assert not rep.by_code("krn-coverage-hole"), rep.report()
 
 
-def test_untraceable_function_degrades_to_advisory():
+def test_untraceable_function_raises():
+    # a kernel the verifier cannot trace is a broken kernel, not a finding
     def boom(x):
         raise ValueError("no trace for you")
 
-    rep = check_kernel(boom, _sds((8, 128)))
-    assert "trace_error" in rep.meta
-    assert rep.by_code("krn-dynamic-index"), rep.report()
+    with pytest.raises(ValueError, match="no trace for you"):
+        check_kernel(boom, _sds((8, 128)))
 
 
 # ---------------------------------------------------------------------------

@@ -100,7 +100,7 @@ def test_gather_fallback_is_flagged_unbounded(mesh, shrunk_meshes):
     assert "all-gather" in plan.collective_kinds()
     assert plan.note
 
-    # the fallback surfaces through the analyzer taxonomy so lint
+    # the fallback surfaces through the analyzer catalogue so lint
     # consumers can rank it with everything else
     rep = plan.findings()
     assert [f.code for f in rep] == ["reshard-unbounded"]

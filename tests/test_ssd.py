@@ -143,12 +143,20 @@ def _ids(cfg, n, seed=0):
                        jnp.int32)
 
 
-def test_prefill_then_decode_bitwise_vs_full_forward(ssd_model):
+def test_prefill_then_decode_bitwise_vs_full_forward():
     """THE decode contract: at every step, decoding one token from the
     recurrent state yields logits bit-identical to re-running the whole
     prefix densely.  Prompt length deliberately not a multiple of the
-    chunk size."""
-    model, cfg = ssd_model, ssd_model.config
+    chunk size.
+
+    Hidden width 64, not the tiny config's 128: XLA's CPU backend picks a
+    different kernel for a one-row matmul once the contraction reaches 128
+    (``(h @ w)[13:14] != h[13:14] @ w`` bitwise), so at 128 the dense
+    projections differ before the recurrence under test is reached."""
+    paddle.seed(0)
+    model = SSDForCausalLM(ssd_tiny_config(hidden_size=64,
+                                           intermediate_size=192))
+    cfg = model.config
     ids = _ids(cfg, 37)
     # ONE full forward is the oracle for every step: the chunk math is
     # exactly causal (masked entries are literal 0.0), so position t is

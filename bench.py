@@ -1235,9 +1235,8 @@ def _bench_obs(jax, paddle, backend, on_tpu, args):
 
     1. **Bubble cross-check** — the MPMD op-span timeline's per-stage idle
        fraction agrees with ``schedule_lint.dag_bubble_fraction`` priced
-       with the trace's own cost table (``value`` = rel err; a dropped or
-       mis-ticked span blows it — the ``OBS_GATE_INJECT=drop-span``
-       self-test relies on exactly that).
+       with the trace's own cost table (``value`` = rel err; a
+       mis-ticked span blows it).
     2. **Tracing never perturbs values, and costs < 5%** — a tiny-preset
        A/B (traced vs untraced pretrain steps, min-of-reps) plus a
        serving trace replayed tracing-off/tracing-on with bit-identical

@@ -367,7 +367,9 @@ class MPMDPipeline:
     def _run_train(self, placed, micro_data, extra):
         from ... import obs
 
-        tr = obs.tracer()
+        # explicit tracing only: the per-op block_until_ready below must
+        # not serialise a pipeline that someone merely profiles
+        tr = obs.explicit_tracer()
         S, M = self.n_stages, self.n_micro
         zb = self.schedule == "ZB"
         dev0, devL = self._assign.device(0), self._assign.device(S - 1)
@@ -565,7 +567,9 @@ class MPMDPipeline:
     def _run_forward(self, placed, micro_inputs, extra):
         from ... import obs
 
-        tr = obs.tracer()
+        # explicit tracing only: the per-op block_until_ready below must
+        # not serialise a pipeline that someone merely profiles
+        tr = obs.explicit_tracer()
         S, M = self.n_stages, self.n_micro
         last_chunk = self.virtual_pp_degree - 1
         in0 = [self._put_dev(jax.tree.map(lambda a: a[m], micro_inputs), 0)

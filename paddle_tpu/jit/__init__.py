@@ -25,6 +25,7 @@ from typing import Any, Callable, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from ..framework import random as rnd
 from ..framework.autograd import no_grad
 from ..framework.dispatch import unwrap, wrap
@@ -624,6 +625,10 @@ class TrainStep:
                 step_fn, donate_argnums=(0, 2) if donate else ())
 
     def __call__(self, *args):
+        with obs.span("train.step", cat="train"):
+            return self._call(args)
+
+    def _call(self, args):
         raw = unwrap(tuple(args))
         self._step += 1
         lr = jnp.asarray(self.optimizer.get_lr(), jnp.float32)

@@ -152,6 +152,7 @@ def _adamw_fused_call(p32, g32, m, v, lr, step, *, beta1, beta2,
         # is the state itself plus the (optional) model-dtype copy
         input_output_aliases={1: 0, 3: 1, 4: 2},
         interpret=interpret,
+        name="adamw_fused",
     )(scal, *args)
 
     def unpad(x):

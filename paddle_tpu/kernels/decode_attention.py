@@ -150,6 +150,7 @@ def _pallas_decode(q, k_cache, v_cache, lengths, sm_scale: float,
         ),
         out_shape=jax.ShapeDtypeStruct((B * hk, rep, d), q.dtype),
         interpret=interpret,
+        name="dense_decode",
     )(len_r, qr, kr, vr)
     return out.reshape(B, hk, rep, d).reshape(B, 1, h, d)
 
@@ -279,6 +280,7 @@ def _pallas_decode_fused(q, k_cache, v_cache, lengths, sm_scale: float,
         ),
         out_shape=jax.ShapeDtypeStruct((B, hk, rep, d), q.dtype),
         interpret=interpret,
+        name="dense_decode_fused",
     )(lengths.astype(jnp.int32), qr, k_cache, v_cache)
     return out.reshape(B, 1, h, d)
 
@@ -482,6 +484,7 @@ def _pallas_paged_decode(q, k_pool, v_pool, block_table, lengths, sm_scale,
         ),
         out_shape=jax.ShapeDtypeStruct((B, hk, rep, d), q.dtype),
         interpret=interpret,
+        name="paged_decode",
     )(block_table.astype(jnp.int32), lengths.astype(jnp.int32), qr,
       k_pool, v_pool)
     return out.reshape(B, 1, h, d)
@@ -566,6 +569,7 @@ def _pallas_paged_decode_fused(q, k_pool, v_pool, block_table, lengths,
         ),
         out_shape=jax.ShapeDtypeStruct((B, hk, rep, d), q.dtype),
         interpret=interpret,
+        name="paged_decode_fused",
     )(block_table.astype(jnp.int32), lengths.astype(jnp.int32), qr,
       k_pool, v_pool)
     return out.reshape(B, 1, h, d)

@@ -203,6 +203,7 @@ def _flash_fwd_stream(q, k, v, causal, sm_scale, block_q, block_k, interpret):
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd_stream",
     )(q, k, v)
     return o, lse
 
@@ -269,6 +270,7 @@ def _flash_fwd_impl(q, k, v, causal, sm_scale, block_q, block_k, interpret):
             jax.ShapeDtypeStruct((BH, 1, Sq), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
     return o, lse
 
@@ -376,6 +378,7 @@ def _flash_bwd_stream(q, k, v, o, lse, do, causal, sm_scale, block_q, block_k,
             pltpu.VMEM((block_k, D), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_bwd_dkv_stream",
     )(q, k, v, do, lse, delta)
 
     def dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
@@ -439,6 +442,7 @@ def _flash_bwd_stream(q, k, v, o, lse, do, causal, sm_scale, block_q, block_k,
         out_shape=jax.ShapeDtypeStruct((BH, Sq, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dq_stream",
     )(q, k, v, do, lse, delta)
 
     return dq, dk, dv
@@ -514,6 +518,7 @@ def _flash_bwd_impl(q, k, v, o, lse, do, causal, sm_scale, block_q, block_k, int
             jax.ShapeDtypeStruct((BH, Sk, D), q.dtype),
         ],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(q, k, v, do, lse, delta)
 
     def dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref):
@@ -555,6 +560,7 @@ def _flash_bwd_impl(q, k, v, o, lse, do, causal, sm_scale, block_q, block_k, int
         out_specs=pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, Sq, D), q.dtype),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(q, k, v, do, lse, delta)
 
     return dq, dk, dv

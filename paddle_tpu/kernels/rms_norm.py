@@ -61,6 +61,7 @@ def _rms_norm_fwd_kernel_call(x, w, epsilon, block_rows: int = 256, interpret: b
         out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, d), x.dtype),
         interpret=interpret,
+        name="rms_norm",
     )(xr, w)
     return out.reshape(orig_shape)
 

@@ -9,8 +9,12 @@ into the runtimes:
 - :mod:`.trace` — structured span tracer.  Thread-safe, monotonic-clock
   spans with categories and args, nestable, exported as Chrome/Perfetto
   ``trace_event`` JSON (open the dump in ``ui.perfetto.dev``).  Disabled
-  is the default and costs one module-global read per call site — no
-  allocation, no locking (``tests/test_obs.py`` pins both).
+  is the default and costs one module-global read and one 24-ns call per
+  call site — no allocation, no locking (``tests/test_obs.py`` pins
+  both).  While a JAX profiler session is active the spans record without
+  being asked and are also entered as ``TraceAnnotation``s, so they lie
+  in the capture on the device trace's clock
+  (``obs.profiled_events()`` reads the session's buffer afterwards).
 - :mod:`.metrics` — metrics registry: counters, gauges and fixed-bucket
   histograms with p50/p95/p99, labeled families
   (``serve.decode_gap_ms{replica=0}``), snapshot-to-JSON round-trippable.
@@ -33,14 +37,29 @@ name                         producer / meaning
                              (ticks, transfers_posted, transfer_bytes, replans)
 ``mpmd.stage-kill``          flight: injected stage failure (victim stage, tick)
 ``mpmd.replan``              flight: survivors re-plan after a stage kill
-``serve.request``            async span chain: one request queued→…→emitted
+``serve.request``            async span chain: one request queued→admitted→
+                             prefill(-chunk)→first-token→decode-round→emitted
+``serve.step``               span: one ``Engine.step()``
+``serve.admit``              span: ``_admit()`` (args admitted, waiting)
+``serve.prefill`` etc.       spans: the jitted call only (``serve.prefill``,
+                             ``serve.prefill-chunk``, ``serve.decode-chunk``)
+``serve.dispatch``           span: all of ``_dispatch_chunk`` (args k, staged,
+                             live): staging, the call, the ledger
+``serve.readback``           span: the host blocked reading the token buffers
+``serve.absorb``             span: the ledger walk and emits (args tokens,
+                             finished)
+``train.step``               span: one ``TrainStep.__call__``
 ``serve.queue_depth``        gauge {replica}: waiting requests after a round
 ``serve.batch_occupancy``    gauge {replica}: live decode slots / max_batch
 ``serve.requests``           counter {replica}: requests emitted
 ``serve.prefix_hit_blocks``  counter {replica}: prompt blocks served from cache
 ``serve.prefill_tokens``     counter {replica}: prompt tokens prefilled
 ``serve.decode_gap_ms``      histogram {replica}: decode-visible gap per chunk
-``serve.ttft_ms``            histogram {replica}: queued→first prefill dispatch
+``serve.ttft_ms``            histogram {replica}: queued→first token on the host
+``serve.queue_wait_ms``      histogram {replica}: queued→dispatch of the first
+                             prefill (the wait for a slot, blocks, the step)
+``serve.warmup_s``           gauge {replica}: seconds of ``Engine.warmup()``
+``serve.warmup_programs``    gauge {replica}: engine programs ``warmup()`` ran
 ``serve.kill``               flight: injected replica kill (victim replica)
 ``serve.reroute``            flight: a harvested request re-placed after a kill
 ``store.leader-elected``     flight: replica won an election (term)
@@ -55,7 +74,8 @@ name                         producer / meaning
 """
 
 from .trace import (Tracer, enable_tracing, disable_tracing, tracer,
-                    trace_enabled, span, instant, validate_chrome_trace)
+                    explicit_tracer, trace_enabled, span, instant,
+                    profiled_events, validate_chrome_trace)
 from .metrics import (Counter, Gauge, Histogram, Registry, registry,
                       reset_metrics)
 from .flight import (FlightRecorder, flight, flight_event, dump_flight,
@@ -63,7 +83,8 @@ from .flight import (FlightRecorder, flight, flight_event, dump_flight,
 
 __all__ = [
     "Tracer", "enable_tracing", "disable_tracing", "tracer",
-    "trace_enabled", "span", "instant",
+    "explicit_tracer", "trace_enabled", "span", "instant",
+    "profiled_events",
     "Counter", "Gauge", "Histogram", "Registry", "registry",
     "reset_metrics",
     "FlightRecorder", "flight", "flight_event", "dump_flight",

@@ -3,12 +3,13 @@
 Design constraints, in priority order:
 
 1. **Disabled is free.**  Tracing is off unless :func:`enable_tracing`
-   ran; every call site goes through the module-level :func:`span` /
-   :func:`instant` fast path, which is one global read and one ``is
-   None`` test before returning a shared no-op singleton — no
-   allocation, no lock acquisition, nothing appended.
-   ``tests/test_obs.py`` pins both properties (tracemalloc diff == 0,
-   poisoned-lock doesn't trip).
+   ran or a JAX profiler session is active (below); every call site goes
+   through the module-level :func:`span` / :func:`instant` fast path,
+   which is one global read, one ``is None`` test and one call of
+   ``TraceAnnotation.is_enabled()`` (24 ns) before returning a shared
+   no-op singleton — no allocation, no lock acquisition, nothing
+   appended.  ``tests/test_obs.py`` pins both properties (tracemalloc
+   diff == 0, poisoned-lock doesn't trip).
 2. **Enabled never perturbs values.**  Spans record wall time
    (``time.perf_counter_ns``) and host-side metadata only; they never
    touch program values, so traced runs are bit-identical to untraced
@@ -32,11 +33,18 @@ Export is the Chrome ``trace_event`` JSON object format
 Extra top-level keys ride along (the spec allows them): ``dump()``
 attaches the metrics-registry snapshot under ``"metrics"``.
 
-Defect injection (for ``scripts/obs_gate.sh``): with
-``OBS_GATE_INJECT=drop-span`` in the environment when the tracer is
-enabled, every 5th completed span is silently dropped — the class of
-defect (an instrumentation point rots away) the gate must be able to
-catch via the bubble cross-check / lifecycle-completeness checks.
+**Following the profiler.**  While a JAX profiler session is active
+(``jax.profiler.start_trace``, the profiler server, a benchmark's
+``--trace 1`` — whoever started it) the fast path records as if tracing
+were enabled, into a buffer of the session's own, and every span is also
+entered as a ``jax.profiler.TraceAnnotation`` of the same name: it lies
+in the capture's ``/host:CPU`` plane on the device trace's clock, above
+the device's lines.  The session's buffer stays readable after the
+session stops (:func:`profiled_events`), is replaced when the next
+session starts, and nothing is recorded between sessions.  A consumer
+that changes what it *does* when traced (the MPMD executor's
+``block_until_ready`` per op) asks :func:`explicit_tracer`, which does
+not follow the profiler.
 """
 
 from __future__ import annotations
@@ -47,15 +55,24 @@ import threading
 import time
 from typing import Any, Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
+from .flight import flight as _flight
+
 __all__ = [
     "Tracer", "enable_tracing", "disable_tracing", "tracer",
-    "trace_enabled", "span", "instant",
+    "explicit_tracer", "trace_enabled", "span", "instant",
+    "profiled_events",
 ]
+
+_profiling = TraceAnnotation.is_enabled    # is a profiler session active?
 
 # guards tracer install/export/clear ONLY — the disabled fast path and the
 # per-event append never acquire it (the no-lock micro-test poisons it)
 _lock = threading.Lock()
-_tracer: Optional["Tracer"] = None
+_tracer: Optional["Tracer"] = None       # installed by enable_tracing()
+_session: Optional["Tracer"] = None      # the current or last profiler session's
+_in_session = False                      # was a session active at the last call
 
 
 class _NoopSpan:
@@ -65,6 +82,9 @@ class _NoopSpan:
     def __enter__(self):
         return self
 
+    def set(self, **args):
+        pass
+
     def __exit__(self, exc_type, exc, tb):
         return False
 
@@ -73,7 +93,7 @@ _NOOP_SPAN = _NoopSpan()
 
 
 class _Span:
-    __slots__ = ("_tr", "name", "cat", "tid", "args", "_t0")
+    __slots__ = ("_tr", "name", "cat", "tid", "args", "_t0", "_ann")
 
     def __init__(self, tr: "Tracer", name: str, cat: str,
                  tid: Optional[int], args: Optional[dict]):
@@ -83,28 +103,40 @@ class _Span:
         self.tid = tid
         self.args = args
         self._t0 = 0
+        self._ann = None
+
+    def set(self, **args):
+        """Args known only when the work is done (counts, outcomes)."""
+        self.args = {**self.args, **args} if self.args else args
 
     def __enter__(self):
+        if _profiling():
+            # the same span on the device trace's clock, for whoever opens
+            # the capture; args known at entry ride along as its stats
+            self._ann = TraceAnnotation(self.name, **(self.args or {}))
+            self._ann.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb):
+        t1 = time.perf_counter_ns()
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         self._tr._complete(self.name, self.cat, self.tid, self.args,
-                           self._t0, time.perf_counter_ns())
+                           self._t0, t1)
         return False
 
 
 class Tracer:
-    """One process-wide event buffer; ts are µs since :func:`enable_tracing`."""
+    """One event buffer; ts are µs since it was made (by
+    :func:`enable_tracing`, or at the first call inside a profiler
+    session)."""
 
     def __init__(self):
         self._origin_ns = time.perf_counter_ns()
         self._pid = os.getpid()
         self._events: List[Dict[str, Any]] = []
         self._chains: set = set()          # lifecycle ids with an open "b"
-        self._seq = 0                      # completed-span counter (injection)
-        self._inject_drop = (
-            os.environ.get("OBS_GATE_INJECT") == "drop-span")
 
     # -- clock ---------------------------------------------------------------
 
@@ -125,17 +157,13 @@ class Tracer:
         return _Span(self, name, cat, tid, args)
 
     def _complete(self, name, cat, tid, args, t0_ns, t1_ns):
-        self._seq += 1
-        if self._inject_drop and self._seq % 5 == 2:
-            return                       # OBS_GATE_INJECT=drop-span
         ev = {"name": name, "cat": cat or "default", "ph": "X",
               "ts": self._ts(t0_ns), "dur": (t1_ns - t0_ns) / 1000.0,
               "pid": self._pid, "tid": self._tid(tid)}
         if args:
             ev["args"] = args
         self._events.append(ev)          # atomic under the GIL
-        from .flight import flight as _get_flight
-        _get_flight().record_span(name, cat, ev["dur"], args)
+        _flight().record_span(name, cat, ev["dur"], args)
 
     def instant(self, name: str, cat: str = "", tid: Optional[int] = None,
                 args: Optional[dict] = None) -> None:
@@ -244,10 +272,36 @@ def disable_tracing() -> None:
         _tracer = None
 
 
+def _follow() -> Optional[Tracer]:
+    """The buffer of the active profiler session, or None outside one.  A
+    session is noticed at the first call inside it (the buffer of the one
+    before goes then) and its end at the first call after it: two sessions
+    with no call between them read as one."""
+    global _session, _in_session
+    if not _profiling():
+        if _in_session:
+            _in_session = False
+        return None
+    if not _in_session:
+        with _lock:
+            if not _in_session:
+                _session = Tracer()
+                _in_session = True
+    return _session
+
+
 def tracer() -> Optional[Tracer]:
-    """The live tracer, or None when tracing is disabled.  Hot loops read
-    this ONCE per step and branch, so the disabled cost is one global
-    read per step, not per op."""
+    """The live tracer, or None when nothing records: the one
+    :func:`enable_tracing` installed, else the buffer of the active
+    profiler session.  Hot loops read this ONCE per step and branch, so
+    the disabled cost is one global read and one 24-ns call per step, not
+    per op."""
+    return _tracer or _follow()
+
+
+def explicit_tracer() -> Optional[Tracer]:
+    """The tracer :func:`enable_tracing` installed, or None: for a consumer
+    that must not change what it does because someone is profiling."""
     return _tracer
 
 
@@ -255,11 +309,20 @@ def trace_enabled() -> bool:
     return _tracer is not None
 
 
+def profiled_events() -> List[Dict[str, Any]]:
+    """What the current or last profiler session recorded (empty if there
+    was none, or if :func:`enable_tracing` took the events instead)."""
+    _follow()                      # a reader after the session ends it
+    s = _session
+    return s.events() if s is not None else []
+
+
 def span(name: str, cat: str = "", tid: Optional[int] = None,
          args: Optional[dict] = None):
-    """``with obs.span("name", cat, args={...}):`` — no-op singleton when
-    tracing is disabled (no allocation, no locking)."""
-    t = _tracer
+    """``with obs.span("name", cat, args={...}) as sp:`` — no-op singleton
+    when nothing records (no allocation, no locking).  ``sp.set(k=v)`` adds
+    args known only at the end."""
+    t = _tracer or _follow()
     if t is None:
         return _NOOP_SPAN
     return t.span(name, cat, tid=tid, args=args)
@@ -267,7 +330,7 @@ def span(name: str, cat: str = "", tid: Optional[int] = None,
 
 def instant(name: str, cat: str = "", tid: Optional[int] = None,
             args: Optional[dict] = None) -> None:
-    t = _tracer
+    t = _tracer or _follow()
     if t is None:
         return
     t.instant(name, cat, tid=tid, args=args)

@@ -118,7 +118,11 @@ class GenRequest:
     _stopped: bool = field(default=False)
     _emitted: bool = field(default=False)
     _prefill_dt: float = field(default=0.0)
-    _queued_t: float = field(default=0.0)  # perf_counter at add_request
+    # perf_counter stamps: add_request, the dispatch of the first prefill,
+    # the first token on the host (kept through an eviction's requeue)
+    _queued_t: float = field(default=0.0)
+    _dispatch_t: float = field(default=0.0)
+    _first_t: float = field(default=0.0)
 
 
 @dataclass
@@ -129,6 +133,7 @@ class RequestOutput:
     finish_reason: str                     # "stop" | "length"
     prefill_time: float = 0.0
     finish_time: float = 0.0
+    ttft_s: float = 0.0        # add_request -> first token on the host
 
 
 @dataclass(eq=False)
@@ -301,7 +306,8 @@ class Engine:
         self._sched_version = 0
         self._staged = None                    # (version, tbl, lengths, ...)
         self._last_dispatch_t: Optional[float] = None
-        self._decode_gaps: List[float] = []
+        # recent decode-visible gaps (bounded: a server runs for ever)
+        self._decode_gaps = collections.deque(maxlen=4096)
         self._full_tok_bufs: List[object] = []
         self._full_first_bufs: List[object] = []
         # deferred-sync state: dispatch-ordered ledger of unmaterialized
@@ -342,6 +348,28 @@ class Engine:
             args.setdefault("replica", self.obs_replica)
         tr.lifecycle_begin(req.request_id)
         tr.lifecycle_mark(req.request_id, phase, args=args or None)
+
+    def _obs_dispatched(self, req: GenRequest, t0: float) -> None:
+        """A prefill of ``req`` is dispatched at ``t0``; the first one ends
+        its wait for a slot, blocks and the running step."""
+        if req._dispatch_t or not req._queued_t:
+            return
+        req._dispatch_t = t0
+        obs.registry().histogram(
+            "serve.queue_wait_ms", **self._obs_labels()).observe(
+                (t0 - req._queued_t) * 1e3)
+
+    def _obs_first_token(self, req: GenRequest, now: float) -> None:
+        """The first token of ``req`` is on the host at ``now``: the ONE
+        place TTFT is taken (once a request; an evicted request's requeue
+        keeps the stamp)."""
+        if req._first_t or not req._queued_t:
+            return
+        req._first_t = now
+        obs.registry().histogram(
+            "serve.ttft_ms", **self._obs_labels()).observe(
+                (now - req._queued_t) * 1e3)
+        self._obs_mark(req, "first-token")
 
     # -- public API ---------------------------------------------------------
 
@@ -446,15 +474,22 @@ class Engine:
         """Admit + prefill new requests, run one decode chunk, sync, and
         return any requests that finished (streaming semantics: every step
         materializes its tokens)."""
-        self._round()
-        self._sync_pending()
-        reg = obs.registry()
-        lbl = self._obs_labels()
-        reg.gauge("serve.queue_depth", **lbl).set(len(self._waiting))
-        reg.gauge("serve.batch_occupancy", **lbl).set(
-            sum(1 for s in self._slots if s.req is not None)
-            / max(1, self.max_batch))
-        return self._drain_ready()
+        with obs.span("serve.step", cat="serve"):
+            self._round()
+            self._sync_pending()
+            reg = obs.registry()
+            lbl = self._obs_labels()
+            reg.gauge("serve.queue_depth", **lbl).set(len(self._waiting))
+            reg.gauge("serve.batch_occupancy", **lbl).set(
+                sum(1 for s in self._slots if s.req is not None)
+                / max(1, self.max_batch))
+            return self._drain_ready()
+
+    def token_counts(self) -> Dict[str, int]:
+        """``{request_id: tokens on the host}`` of the requests that hold a
+        slot: what a streaming client may read after a ``step()``."""
+        return {s.req.request_id: len(s.req.prior_output) + len(s.req._out_vals)
+                for s in self._slots if s.req is not None}
 
     def run_to_completion(self) -> List[RequestOutput]:
         """Drain the queue.  While no ACTIVE request uses eos the schedule is
@@ -551,15 +586,22 @@ class Engine:
         return -(-n // self.block_size) * self.block_size
 
     def _admit(self):
+        with obs.span("serve.admit", cat="serve") as sp:
+            n = self._admit_waiting()
+            sp.set(admitted=n, waiting=len(self._waiting))
+
+    def _admit_waiting(self) -> int:
         """Admit waiting requests into free slots, then prefill them in
         same-bucket BATCHES (size ladder 4/2/1): 16 admissions as 16 single
         prefills would pay 16 dispatches where ~5 batched ones do.  Each
         admission's
         program inputs are snapshotted at admit time (the padding blocks are
         released immediately after — unallocated table entries write to the
-        trash block, which the length mask never attends)."""
+        trash block, which the length mask never attends).  Returns how many
+        it admitted."""
         bs = self.block_size
         admitted = []      # (slot, req, Pb, ids_row, blocks_row, P)
+        n_chunked = 0      # admitted on path B
         for slot in self._slots:
             if not self._waiting:
                 break
@@ -652,6 +694,7 @@ class Engine:
                     **self._obs_labels()).inc(n_hit)
             self._obs_mark(req, "admitted", path="chunked",
                            hit_blocks=n_hit)
+            n_chunked += 1
         by_bucket: Dict[int, list] = {}
         for entry in admitted:
             by_bucket.setdefault(entry[2], []).append(entry)
@@ -670,6 +713,7 @@ class Engine:
             if slot.req is req and slot.out_count >= req.max_new_tokens:
                 self._finish_order.append(req)
                 self._release(slot)
+        return len(admitted) + n_chunked
 
     def _write_tbl_row(self, slot: _Slot):
         i = slot.idx
@@ -715,7 +759,9 @@ class Engine:
             self._first_idx += 1
         else:
             fidx0 = self._first_idx        # unused by the non-final program
+        self._obs_mark(req, "prefill-chunk", take=take, final=final)
         t0 = time.perf_counter()
+        self._obs_dispatched(req, t0)
         with obs.span("serve.prefill-chunk", cat="serve",
                       args={"bucket": Cb, "final": final}):
             self._first_buf, self._last_dev, self.k_pools, self.v_pools = fn(
@@ -737,10 +783,8 @@ class Engine:
         self.stats["prefill_time"] += dt
         self.stats["prefill_tokens"] += Cb
         self.stats["chunk_prefills"] += 1
-        reg = obs.registry()
-        lbl = self._obs_labels()
-        reg.counter("serve.prefill_tokens", **lbl).inc(Cb)
-        self._obs_mark(req, "prefill-chunk", take=take, final=final)
+        obs.registry().counter(
+            "serve.prefill_tokens", **self._obs_labels()).inc(Cb)
         if final:
             slot.out_count = 1
             self._pending.append(
@@ -748,9 +792,6 @@ class Engine:
             self.stats["prefills"] += 1
             self.stats["generated_tokens"] += 1
             self._register_prompt_blocks(slot)
-            if req._queued_t:
-                reg.histogram("serve.ttft_ms", **lbl).observe(
-                    (t0 + dt - req._queued_t) * 1e3)
             if slot.out_count >= req.max_new_tokens:
                 self._finish_order.append(req)
                 self._release(slot)
@@ -821,7 +862,9 @@ class Engine:
             request_id=req.request_id,
             orig_prompt_ids=(req.orig_prompt_ids if req.orig_prompt_ids
                              is not None else req.prompt_ids),
-            prior_output=req.prior_output + list(req._out_vals))
+            prior_output=req.prior_output + list(req._out_vals),
+            _queued_t=req._queued_t, _dispatch_t=req._dispatch_t,
+            _first_t=req._first_t)
         self._waiting.appendleft(requeued)
         self._release(slot)
         self.stats["evictions"] += 1
@@ -922,6 +965,8 @@ class Engine:
             self._first_idx = 0
         fidx0 = self._first_idx
         self._first_idx += n
+        for _slot, req, *_rest in group:
+            self._obs_mark(req, "prefill", bucket=Pb, batch=n)
         t0 = time.perf_counter()
         with obs.span("serve.prefill", cat="serve",
                       args={"bucket": Pb, "n": n}):
@@ -932,22 +977,17 @@ class Engine:
                 jnp.asarray(temps), jnp.asarray(top_ks), jnp.asarray(top_ps),
                 self._first_buf, jnp.asarray(fidx0, jnp.int32))
         dt = time.perf_counter() - t0                    # dispatch cost only
-        reg = obs.registry()
-        lbl = self._obs_labels()
-        ttft = reg.histogram("serve.ttft_ms", **lbl)
-        for j, (slot, req, *_rest) in enumerate(group):
+        for j, (_slot, req, *_rest) in enumerate(group):
             req._prefill_dt = dt
+            self._obs_dispatched(req, t0)
             self._pending.append(
                 ("prefill", req, len(self._full_first_bufs), fidx0 + j))
-            # first token is sampled by this call: TTFT-to-dispatch
-            if req._queued_t:
-                ttft.observe((t0 + dt - req._queued_t) * 1e3)
-            self._obs_mark(req, "prefill", bucket=Pb, batch=n)
         self.stats["prefills"] += n
         self.stats["prefill_time"] += dt
         self.stats["prefill_tokens"] += n * Pb
         self.stats["generated_tokens"] += n
-        reg.counter("serve.prefill_tokens", **lbl).inc(n * Pb)
+        obs.registry().counter(
+            "serve.prefill_tokens", **self._obs_labels()).inc(n * Pb)
 
     def _build_prefill(self, Pb: int, n: int):
         from ..jit import functional_call
@@ -1049,7 +1089,9 @@ class Engine:
             self._first_idx = 0
         fidx0 = self._first_idx
         self._first_idx += 1
+        self._obs_mark(req, "prefill", bucket=Pb, batch=1)
         t0 = time.perf_counter()
+        self._obs_dispatched(req, t0)
         with obs.span("serve.prefill", cat="serve",
                       args={"bucket": Pb, "n": 1}):
             (self._first_buf, self._last_dev, self._ssd_state, self.k_pools,
@@ -1071,13 +1113,8 @@ class Engine:
         self.stats["prefill_time"] += dt
         self.stats["prefill_tokens"] += Pb
         self.stats["generated_tokens"] += 1
-        reg = obs.registry()
-        lbl = self._obs_labels()
-        reg.counter("serve.prefill_tokens", **lbl).inc(Pb)
-        if req._queued_t:
-            reg.histogram("serve.ttft_ms", **lbl).observe(
-                (t0 + dt - req._queued_t) * 1e3)
-        self._obs_mark(req, "prefill", bucket=Pb, batch=1)
+        obs.registry().counter(
+            "serve.prefill_tokens", **self._obs_labels()).inc(Pb)
 
     def _build_ssd_decode(self, k: int):
         """The decode-chunk program with the slot-state arrays threaded
@@ -1115,11 +1152,17 @@ class Engine:
         return decode
 
     def _dispatch_chunk(self, k: int):
+        with obs.span("serve.dispatch", cat="serve") as sp:
+            staged, live = self._dispatch_decode(k)
+            sp.set(k=k, staged=staged, live=live)
+
+    def _dispatch_decode(self, k: int):
         """Dispatch one k-sub-step decode chunk asynchronously and account
         for it: ownership ledger, host length mirrors, dispatch-decided
         finishes (a finish frees its blocks NOW — the chunk's garbage tail
         writes land before any later prefill reuses them, because device
-        execution preserves dispatch order)."""
+        execution preserves dispatch order).  Returns whether the staged
+        scheduler arrays were reused and how many slots decoded."""
         from ..framework import random as rnd
 
         # slots mid-chunked-prefill are NOT decoded: masked inactive
@@ -1215,6 +1258,7 @@ class Engine:
                 self._release(s)
         self._pending.append(
             ("chunk", len(self._full_tok_bufs), row0, k, recs))
+        return staged, len(recs)
 
     def _build_decode(self, k: int):
         from ..jit import functional_call
@@ -1278,9 +1322,19 @@ class Engine:
         trash block absorbing all writes), so no XLA compile lands inside a
         serving window.  Dummy EXECUTION rather than AOT ``.lower().compile()``
         because only a real call warms jit's dispatch cache."""
+        t0 = time.perf_counter()
+        n = self._run_warmup()
+        reg = obs.registry()
+        lbl = self._obs_labels()
+        reg.gauge("serve.warmup_s", **lbl).set(time.perf_counter() - t0)
+        reg.gauge("serve.warmup_programs", **lbl).set(n)
+
+    def _run_warmup(self) -> int:
+        """Run the ladder; returns how many engine programs it called."""
         from ..framework import random as rnd
 
         zeros = np.zeros((self.max_batch,), np.int32)
+        n_prog = 0
         k = 1
         while k <= self.decode_chunk:
             if self._recurrent:
@@ -1300,6 +1354,7 @@ class Engine:
                 buf, _lst, self.k_pools, self.v_pools, _lens = fn(
                     *self._decode_dummy_args())
             jax.block_until_ready(buf)
+            n_prog += 1
             k *= 2
         if self._recurrent:
             for Pb in self.prefill_buckets:
@@ -1317,8 +1372,9 @@ class Engine:
                     jnp.asarray(1.0, jnp.float32),
                     jnp.zeros((self._first_seg,), jnp.int32),
                     jnp.asarray(0, jnp.int32))
+                n_prog += 1
             jax.block_until_ready(self._ssd_state)
-            return
+            return n_prog
         for Pb in self.prefill_buckets:
             for n in (1, 2, 4):
                 if n > self.max_batch:
@@ -1334,6 +1390,7 @@ class Engine:
                     jnp.zeros((n,), jnp.int32), jnp.ones((n,), jnp.float32),
                     jnp.zeros((self._first_seg,), jnp.int32),
                     jnp.asarray(0, jnp.int32))
+                n_prog += 1
         if self.prefix_cache or self.prefill_chunk is not None:
             # chunk-prefill family: final variant at every bucket (suffix
             # prefill picks its bucket by suffix length), non-final only at
@@ -1353,7 +1410,9 @@ class Engine:
                     jnp.asarray(0, jnp.int32), jnp.asarray(1.0, jnp.float32),
                     jnp.zeros((self._first_seg,), jnp.int32),
                     jnp.asarray(0, jnp.int32))
+                n_prog += 1
         jax.block_until_ready(self.k_pools)
+        return n_prog
 
     # -- deferred-sync materialization --------------------------------------
 
@@ -1361,36 +1420,48 @@ class Engine:
         """Materialize every pending token in ONE fused readback per kind,
         walk the ledger in dispatch order filling request values (honoring
         eos cuts), and emit finished outputs into the ready queue."""
+        if not self._pending and not self._finish_order:
+            return
         if self._pending:
             self.stats["syncs"] += 1
             t0 = time.perf_counter()
             # the programs accumulated every sampled token into device-side
             # segment buffers, so the backlog materializes in a handful of
-            # reads no matter how many calls were dispatched
-            tok_segs = [np.asarray(b)
-                        for b in (*self._full_tok_bufs, self._tok_buf)]
-            first_segs = [np.asarray(b)
-                          for b in (*self._full_first_bufs, self._first_buf)]
+            # reads no matter how many calls were dispatched; the host is
+            # blocked on the device for as long as these reads take
+            with obs.span("serve.readback", cat="serve"):
+                tok_segs = [np.asarray(b)
+                            for b in (*self._full_tok_bufs, self._tok_buf)]
+                first_segs = [np.asarray(b) for b in
+                              (*self._full_first_bufs, self._first_buf)]
+            t_read = time.perf_counter()
+        with obs.span("serve.absorb", cat="serve") as sp:
+            n_tok, n_ready = 0, len(self._ready)
             for e in self._pending:
                 if e[0] == "prefill":
                     _, req, seg, fidx = e
+                    self._obs_first_token(req, t_read)
                     self._absorb(req, [int(first_segs[seg][fidx])])
+                    n_tok += 1
                 else:
                     _, seg, row0, kk, recs = e
                     rows = tok_segs[seg][row0:row0 + kk]
                     for req, idx, take in recs:
                         self._absorb(req, rows[:take, idx].tolist())
-            self._pending.clear()
-            self._full_tok_bufs.clear()
-            self._full_first_bufs.clear()
-            self._tok_row = 0
-            self._first_idx = 0
-            self.stats["sync_time"] = (self.stats.get("sync_time", 0.0)
-                                       + time.perf_counter() - t0)
-        for req in self._finish_order:
-            if not req._emitted:
-                self._ready.append(self._emit(req, "length"))
-        self._finish_order.clear()
+                        n_tok += take
+            if self._pending:
+                self._pending.clear()
+                self._full_tok_bufs.clear()
+                self._full_first_bufs.clear()
+                self._tok_row = 0
+                self._first_idx = 0
+                self.stats["sync_time"] = (self.stats.get("sync_time", 0.0)
+                                           + time.perf_counter() - t0)
+            for req in self._finish_order:
+                if not req._emitted:
+                    self._ready.append(self._emit(req, "length"))
+            self._finish_order.clear()
+            sp.set(tokens=n_tok, finished=len(self._ready) - n_ready)
 
     def _absorb(self, req: GenRequest, vals: List[int]):
         """Append materialized tokens to a request, cutting at eos (the
@@ -1434,7 +1505,9 @@ class Engine:
             output_ids=req.prior_output + list(req._out_vals),
             finish_reason=reason,
             prefill_time=req._prefill_dt,
-            finish_time=time.time())
+            finish_time=time.time(),
+            ttft_s=(req._first_t - req._queued_t
+                    if req._first_t and req._queued_t else 0.0))
 
     def _drain_ready(self) -> List[RequestOutput]:
         out, self._ready = self._ready, []

@@ -26,12 +26,6 @@
 # rel_err / overhead are wall-clock-derived: recorded for provenance,
 # gated only against the absolute bounds above, never diffed.
 #
-# Defect injection (proves the gate can fail):
-#     OBS_GATE_INJECT=drop-span scripts/obs_gate.sh   # must exit != 0
-#   (the tracer drops every 5th completed span; the conformance suite's
-#   exact span accounting catches the loss — note the bubble crosscheck
-#   alone would NOT, its per-identity median reconstruction tolerates a
-#   20% sample drop, which is why the gate runs both)
 # Refresh the baseline after an intentional change:
 #     scripts/obs_gate.sh --update
 # Exit code: number of failed checks (0 = gate passes).

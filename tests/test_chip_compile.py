@@ -59,13 +59,18 @@ def _flash_fwd_bwd(one_chip, b, s):
         o = flash_attention.flash_attention(q, k, v, causal=True)
         return jnp.sum(o.astype(F32))
 
-    _compile(one_chip, jax.grad(loss, argnums=(0, 1, 2)),
-             ((b, s, H, D), BF16), ((b, s, HK, D), BF16), ((b, s, HK, D), BF16))
+    return _compile(one_chip, jax.grad(loss, argnums=(0, 1, 2)),
+                    ((b, s, H, D), BF16), ((b, s, HK, D), BF16),
+                    ((b, s, HK, D), BF16))
 
 
 def test_flash_fwd_bwd_resident(one_chip, kernel_branch):
     assert flash_attention._resident_ok(S, D, 2)
-    _flash_fwd_bwd(one_chip, B, S)
+    text = _flash_fwd_bwd(one_chip, B, S).as_text()
+    # the device line of a trace names an op by its instruction: the
+    # kernels' names have to be in it (PERF.md's breakdowns read them)
+    for name in ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq"):
+        assert f"jvp_{name}_" in text, name
 
 
 def test_flash_fwd_bwd_streaming(one_chip, kernel_branch):

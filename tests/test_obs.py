@@ -157,14 +157,112 @@ class TestTracer:
         assert any("negative dur" in p for p in probs)
         assert obs.validate_chrome_trace({}) == ["missing traceEvents key"]
 
-    def test_drop_span_injection(self, monkeypatch):
-        monkeypatch.setenv("OBS_GATE_INJECT", "drop-span")
-        tr = obs.enable_tracing()              # injection read at install
-        for _ in range(10):
-            with tr.span("s", cat="c"):
+
+# -- following the profiler ---------------------------------------------------
+
+
+@pytest.fixture
+def profiler(tmp_path):
+    """``start()`` opens a JAX profiler session, ``stop()`` closes it and
+    returns the capture; a test that fails in between leaves none open."""
+    import jax
+
+    class _P:
+        on = False
+
+        def start(self):
+            jax.profiler.start_trace(str(tmp_path))
+            self.on = True
+
+        def stop(self):
+            from benchmarks.tracered import find_xplane
+
+            jax.profiler.stop_trace()
+            self.on = False
+            return jax.profiler.ProfileData.from_file(
+                find_xplane(str(tmp_path)))
+
+    p = _P()
+    yield p
+    if p.on:
+        jax.profiler.stop_trace()
+    obs.profiled_events()          # the first call after a session ends it
+
+
+def _names(events, ph="X"):
+    return [e["name"] for e in events if e["ph"] == ph]
+
+
+class TestFollowsProfiler:
+    def test_span_is_recorded_and_lies_in_the_capture(self, profiler):
+        profiler.start()
+        with obs.span("serve.step", cat="serve", args={"k": 3}) as sp:
+            with obs.span("serve.readback", cat="serve"):
                 pass
-        kept = [e for e in tr.events() if e["ph"] == "X"]
-        assert len(kept) == 8                  # seq 2 and 7 dropped
+            sp.set(tokens=7)
+        data = profiler.stop()
+        evs = obs.profiled_events()
+        assert _names(evs) == ["serve.readback", "serve.step"]
+        assert evs[1]["args"] == {"k": 3, "tokens": 7}
+        host = next(pl for pl in data.planes if pl.name == "/host:CPU")
+        seen = {e.name: e for ln in host.lines for e in ln.events
+                if e.name.startswith("serve.")}
+        assert set(seen) == {"serve.step", "serve.readback"}
+        # the capture holds the same nesting, on its own clock
+        outer, inner = seen["serve.step"], seen["serve.readback"]
+        assert outer.start_ns <= inner.start_ns
+        assert (inner.start_ns + inner.duration_ns
+                <= outer.start_ns + outer.duration_ns)
+
+    def test_nothing_is_recorded_between_sessions(self, profiler):
+        profiler.start()
+        with obs.span("in-session"):
+            pass
+        profiler.stop()
+        assert obs.span("after") is obs.span("after-too")   # the no-op
+        with obs.span("after"):
+            pass
+        obs.instant("after")
+        assert obs.tracer() is None
+        assert _names(obs.profiled_events()) == ["in-session"]
+
+    def test_next_session_starts_with_an_empty_buffer(self, profiler):
+        profiler.start()
+        with obs.span("first"):
+            pass
+        profiler.stop()
+        with obs.span("between"):
+            pass
+        profiler.start()
+        with obs.span("second"):
+            pass
+        assert _names(obs.profiled_events()) == ["second"]
+        profiler.stop()
+        assert _names(obs.profiled_events()) == ["second"]
+
+    def test_explicit_tracer_does_not_follow(self, profiler):
+        """The MPMD executor blocks per op when ITS tracer is live; a
+        profiler session alone must not switch that on."""
+        profiler.start()
+        assert obs.tracer() is not None
+        assert obs.explicit_tracer() is None and not obs.trace_enabled()
+        tr = obs.enable_tracing()
+        assert obs.explicit_tracer() is tr and obs.tracer() is tr
+        profiler.stop()
+        assert obs.tracer() is tr                  # explicit tracing stays
+
+    def test_enabled_tracing_keeps_its_buffer_through_a_session(
+            self, profiler):
+        tr = obs.enable_tracing()
+        with obs.span("before"):
+            pass
+        profiler.start()
+        with obs.span("during"):
+            pass
+        data = profiler.stop()
+        assert _names(tr.events()) == ["before", "during"]
+        host = next(pl for pl in data.planes if pl.name == "/host:CPU")
+        assert "during" in {e.name for ln in host.lines for e in ln.events}
 
 
 # -- metrics -----------------------------------------------------------------
@@ -336,6 +434,45 @@ class TestServingLifecycle:
                    if e.get("id") == rids_on[0] and e["ph"] == "e")
         assert end["args"]["tokens"] == len(outs_on[rids_on[0]])
         assert obs.validate_chrome_trace(tr.to_chrome_trace()) == []
+
+    def test_outputs_bit_identical_under_a_profiler_session(
+            self, tiny_model, profiler):
+        """Follow mode: nobody called enable_tracing(); the engine's spans
+        and the request chains record because a profiler session is on."""
+        from paddle_tpu.serving import GenRequest
+
+        cfg = tiny_model.config
+        prompts = _prompts(cfg, (20, 45, 33))
+
+        def run():
+            eng = _engine(tiny_model)
+            rids = [eng.add_request(GenRequest(prompt_ids=p,
+                                               max_new_tokens=6))
+                    for p in prompts]
+            outs = {}
+            while eng.has_work():
+                for o in eng.step():
+                    outs[o.request_id] = o.output_ids
+            return rids, outs
+
+        _, outs_off = run()
+        profiler.start()
+        rids, outs_on = run()
+        profiler.stop()
+        assert outs_on == outs_off, "a profiler session changed the tokens"
+        evs = obs.profiled_events()
+        spans = set(_names(evs))
+        assert {"serve.step", "serve.admit", "serve.prefill",
+                "serve.dispatch", "serve.decode-chunk", "serve.readback",
+                "serve.absorb"} <= spans
+        for rid in rids:
+            phases = [e["name"] for e in evs
+                      if e.get("id") == rid and e["ph"] == "n"]
+            assert phases.count("first-token") == 1
+            assert (phases.index("queued") < phases.index("prefill")
+                    < phases.index("first-token"))
+        assert obs.validate_chrome_trace(
+            {"traceEvents": evs}) == []
 
     def test_registry_metrics_flow(self, tiny_model):
         from paddle_tpu.serving import GenRequest

@@ -614,14 +614,25 @@ def write_paged_token(k_pool, v_pool, block_table, lengths, k_new, v_new):
 
     k_new/v_new: ``[B, 1, Hk, D]``. Target: block ``table[b, lengths[b]//bs]``
     slot ``lengths[b] % bs``. Inactive slots (length 0, table row pointing at
-    the reserved trash block) harmlessly write there."""
+    the reserved trash block) harmlessly write there.
+
+    The write is a scatter of ``B*Hk`` rows of ``D`` into the pool viewed
+    ``[NB*Hk*bs, D]`` (a bitcast of the row-major pool), row
+    ``(phys*Hk + head)*bs + slot``.  Written as ``pool.at[phys, :, slot]``
+    the scatter's window spans heads and ``D``, for which the TPU compiler
+    lays the operand out ``{3,1,2,0}``; the decode kernels read row-major, so
+    every layer of every decode step would copy both whole pools."""
     nb, hk, bs, d = k_pool.shape
     lengths = jnp.asarray(lengths, jnp.int32)
     phys = jnp.take_along_axis(block_table, (lengths // bs)[:, None], axis=1)[:, 0]
-    slot = lengths % bs
-    k_pool = k_pool.at[phys, :, slot].set(k_new[:, 0])
-    v_pool = v_pool.at[phys, :, slot].set(v_new[:, 0])
-    return k_pool, v_pool
+    rows = ((phys[:, None] * hk + jnp.arange(hk)) * bs
+            + (lengths % bs)[:, None]).reshape(-1)                   # [B*Hk]
+
+    def write(pool, new):
+        flat = pool.reshape(-1, d).at[rows].set(new.reshape(-1, d))
+        return flat.reshape(pool.shape)
+
+    return write(k_pool, k_new), write(v_pool, v_new)
 
 
 def paged_chunk_attention(q, k_pool, v_pool, block_table, ctx_lengths,

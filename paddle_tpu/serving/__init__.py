@@ -41,7 +41,11 @@ TPU-native design:
   (recompute-style preemption) — admission control the reference does with
   its block manager.
 
-Pools are donated through the decode step, so XLA updates them in place.
+Pools are donated through the decode step, and the per-token write
+(``kernels.decode_attention.write_paged_token``) leaves them in the row-major
+layout the decode kernels read, so XLA updates them in place: the compiled
+chunk copies no pool, per layer or around its scan
+(``tests/test_chip_compile.py`` holds it to that).
 
 **Cache backends.** What a sequence's "cache" IS is a policy, not a fact:
 the engine's block bookkeeping lives behind the ``CacheBackend`` seam

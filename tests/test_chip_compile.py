@@ -128,6 +128,41 @@ def test_decode_wrappers_pick_the_fused_kernels(one_chip, kernel_branch):
     _compile(one_chip, decode_attention.paged_decode_attention, *_PAGED)
 
 
+@pytest.mark.parametrize("k", [1, 4])
+def test_decode_chunk_copies_no_pool(one_chip, kernel_branch, k):
+    """The engine's k-step decode program at the serving cell's attention
+    widths (8 kv heads x 128, blocks of 128, 32 slots): the token write
+    leaves the pools in the row-major layout ``paged_decode_fused`` reads,
+    so the scan carries them without a copy of a pool, per layer or around
+    the loop, and needs less scratch than one pool."""
+    import re
+
+    import paddle_tpu as paddle
+    from paddle_tpu.models import LlamaForCausalLM
+    from paddle_tpu.models.llama import LlamaConfig
+    from paddle_tpu.serving import Engine
+
+    paddle.seed(0)
+    model = LlamaForCausalLM(LlamaConfig(
+        vocab_size=1024, hidden_size=H * D, intermediate_size=512,
+        num_hidden_layers=2, num_attention_heads=H, num_key_value_heads=HK,
+        max_position_embeddings=1024, dtype="bfloat16"))
+    eng = Engine(model, max_batch=32, num_blocks=48, block_size=BS,
+                 prefill_buckets=(BS,))
+    pool = eng.k_pools[0]
+    assert pool.shape == (48, HK, BS, D) and pool.dtype == BF16
+    args = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        eng._decode_dummy_args())
+    compiled = eng._get_decode_fn(k).lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "paged_decode_fused" in text
+    dims = ",".join(map(str, pool.shape))
+    copies = re.findall(rf"= \w+\[{dims}\]\S* copy\(.*", text)
+    assert not copies, copies[:2]
+    assert compiled.memory_analysis().temp_size_in_bytes < pool.nbytes
+
+
 def test_ssd_scan(one_chip):
     # ssd_8b_config: 64 heads, state 128, head dim 64, chunk 128; seq 2048
     G, T, P, N, chunk = 64, 2048, 64, 128, 128

@@ -89,6 +89,50 @@ def test_write_paged_token_and_prefill_roundtrip():
                                np.asarray(k_new[0, 0]))
 
 
+# lengths per decode step, blocks of 8: 0 = an inactive slot, whose table
+# row points at the trash block
+_TOKEN_WRITES = {
+    "ragged": [[13, 3, 22, 9]],
+    "first_slot_of_a_block": [[8, 16, 13, 3]],
+    "last_slot_of_a_block": [[7, 23, 15, 3]],
+    "into_the_next_block": [[7, 14, 15, 3], [8, 15, 16, 4], [9, 16, 17, 5]],
+    "inactive_slots_share_the_trash_block": [[0, 11, 0, 0], [0, 12, 0, 0]],
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["fp32", "bf16"])
+@pytest.mark.parametrize("steps", _TOKEN_WRITES.values(),
+                         ids=_TOKEN_WRITES.keys())
+def test_write_paged_token_matches_per_sequence_loop(steps, dtype):
+    B, Hk, D, bs, MAXB = 4, 2, 16, 8, 3
+    NB = 1 + B * MAXB
+    rng = np.random.RandomState(2)
+    k0, v0 = (jnp.asarray(rng.randn(NB, Hk, bs, D), dtype) for _ in "kv")
+    kp, vp = k0, v0
+    want_k, want_v = np.array(k0), np.array(v0)
+    trash = np.zeros(want_k.shape, bool)
+    for lengths in steps:
+        lengths = np.asarray(lengths, np.int32)
+        tbl = 1 + np.arange(B * MAXB, dtype=np.int32).reshape(B, MAXB)
+        tbl[lengths == 0] = 0
+        k_new, v_new = (jnp.asarray(rng.randn(B, 1, Hk, D), dtype)
+                        for _ in "kv")
+        kp, vp = da.write_paged_token(kp, vp, jnp.asarray(tbl),
+                                      jnp.asarray(lengths), k_new, v_new)
+        for b in range(B):
+            phys, slot = tbl[b, lengths[b] // bs], lengths[b] % bs
+            want_k[phys, :, slot] = np.asarray(k_new[b, 0])
+            want_v[phys, :, slot] = np.asarray(v_new[b, 0])
+            trash[phys, :, slot] |= lengths[b] == 0
+    assert kp.dtype == dtype and kp.shape == k0.shape
+    # which inactive slot's token the trash block keeps is nobody's concern;
+    # everything else, written or not, is bit-identical to the loop's pool
+    for got, want in ((kp, want_k), (vp, want_v)):
+        np.testing.assert_array_equal(np.asarray(got)[~trash], want[~trash])
+    assert not trash[1:].any()
+
+
 # ---------------------------------------------------------------------------
 # engine end-to-end
 # ---------------------------------------------------------------------------

@@ -47,9 +47,9 @@ def kernel_branch(monkeypatch):
     monkeypatch.setattr(kernels, "use_pallas", lambda: True)
 
 
-def _compile(one_chip, fn, *shapes):
+def _compile(one_chip, fn, *shapes, donate=()):
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
-    compiled = jax.jit(fn).lower(*args).compile()
+    compiled = jax.jit(fn, donate_argnums=donate).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
     return compiled
 
@@ -88,14 +88,42 @@ def test_rms_norm_fwd_bwd(one_chip, kernel_branch):
              ((B, S, HID), BF16), ((HID,), BF16))
 
 
-def test_adamw_update(one_chip):
-    def step(p, g, m, v, lr, t):
-        return adamw.adamw_update(p, g, m, v, lr, t, beta1=0.9, beta2=0.95,
-                                  epsilon=1e-8, weight_decay=0.1,
-                                  out_dtype="bfloat16")
+@pytest.mark.parametrize("shape,out_dtype", [
+    # the benchmark's leaves (Mistral-7B widths, fp32 parameters) ...
+    ((32768, 4096), None), ((4096, 28672), None), ((14336, 4096), None),
+    ((4096, 6144), None),
+    # ... the base preset's MLP leaf with the bf16 copy written in the pass,
+    # and a norm weight, which keeps the flat [rows, 128] view
+    ((HID, INTER), "bfloat16"), ((4096,), None),
+], ids=lambda x: "x".join(map(str, x)) if isinstance(x, tuple) else str(x))
+def test_adamw_update(one_chip, shape, out_dtype):
+    """A leaf the kernel can cut along its own rows goes in and comes out
+    with no copy: under the (8, 128) tiling ``[r, c] -> [r*c/128, 128]`` is
+    a relayout of the whole array through HBM, seven times a leaf."""
+    import math
+    import re
 
-    z = ((HID, INTER), F32)
-    _compile(one_chip, step, z, z, z, z, ((), F32), ((), I32))
+    def step(p, g, m, v, lr, t):
+        out = adamw.adamw_update(p, g, m, v, lr, t, beta1=0.9, beta2=0.95,
+                                 epsilon=1e-8, weight_decay=0.1,
+                                 out_dtype=out_dtype)
+        return out if out_dtype else out[:3]  # p_out is p_new: one buffer
+
+    # donated as TrainStep donates them: an argument the caller keeps would
+    # be copied before the aliased call whatever the kernel's layout
+    z = (shape, F32)
+    compiled = _compile(one_chip, step, z, z, z, z, ((), F32), ((), I32),
+                        donate=(0, 2, 3))
+    text = compiled.as_text()
+    assert "adamw_fused" in text
+    if len(shape) < 2:
+        return
+    n = math.prod(shape)
+    moved = [m.group(0)[:120] for m in re.finditer(
+        r"= \w+\[([\d,]+)\]\S* (?:reshape|copy)\(.*", text)
+        if math.prod(map(int, m.group(1).split(","))) == n]
+    assert not moved, moved
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * n
 
 
 _DENSE = (((SB, 1, H, D), BF16), ((SB, CTX, HK, D), BF16),

@@ -29,7 +29,8 @@ import paddle_tpu as paddle
 import paddle_tpu.distributed as dist
 from paddle_tpu.framework import flags
 from paddle_tpu.framework.tensor import Parameter
-from paddle_tpu.kernels.adamw import adamw_reference, adamw_update
+from paddle_tpu.kernels.adamw import (NATIVE_BLOCK, adamw_reference,
+                                      adamw_update, native_view)
 
 HYP = dict(beta1=0.9, beta2=0.999, epsilon=1e-8)
 LR = 1e-3
@@ -99,6 +100,79 @@ def test_kernel_moments_exact_params_1ulp_split_fusion_shapes(shape):
     # the reference's own v_new-as-returned vs v_new-as-consumed split costs
     # 1 ulp here; the kernel is pinned to the consistent value
     assert _ulp_diff(rp, fp) <= 1
+
+
+# each side of the kernel's choice of layout: leaves it cuts along their own
+# rows (last dim a multiple of 128, second-last of 8), and leaves that keep
+# the flat [rows, 128] view
+NATIVE_SHAPES = [(8, 128), (16, 256), (3, 8, 128), (24, 384)]
+FLAT_SHAPES = [(5, 7), (8, 100), (4097,), (12, 128)]
+
+
+@pytest.mark.parametrize("shape,native",
+                         [(s, True) for s in NATIVE_SHAPES]
+                         + [(s, False) for s in FLAT_SHAPES], ids=str)
+def test_kernel_bit_exact_on_each_side_of_the_layout_choice(shape, native):
+    assert (native_view(shape) is not None) == native
+    seed = hash(shape) % 997
+    (rp, rm, rv), (fp, fm, fv, _) = _run_both(shape, seed=seed, **HYP,
+                                              weight_decay=WD)
+    np.testing.assert_array_equal(np.asarray(rm), np.asarray(fm))
+    np.testing.assert_array_equal(np.asarray(rv), np.asarray(fv))
+    # the reference's own 1-ulp split (module docstring) shows on some seeds
+    assert _ulp_diff(rp, fp) <= 1
+    # one body, two ways of cutting: the same leaf handed over flat gives
+    # the same bits
+    flat = adamw_update(*(x.reshape(-1) for x in _rand_state(shape, seed)),
+                        jnp.float32(LR), jnp.int32(3), interpret=True, **HYP,
+                        weight_decay=WD)
+    for a, b in zip(flat[:3], (fp, fm, fv)):
+        np.testing.assert_array_equal(np.asarray(a).reshape(shape),
+                                      np.asarray(b))
+
+
+def test_native_block_that_does_not_divide_the_leaf():
+    # both axes end in a ragged block of the kernel's own block size
+    rows, cols = NATIVE_BLOCK
+    shape = (rows + 8, cols + 128)
+    assert native_view(shape) == shape
+    (rp, rm, rv), (fp, fm, fv, _) = _run_both(shape, seed=13, **HYP,
+                                              weight_decay=WD)
+    np.testing.assert_array_equal(np.asarray(rm), np.asarray(fm))
+    np.testing.assert_array_equal(np.asarray(rv), np.asarray(fv))
+    assert _ulp_diff(rp, fp) <= 1
+
+
+def test_bf16_copy_narrows_the_native_side():
+    # the bf16 copy is tiled (16, 128): 8 rows collapse without a copy in
+    # fp32 only
+    assert native_view((3, 8, 128)) == (24, 128)
+    assert native_view((3, 8, 128), "bfloat16") is None
+    assert native_view((3, 16, 128), "bfloat16") == (48, 128)
+
+
+def test_fused_bytes_counted_by_layout():
+    from paddle_tpu import obs
+
+    def read():
+        return {lay: obs.registry().counter("optimizer.fused_bytes",
+                                            layout=lay).value
+                for lay in ("native", "flat")}
+
+    before = read()
+    lr, step = jnp.float32(LR), jnp.int32(1)
+
+    @jax.jit
+    def both(a, b):
+        return (adamw_update(*a, lr, step, interpret=True, **HYP),
+                adamw_update(*b, lr, step, interpret=True, **HYP))
+
+    a, b = _rand_state((16, 256), seed=1), _rand_state((8, 100), seed=2)
+    both(a, b)
+    both(a, b)  # a compiled step adds nothing: the count is the trace's
+    after = read()
+    assert after["native"] - before["native"] == 16 * 256 * 4
+    assert after["flat"] - before["flat"] == 8 * 100 * 4
 
 
 def test_master_weight_cast_written_in_same_pass():

@@ -20,15 +20,11 @@ Presets:
   decode — KV-cache greedy generation (prefill 512 + 512 new tokens):
            serving-path throughput; vs_baseline = fraction of the
            weight-streaming bandwidth bound
-  ssd    — O(1)-cache decode family: kernel bit-identity, serve-vs-
-           generate parity on the RecurrentState backend, memory_plan
-           honesty, and the flat-vs-linear footprint curve at 8B scale
-
   obs    — observability self-check: MPMD trace-vs-analytic bubble
            cross-check, tracing overhead A/B, serving bit-identity +
            lifecycle completeness, Chrome-trace schema validation
 
-Usage: python bench.py [--preset tiny|small|base|longctx|ocr|moe|decode|serve|ssd|obs]
+Usage: python bench.py [--preset tiny|small|base|longctx|ocr|moe|decode|obs]
        [--device cpu|tpu] [--steps N] [--batch B] [--seq S]
        [--accum K] [--grad-dtype bfloat16|float32]
 """
@@ -402,26 +398,6 @@ def _overlap_fields(lowered, overlap=False, label=""):
     }
 
 
-def _merge_program_fields(dst, src, prefix):
-    """Fold a second program's lint/mem fields into ``dst``: finding counts
-    sum, per-code counts add, peak/error fields keep a ``<prefix>_`` key
-    (the unprefixed peak stays the primary program's figure)."""
-    for kind in ("lint", "mem"):
-        if f"{kind}_findings" in src:
-            dst[f"{kind}_findings"] = (dst.get(f"{kind}_findings", 0)
-                                       + src[f"{kind}_findings"])
-            codes = dict(dst.get(f"{kind}_codes", {}))
-            for c, n in src.get(f"{kind}_codes", {}).items():
-                codes[c] = codes.get(c, 0) + n
-            dst[f"{kind}_codes"] = codes
-        if f"{kind}_error" in src:
-            dst[f"{prefix}_{kind}_error"] = src[f"{kind}_error"]
-    for k in ("peak_bytes", "peak_agreement"):
-        if k in src:
-            dst[f"{prefix}_{k}"] = src[k]
-    return dst
-
-
 def _bench_decode(jax, paddle, backend, on_tpu, args):
     """Serving path: KV-cache greedy decode throughput (new tokens/s).
 
@@ -508,447 +484,6 @@ def _bench_decode(jax, paddle, backend, on_tpu, args):
         "new_tokens": new,
         "decode_ms_per_step": round(1000 * dt / new, 3),
     }
-
-
-def _bench_serve(jax, paddle, backend, on_tpu, args):
-    """Serving engine under a mixed-request trace: continuous batching over
-    the paged KV cache (admission, block growth, prefill/decode interleave,
-    fused sampling, deferred-sync async dispatch). Reports aggregate new
-    tokens/s; ``vs_baseline`` is a MIXED-TRACE roofline — ideal wall
-    (decode weight-streaming + prefill compute at peak) / measured wall —
-    because the engine pipelines prefill and decode in one async stream.
-    ``decode_time_s``/``prefill_time_s`` are DISPATCH time only (~ms per
-    call), not execution time."""
-    import numpy as np
-
-    from paddle_tpu.models import LlamaForCausalLM
-    from paddle_tpu.models.llama import LlamaConfig
-    from paddle_tpu.serving import Engine, GenRequest
-
-    paddle.seed(0)
-    dtype = "bfloat16" if on_tpu else "float32"
-    if on_tpu:
-        cfg = LlamaConfig(vocab_size=32000, hidden_size=2048, intermediate_size=5632,
-                          num_hidden_layers=12, num_attention_heads=16,
-                          num_key_value_heads=8, max_position_embeddings=2048,
-                          dtype=dtype)
-        max_batch, num_blocks = (args.batch or 16), 256
-        n_req, p_lo, p_hi, n_lo, n_hi = 48, 128, 512, 64, 256
-    else:
-        from paddle_tpu.models import llama_tiny_config
-
-        cfg = llama_tiny_config(dtype=dtype)
-        max_batch, num_blocks = (args.batch or 2), 16
-        n_req, p_lo, p_hi, n_lo, n_hi = 4, 16, 64, 8, 16
-    model = LlamaForCausalLM(cfg)
-    n_params = sum(p.size for p in model.parameters())
-    eng = Engine(model, max_batch=max_batch, num_blocks=num_blocks,
-                 prefill_buckets=(128, 256, 512))
-
-    rng = np.random.default_rng(0)
-    reqs = [GenRequest(
-        prompt_ids=rng.integers(1, cfg.vocab_size,
-                                size=(int(rng.integers(p_lo, p_hi + 1)),)).astype(np.int32),
-        max_new_tokens=int(rng.integers(n_lo, n_hi + 1)))
-        for _ in range(n_req)]
-
-    # warm every program the engine can hit (prefill buckets + the whole
-    # decode-chunk ladder) so no XLA compile lands in the timed window
-    eng.warmup()
-    eng.stats = {k: (0.0 if isinstance(v, float) else 0)
-                 for k, v in eng.stats.items()}
-
-    t0 = time.perf_counter()
-    for r in reqs:
-        eng.add_request(r)
-    done = eng.run_to_completion()
-    dt = time.perf_counter() - t0
-
-    assert len(done) == n_req
-    gen = eng.stats["generated_tokens"]
-    tokens_per_sec = gen / dt
-    decode_steps = eng.stats["decode_steps"]
-    decode_time = eng.stats["decode_time"] or dt
-    dev_kind, peak = _peak_flops(jax, on_tpu)
-    param_bytes = n_params * (2 if dtype == "bfloat16" else 4)
-    hbm = _hbm_bytes_per_s(jax, on_tpu)
-    avg_batch = gen / max(decode_steps, 1)
-    # mixed-trace roofline: the engine pipelines prefill and decode in one
-    # async dispatch stream (deferred-sync drain), so per-phase timing is
-    # meaningless — vs_baseline is ideal wall / measured wall, where ideal =
-    # decode weight-streaming (one full param read per decode step) +
-    # prefill compute at MXU peak (prefill is compute-bound)
-    if hbm:
-        ideal = (decode_steps * param_bytes / hbm
-                 + eng.stats["prefill_tokens"] * 2.0 * n_params / peak)
-        frac_bound = ideal / dt
-    else:
-        frac_bound = 0.0
-    lint_fields = {}
-    if getattr(args, "lint", False) or getattr(args, "mem", False):
-        # the engine runs many programs; lint the k=1 decode chunk (the
-        # steady-state serving program) AND the largest-bucket prefill —
-        # prefill is where the big activation peaks live.  Arg recipes
-        # mirror Engine.warmup; findings from both programs are merged
-        # (counts summed) so the gate sees the whole serving surface.
-        try:
-            import jax.numpy as jnp
-
-            from paddle_tpu.framework import random as rnd
-
-            do_lint = getattr(args, "lint", False)
-            do_mem = getattr(args, "mem", False)
-            budget = getattr(args, "hbm_budget", None)
-            lowered = eng.lower_decode(1)
-            lint_fields = _lint_fields(lowered, do_lint, label="serve-decode")
-            lint_fields.update(_mem_fields(lowered, do_mem,
-                                           label="serve-decode",
-                                           hbm_budget=budget))
-            Pb, n = max(eng.prefill_buckets), 1
-            pfn = eng._get_prefill_fn(Pb, n)
-            plow = pfn.lower(
-                eng._params, eng._buffers, eng.k_pools, eng.v_pools,
-                eng._last_dev, jnp.zeros((n,), jnp.int32),
-                jnp.zeros((n, Pb), jnp.int32),
-                jnp.zeros((n, Pb // eng.block_size), jnp.int32),
-                jnp.ones((n,), jnp.int32), rnd.next_key(),
-                jnp.zeros((n,), jnp.float32),
-                jnp.zeros((n,), jnp.int32), jnp.ones((n,), jnp.float32),
-                jnp.zeros((eng._first_seg,), jnp.int32),
-                jnp.asarray(0, jnp.int32))
-            pf = _lint_fields(plow, do_lint, label="serve-prefill")
-            pf.update(_mem_fields(plow, do_mem, label="serve-prefill",
-                                  hbm_budget=budget))
-            _merge_program_fields(lint_fields, pf, "prefill")
-        except Exception as e:
-            lint_fields = {"lint_error": repr(e)}
-    return {
-        # the engine runs many distinct programs (prefill buckets + decode
-        # chunk ladder); per-decode-step traffic is the analytic weight
-        # stream — labeled as such so the gate knows it's a model, not XLA
-        "bytes_per_step": float(param_bytes),
-        "bytes_source": "analytic_weight_stream",
-        **lint_fields,
-        "metric": "llama_serve_new_tokens_per_sec",
-        "value": round(tokens_per_sec, 2),
-        "unit": "tokens/s",
-        "vs_baseline": round(frac_bound, 4),
-        "mfu": 0.0,
-        "device": dev_kind,
-        "backend": backend,
-        "preset": "serve",
-        "params": n_params,
-        "requests": n_req,
-        "max_batch": max_batch,
-        "avg_decode_batch": round(avg_batch, 2),
-        "decode_steps": decode_steps,
-        "prefills": eng.stats["prefills"],
-        "evictions": eng.stats["evictions"],
-        "wall_s": round(dt, 2),
-        "decode_time_s": round(decode_time, 2),
-        "prefill_time_s": round(eng.stats["prefill_time"], 2),
-    }
-
-
-def _bench_serve_trace(jax, paddle, backend, on_tpu, args):
-    """Load-generator trace presets for the serving tier (ISSUE 11).
-
-    Runs the SAME arrival trace twice in one process — feature on, then
-    feature off — so the headline numbers are self-relative ratios that
-    hold on any machine (wall-clock noise cancels), plus deterministic
-    accounting (hit rate, prefill tokens) and absolute latency percentiles
-    for the record:
-
-    - ``shared_prefix``: prefix cache on vs off.  ``goodput_ratio`` is the
-      acceptance number (>= 1.5x on the CPU proxy); greedy outputs must be
-      bit-identical between the two runs.
-    - ``long_prompt``: chunked prefill on vs off (monolithic).
-      ``decode_gap_p99_ratio`` (on/off, < 1 is better) is the stall the
-      chunking removes.
-    """
-    import numpy as np
-
-    from paddle_tpu.models import LlamaForCausalLM
-    from paddle_tpu.models.llama import LlamaConfig
-    from paddle_tpu.serving import Engine
-    from paddle_tpu.serving.loadgen import make_trace, run_trace
-    from paddle_tpu.serving.router import Router
-
-    paddle.seed(0)
-    dtype = "bfloat16" if on_tpu else "float32"
-    if on_tpu:
-        cfg = LlamaConfig(vocab_size=32000, hidden_size=2048,
-                          intermediate_size=5632, num_hidden_layers=12,
-                          num_attention_heads=16, num_key_value_heads=8,
-                          max_position_embeddings=2048, dtype=dtype)
-        max_batch, num_blocks = (args.batch or 16), 256
-        n_req, shared_len, long_len, max_new = 32, 1024, 1024, 32
-    else:
-        from paddle_tpu.models import llama_tiny_config
-
-        # traces run 512-token prompts + decode: lift the tiny config's
-        # position table so the reference outputs are in-contract
-        cfg = llama_tiny_config(dtype=dtype, max_position_embeddings=1024)
-        max_batch, num_blocks = (args.batch or 2), 24
-        n_req, shared_len, long_len, max_new = 8, 384, 512, 8
-    model = LlamaForCausalLM(cfg)
-    trace = make_trace(args.trace, cfg.vocab_size, seed=0,
-                       n_requests=n_req, shared_len=shared_len,
-                       long_len=long_len, max_new_tokens=max_new)
-
-    def run(**eng_kw):
-        eng = Engine(model, max_batch=max_batch, num_blocks=num_blocks,
-                     prefill_buckets=(128, 256, 512), **eng_kw)
-        eng.warmup()
-        r = Router()
-        r.add_replica(eng)
-        return run_trace(r, trace)
-
-    if args.trace == "shared_prefix":
-        cache_on = args.serve_cache == "on"
-        m_on = run(prefix_cache=cache_on)
-        m_off = run(prefix_cache=False)
-        identical = m_on["outputs"] == m_off["outputs"]
-        result = {
-            "metric": "serve_trace_goodput_ratio",
-            "value": round(m_on["goodput_tps"] / max(m_off["goodput_tps"],
-                                                     1e-9), 4),
-            "unit": "x_vs_cache_off",
-            "hit_rate": round(m_on["hit_rate"], 4),
-            "prefill_tokens_on": m_on["prefill_tokens"],
-            "prefill_tokens_off": m_off["prefill_tokens"],
-            "outputs_bit_identical": identical,
-        }
-    else:
-        m_on = run(prefill_chunk=128)
-        m_off = run()
-        identical = m_on["outputs"] == m_off["outputs"]
-        result = {
-            "metric": "serve_trace_decode_gap_p99_ratio",
-            "value": round(m_on["decode_gap_p99_ms"]
-                           / max(m_off["decode_gap_p99_ms"], 1e-9), 4),
-            "unit": "x_vs_monolithic_prefill",
-            "decode_gap_p99_on_ms": round(m_on["decode_gap_p99_ms"], 3),
-            "decode_gap_p99_off_ms": round(m_off["decode_gap_p99_ms"], 3),
-            "outputs_bit_identical": identical,
-        }
-    dev_kind, _ = _peak_flops(jax, on_tpu)
-    result.update({
-        "preset": "serve",
-        "trace": args.trace,
-        "device": dev_kind,
-        "backend": backend,
-        "requests": n_req,
-        "completed_on": m_on["completed"],
-        "completed_off": m_off["completed"],
-        "goodput_tps_on": round(m_on["goodput_tps"], 2),
-        "goodput_tps_off": round(m_off["goodput_tps"], 2),
-        "p50_ms": round(m_on["p50_ms"], 3),
-        "p99_ms": round(m_on["p99_ms"], 3),
-        # obs-registry snapshot of the feature-on run (queue depth / batch
-        # occupancy gauges, decode-gap + TTFT histograms, per-replica
-        # counters): the structured replacement for ad-hoc stat dicts
-        "metrics": m_on["metrics"],
-        "mfu": 0.0,
-        "vs_baseline": 0.0,
-    })
-    return result
-
-
-def _bench_ssd(jax, paddle, backend, on_tpu, args):
-    """O(1)-cache decode: the SSD/Mamba family's headline numbers.
-
-    One JSON line, four deterministic sections plus one timed number:
-
-    - ``kernel_bit_identical`` — the chunked Pallas scan (interpret mode on
-      the CPU proxy, compiled on TPU) vs ``ssd_scan_reference``;
-    - ``serve_matches_generate`` — tiny pure-SSD engine through the
-      ``RecurrentState`` backend vs ``model.generate`` greedy (``value`` is
-      the serve-loop new tokens/s while it runs);
-    - ``plan_within_10pct`` — ``memory_plan()``'s ``state_bytes`` /
-      ``kv_pool_bytes`` vs the live device arrays' actual bytes, for the
-      pure AND hybrid engines (the acceptance bound is 10%; the formulas
-      are exact so the measured error is ~0);
-    - the flat-vs-linear footprint story at 8B scale: per-sequence cache
-      bytes at 4k/16k/64k context for the SSD-8B config vs Llama-3-8B,
-      pure ``cache_spec`` arithmetic (no 8B params are instantiated).
-
-    ``SSD_GATE_INJECT=kv-backend`` prices the SSD family through paged-KV
-    arithmetic instead of its recurrent backend — the defect a missing
-    CacheBackend seam would produce.  The flat-footprint invariant breaks
-    and ``scripts/ssd_gate.sh`` must exit non-zero.
-
-    With ``--trace long_prompt``: additionally A/B the engine's dispatch
-    staging (host-side table/sampling uploads skipped when the schedule is
-    unchanged) on the llama long-prompt trace — ``staging_gap_p99_ratio``
-    is the per-dispatch decode-gap p99, staged over unstaged.
-    """
-    import os
-
-    import numpy as np
-
-    from paddle_tpu.kernels.ssd_scan import ssd_scan, ssd_scan_reference
-    from paddle_tpu.models import (SSDForCausalLM, ssd_8b_config,
-                                   ssd_tiny_config, ssd_tiny_hybrid_config)
-    from paddle_tpu.models.llama import llama3_8b_config
-    from paddle_tpu.models.ssd import ssd_cache_spec
-    from paddle_tpu.serving import Engine, GenRequest, make_backend
-
-    jnp = jax.numpy
-    paddle.seed(0)
-    rng = np.random.default_rng(0)
-
-    # -- kernel bit-identity (the training-path contract) -------------------
-    G, T, N, P, chunk = (8, 512, 128, 128, 128) if on_tpu \
-        else (3, 64, 8, 16, 16)
-    kx = rng.standard_normal((G, T, P)).astype(np.float32)
-    kb = rng.standard_normal((G, T, N)).astype(np.float32)
-    kc = rng.standard_normal((G, T, N)).astype(np.float32)
-    kla = -np.abs(rng.standard_normal((G, T)).astype(np.float32)) * 0.1
-    y_k, s_k = ssd_scan(kx, kb, kc, kla, chunk=chunk, interpret=not on_tpu)
-    y_r, s_r = ssd_scan_reference(jnp.asarray(kx), jnp.asarray(kb),
-                                  jnp.asarray(kc), jnp.asarray(kla),
-                                  chunk=chunk)
-    kernel_ok = bool(np.array_equal(np.asarray(y_k), np.asarray(y_r))
-                     and np.array_equal(np.asarray(s_k), np.asarray(s_r)))
-
-    # -- serve-vs-generate parity on the RecurrentState backend -------------
-    cfg = ssd_tiny_config()
-    model = SSDForCausalLM(cfg)
-    n_params = sum(p.size for p in model.parameters())
-    eng = Engine(model, num_blocks=32, block_size=16, max_batch=4,
-                 prefill_buckets=(32, 64))
-    lengths, max_new = (7, 13, 24, 18, 9, 21), 16
-    prompts = [rng.integers(1, cfg.vocab_size, size=(n,)).astype(np.int32)
-               for n in lengths]
-    for i, p in enumerate(prompts):
-        eng.add_request(GenRequest(prompt_ids=p, max_new_tokens=max_new,
-                                   temperature=0.0, request_id=f"r{i}"))
-    t0 = time.perf_counter()
-    outs = {o.request_id: o for o in eng.run_to_completion()}
-    dt = time.perf_counter() - t0
-    new_tokens = sum(len(o.output_ids) for o in outs.values())
-    parity = all(
-        np.array_equal(
-            outs[f"r{i}"].output_ids,
-            np.asarray(model.generate(
-                paddle.to_tensor(p[None, :]),
-                max_new_tokens=max_new)._data)[0, len(p):])
-        for i, p in enumerate(prompts))
-
-    # -- memory_plan honesty: predicted vs live device bytes ----------------
-    def _state_nbytes(states):
-        return sum(int(a.size) * a.dtype.itemsize
-                   for st in states for a in st.values())
-
-    plan = eng.memory_plan()
-    state_actual = _state_nbytes(eng._ssd_state)
-    state_err = abs(plan["state_bytes"] - state_actual) / max(state_actual, 1)
-    paddle.seed(1)
-    eng_h = Engine(SSDForCausalLM(ssd_tiny_hybrid_config()), num_blocks=32,
-                   block_size=16, max_batch=4, prefill_buckets=(32, 64))
-    plan_h = eng_h.memory_plan()
-    hybrid_actual = (_state_nbytes(eng_h._ssd_state)
-                     + sum(int(a.size) * a.dtype.itemsize
-                           for pool in (eng_h.k_pools, eng_h.v_pools)
-                           for a in pool))
-    hybrid_plan = plan_h["state_bytes"] + plan_h["kv_pool_bytes"]
-    hybrid_err = abs(hybrid_plan - hybrid_actual) / max(hybrid_actual, 1)
-
-    # -- flat-vs-linear at 8B scale (pure cache_spec arithmetic) ------------
-    spec8 = ssd_cache_spec(ssd_8b_config())
-    if os.environ.get("SSD_GATE_INJECT", "") == "kv-backend":
-        # defect injection: price the SSD layers as if they paged KV — the
-        # footprint curve turns linear and the gate must catch it
-        cfg8 = ssd_8b_config()
-        spec8 = {"kinds": ("attention",) * cfg8.num_hidden_layers,
-                 "state_bytes_per_slot": 0,
-                 "kv_layers": cfg8.num_hidden_layers,
-                 "kv_bytes_per_token_layer":
-                     2 * cfg8.kv_heads * cfg8.head_dim
-                     * jnp.dtype(cfg8.dtype).itemsize}
-    lcfg = llama3_8b_config()
-    lspec = {"kinds": ("attention",) * lcfg.num_hidden_layers,
-             "state_bytes_per_slot": 0,
-             "kv_layers": lcfg.num_hidden_layers,
-             "kv_bytes_per_token_layer":
-                 2 * lcfg.kv_heads * lcfg.head_dim
-                 * jnp.dtype(lcfg.dtype).itemsize}
-    ctxs = (4096, 16384, 65536)
-    be8 = make_backend(spec8, num_blocks=1, block_size=128, max_slots=1)
-    bel = make_backend(lspec, num_blocks=1, block_size=128, max_slots=1)
-    ssd8 = {c: be8.seq_bytes(c) for c in ctxs}
-    llama8 = {c: bel.seq_bytes(c) for c in ctxs}
-
-    result = {
-        "metric": "ssd_serve_new_tokens_per_sec",
-        "value": round(new_tokens / dt, 2),
-        "unit": "tokens/s",
-        "vs_baseline": 0.0,
-        "mfu": 0.0,
-        "device": _peak_flops(jax, on_tpu)[0],
-        "backend": backend,
-        "preset": "ssd",
-        "params": n_params,
-        "requests": len(prompts),
-        "completed": len(outs),
-        "new_tokens": new_tokens,
-        "kernel_bit_identical": kernel_ok,
-        "serve_matches_generate": bool(parity),
-        "state_plan_err": round(state_err, 6),
-        "hybrid_plan_err": round(hybrid_err, 6),
-        "plan_within_10pct": bool(state_err <= 0.1 and hybrid_err <= 0.1),
-        "state_bytes_per_slot": spec8.get("state_bytes_per_slot", 0),
-        "ssd8b_seq_mb": {str(c): round(v / 1e6, 2) for c, v in ssd8.items()},
-        "llama8b_seq_mb": {str(c): round(v / 1e6, 2)
-                           for c, v in llama8.items()},
-        "footprint_flat": bool(ssd8[ctxs[0]] == ssd8[ctxs[-1]]),
-        "flat_vs_linear_64k": round(llama8[65536] / max(ssd8[65536], 1), 2),
-    }
-
-    # -- dispatch staging A/B (PR 13 remainder), opt-in: --trace long_prompt
-    if args.trace == "long_prompt":
-        from paddle_tpu.models import LlamaForCausalLM, llama_tiny_config
-        from paddle_tpu.serving.loadgen import make_trace, run_trace
-        from paddle_tpu.serving.router import Router
-
-        paddle.seed(0)
-        lmodel = LlamaForCausalLM(llama_tiny_config(
-            dtype="float32", max_position_embeddings=1024))
-        trace = make_trace("long_prompt", lmodel.config.vocab_size, seed=0,
-                           n_requests=8, long_len=512, max_new_tokens=8)
-
-        def run_staged(staged):
-            e = Engine(lmodel, max_batch=2, num_blocks=24,
-                       prefill_buckets=(128, 256, 512),
-                       dispatch_staging=staged)
-            e.warmup()
-            r = Router()
-            r.add_replica(e)
-            m = run_trace(r, trace)
-            gaps = sorted(e._decode_gaps)
-            m["dispatch_gap_p99_ms"] = (
-                1e3 * float(np.percentile(gaps, 99)) if gaps else 0.0)
-            return m
-
-        m_on = run_staged(True)
-        m_off = run_staged(False)
-        result.update({
-            "trace": "long_prompt",
-            "staging_outputs_bit_identical":
-                m_on["outputs"] == m_off["outputs"],
-            "staged_dispatch_gap_p99_ms":
-                round(m_on["dispatch_gap_p99_ms"], 3),
-            "unstaged_dispatch_gap_p99_ms":
-                round(m_off["dispatch_gap_p99_ms"], 3),
-            "staging_gap_p99_ratio": round(
-                m_on["dispatch_gap_p99_ms"]
-                / max(m_off["dispatch_gap_p99_ms"], 1e-9), 4),
-            "staged_decode_gap_p99_ms": round(m_on["decode_gap_p99_ms"], 3),
-            "unstaged_decode_gap_p99_ms": round(m_off["decode_gap_p99_ms"],
-                                                3),
-        })
-    return result
 
 
 def _bench_fuse(jax, paddle, backend, on_tpu, preset, args):
@@ -1250,8 +785,7 @@ def _bench_obs(jax, paddle, backend, on_tpu, args):
     from paddle_tpu import obs
     from paddle_tpu.distributed.parallel.mpmd import mpmd_bubble_crosscheck
     from paddle_tpu.models import LlamaForCausalLM, llama_tiny_config
-    from paddle_tpu.serving import Engine
-    from paddle_tpu.serving.loadgen import make_trace, run_trace
+    from paddle_tpu.serving import Engine, GenRequest
     from paddle_tpu.serving.router import Router
 
     # -- 1. trace-vs-analytic MPMD bubble (pp2, small dims: gate budget) --
@@ -1286,25 +820,33 @@ def _bench_obs(jax, paddle, backend, on_tpu, args):
     paddle.seed(0)
     cfg = llama_tiny_config(dtype="float32", max_position_embeddings=1024)
     model = LlamaForCausalLM(cfg)
-    trace = make_trace("shared_prefix", cfg.vocab_size, seed=0,
-                       n_requests=6, shared_len=96, tail_len=8,
-                       max_new_tokens=8)
+    rng = np.random.default_rng(0)
+    shared = rng.integers(1, cfg.vocab_size, size=96).astype(np.int32)
+    prompts = [np.concatenate([shared, rng.integers(
+        1, cfg.vocab_size, size=8).astype(np.int32)]) for _ in range(6)]
 
     def serve_once():
+        """Six shared-prefix requests through a one-replica router:
+        ``(outputs by request id, the run's registry snapshot)``."""
+        obs.reset_metrics()
         eng = Engine(model, max_batch=2, num_blocks=24,
                      prefill_buckets=(128, 256))
         eng.warmup()
         r = Router()
         r.add_replica(eng)
-        return run_trace(r, trace)
+        for p in prompts:
+            r.submit(GenRequest(prompt_ids=p, max_new_tokens=8))
+        outs = {o.request_id: list(o.output_ids)
+                for o in r.run_to_completion()}
+        return outs, obs.registry().snapshot()
 
     obs.disable_tracing()
-    m_off = serve_once()
+    outs_off, _ = serve_once()
     tr = obs.enable_tracing()
-    m_on = serve_once()
+    outs_on, metrics_on = serve_once()
     events = tr.events()
-    identical = m_on["outputs"] == m_off["outputs"]
-    rids = set(m_on["outputs"])
+    identical = outs_on == outs_off
+    rids = set(outs_on)
     begins = {e["id"] for e in events
               if e.get("ph") == "b" and e.get("cat") == "serve.request"}
     ends = {e["id"] for e in events
@@ -1322,7 +864,7 @@ def _bench_obs(jax, paddle, backend, on_tpu, args):
     if not was_on and not args.otrace:
         obs.disable_tracing()
 
-    gap_snap = m_on["metrics"].get("serve.decode_gap_ms{replica=0}", {})
+    gap_snap = metrics_on.get("serve.decode_gap_ms{replica=0}", {})
     dev_kind, _ = _peak_flops(jax, on_tpu)
     return {
         "metric": "obs_crosscheck_rel_err",
@@ -1336,7 +878,7 @@ def _bench_obs(jax, paddle, backend, on_tpu, args):
         "lifecycle_complete": bool(lifecycle_complete and dup_free),
         "trace_valid": not problems,
         "trace_problems": problems[:5],
-        "metrics_families": len(m_on["metrics"]),
+        "metrics_families": len(metrics_on),
         "decode_gap_p99_ms": round(gap_snap.get("p99", 0.0), 3),
         "preset": "obs",
         "device": dev_kind,
@@ -1416,7 +958,7 @@ def _bench_pp(jax, backend, on_tpu, args):
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--preset", default=None, choices=["tiny", "small", "base", "longctx", "ocr", "moe", "decode", "serve", "ssd", "obs"])
+    ap.add_argument("--preset", default=None, choices=["tiny", "small", "base", "longctx", "ocr", "moe", "decode", "obs"])
     ap.add_argument("--device", default=None, choices=["cpu", "tpu"])
     ap.add_argument("--steps", type=int, default=None)
     ap.add_argument("--batch", type=int, default=None)
@@ -1464,15 +1006,6 @@ def main():
                          "tail all-gather, 'overlap' = head-of-next-step "
                          "bucketed gather behind the forward; on CPU forces "
                          "an 8-device host mesh")
-    ap.add_argument("--trace", default=None,
-                    choices=["shared_prefix", "long_prompt"],
-                    help="serve preset only: run the load-generator trace "
-                         "comparison (feature on vs off in one process) and "
-                         "report p50/p99 latency, goodput, and the on/off "
-                         "ratios instead of the steady-state trace")
-    ap.add_argument("--serve-cache", default="on", choices=["on", "off"],
-                    help="serve --trace only: force the prefix cache off in "
-                         "the feature-on run (gate injection hook)")
     ap.add_argument("--fuse", action="store_true",
                     help="pretrain presets: run the fusion-transformer A/B "
                          "(analysis.fusion_transform over the audit's "
@@ -1653,19 +1186,6 @@ def main():
 
     if preset == "decode":
         result = _bench_decode(jax, paddle, backend, on_tpu, args)
-        result.update(_kernel_lint_fields(args.lint, preset))
-        print(json.dumps(_stamp(result)))
-        return
-    if preset == "serve":
-        if args.trace:
-            result = _bench_serve_trace(jax, paddle, backend, on_tpu, args)
-        else:
-            result = _bench_serve(jax, paddle, backend, on_tpu, args)
-        result.update(_kernel_lint_fields(args.lint, preset))
-        print(json.dumps(_stamp(result)))
-        return
-    if preset == "ssd":
-        result = _bench_ssd(jax, paddle, backend, on_tpu, args)
         result.update(_kernel_lint_fields(args.lint, preset))
         print(json.dumps(_stamp(result)))
         return

@@ -688,6 +688,18 @@ class LlamaForCausalLM(Layer):
                          dtype=None):
         return self.llama.init_paged_pools(num_blocks, block_size, dtype)
 
+    def cache_spec(self) -> dict:
+        """The model half of the serving tier's ``CacheBackend`` seam:
+        per-layer cache kinds plus the byte quantities a backend needs to
+        account a sequence's cache without knowing the model."""
+        cfg = self.config
+        itemsize = jnp.dtype(cfg.dtype).itemsize
+        return {"kinds": ("attention",) * cfg.num_hidden_layers,
+                "state_bytes_per_slot": 0,
+                "kv_layers": cfg.num_hidden_layers,
+                "kv_bytes_per_token_layer":
+                    2 * cfg.kv_heads * cfg.head_dim * itemsize}
+
     def forward(self, input_ids, position_ids=None, cache=None):
         """Returns logits; with ``cache`` returns ``(logits, new_cache)``
         (the reference's ``use_cache=True`` contract)."""

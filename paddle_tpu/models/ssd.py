@@ -123,7 +123,7 @@ class SSDConfig:
 
 
 def ssd_tiny_config(**overrides) -> SSDConfig:
-    """CPU-smoke scale (bench --preset ssd)."""
+    """CPU-smoke scale (tests/test_ssd.py)."""
     cfg = dict(vocab_size=512, hidden_size=128, intermediate_size=384,
                num_hidden_layers=2, num_heads=4, state_size=16, chunk_size=16,
                num_key_value_heads=2, max_position_embeddings=256)
@@ -670,7 +670,7 @@ class SSDForCausalLM(Layer):
 
 def ssd_cache_spec(cfg: SSDConfig) -> dict:
     """``cache_spec`` from the config alone — pure arithmetic, so capacity
-    planning (``bench.py --preset ssd``, PERF tables) can price full-scale
+    planning (PERF tables, ``tests/test_ssd.py``) can price full-scale
     configs without instantiating their parameters."""
     nh, P, N, L = (cfg.num_heads, cfg.head_dim, cfg.state_size,
                    cfg.chunk_size)
@@ -683,17 +683,5 @@ def ssd_cache_spec(cfg: SSDConfig) -> dict:
             "state_bytes_per_slot": state_slot * sum(
                 1 for k in kinds if k == "ssd"),
             "kv_layers": sum(1 for k in kinds if k == "attention"),
-            "kv_bytes_per_token_layer":
-                2 * cfg.kv_heads * cfg.head_dim * itemsize}
-
-
-def llama_cache_spec(model) -> dict:
-    """``cache_spec`` for the attention-only Llama family (the PagedKV
-    side of the seam), computed from its config."""
-    cfg = model.config
-    itemsize = jnp.dtype(cfg.dtype).itemsize
-    return {"kinds": ("attention",) * cfg.num_hidden_layers,
-            "state_bytes_per_slot": 0,
-            "kv_layers": cfg.num_hidden_layers,
             "kv_bytes_per_token_layer":
                 2 * cfg.kv_heads * cfg.head_dim * itemsize}

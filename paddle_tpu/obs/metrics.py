@@ -6,8 +6,7 @@ of the ``serve.decode_gap_ms`` family, creating it on first use.  The
 snapshot is a plain JSON document (one entry per labeled instrument,
 keyed ``name{k=v,...}``) that round-trips through
 :meth:`Registry.from_snapshot` — what ``bench.py --otrace`` attaches to
-the trace dump and ``serving.loadgen`` returns beside its legacy stat
-keys.
+the trace dump.
 
 Histograms use fixed bucket upper bounds (defaults suit millisecond
 latencies); p50/p95/p99 are estimated by linear interpolation inside

@@ -1,4 +1,4 @@
-"""Continuous-batching LLM serving engine over the paged KV cache.
+"""Continuous-batching LLM serving engine over a model's own kind of cache.
 
 Reference counterparts: the inference product around
 ``paddle/fluid/inference/api/analysis_predictor.cc:427`` and the paged
@@ -7,13 +7,15 @@ block_multi_head_attention_kernel.cu:1`` (block tables, dynamic batching).
 
 TPU-native design:
 
-- **Two compiled programs, not a graph pass pipeline.** A bucketed *prefill*
-  program (dense causal attention over the padded prompt, K/V scattered into
-  the paged pools afterwards; same-bucket admissions batch through one call
-  on a 4/2/1 size ladder) and a batched *decode-chunk* program (paged
-  attention via the block-table Pallas kernel, sampling fused in). Static
-  shapes everywhere: the decode batch is always ``max_batch`` wide with
-  inactive slots masked by ``lengths == 0``.
+- **One family of compiled programs, not a graph pass pipeline.** A bucketed
+  *prefill* program (the model's dense forward over the padded prompt, what
+  it left in the cache written into the admitted slots afterwards;
+  same-bucket admissions batch through one call on the backend's size
+  ladder, 4/2/1 for pages), a batched *decode-chunk* program (the model's
+  serving forward, sampling fused in; paged attention runs the block-table
+  Pallas kernel) and a *chunk-prefill* program for a prefix hit's suffix or
+  a long prompt's pieces.  Static shapes everywhere: the decode batch is
+  always ``max_batch`` wide with inactive slots masked by ``lengths == 0``.
 - **Chunked on-device decode.** One compiled call runs ``k`` decode steps as
   a ``lax.scan`` (k from a power-of-two ladder), so per-call costs amortize
   over ``k`` tokens.  A sequence whose budget ends mid-chunk simply stops
@@ -47,18 +49,23 @@ layout the decode kernels read, so XLA updates them in place: the compiled
 chunk copies no pool, per layer or around its scan
 (``tests/test_chip_compile.py`` holds it to that).
 
-**Cache backends.** What a sequence's "cache" IS is a policy, not a fact:
-the engine's block bookkeeping lives behind the ``CacheBackend`` seam
-(``cache_backend.py``).  Attention models ride the ``PagedKV`` backend
-(refcounted blocks + prefix cache, exactly the original behavior); the SSD
-family (``models/ssd.py``) rides ``RecurrentState`` — constant-size
-per-slot decode state, no blocks, no growth, no prefix hashing — and
-hybrid stacks ride both at once.  The engine picks its program family from
-``model.cache_spec()``: recurrent-family prefills are B=1 (the per-slot
-state scatter has no batched form yet) and chunked/prefix-hit prefill is
-structurally off (no block chain to hash); decode is the same masked
-``max_batch``-wide chunk program with the slot states threaded through the
-scan alongside the pools.
+**Cache backends.** What a sequence's "cache" IS is a policy, not a fact,
+and all of it lives behind the ``CacheBackend`` seam (``cache_backend.py``):
+the block and slot bookkeeping on the host AND the device arrays, as one
+pytree (``backend.device``) that every program takes donated and hands
+back.  The engine keeps the scheduler, the token ledger and the program
+skeletons and knows no cache kind by name: a program asks the backend for
+the ``cache`` dict the model's forward takes, and gives it the forward's
+``new_cache`` to take the new arrays out of or to write a prefill from.
+The backend is made from ``model.cache_spec()``.  Attention models ride
+``PagedKV`` (refcounted blocks + prefix cache, K/V pools); the SSD family
+rides ``RecurrentState`` — constant-size per-slot decode state, no blocks,
+no growth, no prefix hashing, one prompt a prefill call — and hybrid stacks
+ride both at once.  What a cache cannot do (prefix reuse, chunked prefill,
+batched prefill) the backend says, and the engine degrades to what is left.
+
+Dispatch staging is what the engine does, not an option: the decode call's
+scheduler inputs stay on the device while the schedule is unchanged.
 """
 
 from __future__ import annotations
@@ -73,8 +80,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .cache_backend import (CacheBackend, HybridCache, PagedKV,
-                            RecurrentState, make_backend)
+from .cache_backend import (CacheBackend, PagedKV, RecurrentState,
+                            make_backend)
 from .. import obs
 
 __all__ = ["Engine", "GenRequest", "RequestOutput", "prefix_block_hashes",
@@ -176,30 +183,9 @@ class Engine:
                  max_prefill_overhead: float = 1.0, decode_chunk: int = 32,
                  hbm_budget_bytes: Optional[int] = None,
                  prefix_cache: bool = True,
-                 prefill_chunk: Optional[int] = None,
-                 dispatch_staging: bool = True):
-        from ..jit import functional_call
-
+                 prefill_chunk: Optional[int] = None):
         self.model = model
         self.cfg = model.config
-        # the CacheBackend seam: per-layer cache kinds + byte quantities
-        # from the model, policy objects from cache_backend.make_backend
-        if hasattr(model, "cache_spec"):
-            spec = model.cache_spec()
-        else:
-            from ..models.ssd import llama_cache_spec
-
-            spec = llama_cache_spec(model)
-        self._spec = spec
-        self._recurrent = any(k == "ssd" for k in spec["kinds"])
-        self._uses_pages = any(k == "attention" for k in spec["kinds"])
-        if self._recurrent:
-            # graceful degradation: no block chain to hash (pure SSD) or a
-            # hit would restore only the attention half (hybrid) — and
-            # chunked prefill rides the block-aligned context offset, which
-            # the recurrent prefill program doesn't model
-            prefix_cache = False
-            prefill_chunk = None
         self.max_batch = max_batch
         self.block_size = block_size
         self.num_blocks = num_blocks
@@ -225,32 +211,24 @@ class Engine:
         self._buffers = {n: b._data for n, b in model.named_buffers()}
         self.hbm_budget_bytes = hbm_budget_bytes
 
-        # prefix caching (vLLM-style, scheduler-side only — the paged
-        # kernels address blocks indirectly so no kernel work is needed):
-        # a block serving >= 1 live slot carries a refcount in _ref; a
-        # registered block whose refcount drops to 0 parks in the _lru
-        # (hash -> block, oldest first) where a later admission can either
-        # HIT it (reacquire, skip its prefill) or RECLAIM it (allocation
-        # pressure pops the oldest cached block back into service)
+        # the CacheBackend seam: per-layer cache kinds + byte quantities
+        # from the model, everything about the cache from the backend.  What
+        # a cache cannot do is the backend's to say, and degrades gracefully:
+        # no block chain to hash (pure SSD) or a hit that would restore only
+        # the attention half (hybrid); no block-aligned context offset for a
+        # chunked prefill to ride
+        self.backend = make_backend(model.cache_spec(), num_blocks,
+                                    block_size, max_batch,
+                                    prefix_cache=prefix_cache)
+        self.prefix_cache = self.backend.supports_prefix_cache
+        if not self.backend.supports_chunked_prefill:
+            prefill_chunk = None
         if prefill_chunk is not None:
             # chunks must be block-aligned so every chunk starts on a block
             # boundary (write_paged_chunk's precondition)
             prefill_chunk = max(1, -(-int(prefill_chunk) // block_size)) \
                 * block_size
         self.prefill_chunk = prefill_chunk
-        self.backend = make_backend(spec, num_blocks, block_size, max_batch,
-                                    prefix_cache=prefix_cache)
-        self.prefix_cache = self.backend.supports_prefix_cache
-        # block-verb delegation target: the paged side of the backend (a
-        # zero-block dummy for pure-recurrent models so the _free/_ref/...
-        # introspection surface stays uniform), and the slot-state ledger
-        if isinstance(self.backend, PagedKV):
-            self._pages, self._rstate = self.backend, None
-        elif isinstance(self.backend, HybridCache):
-            self._pages, self._rstate = self.backend.pages, self.backend.state
-        else:
-            self._pages = PagedKV(1, block_size, 0, prefix_cache=False)
-            self._rstate = self.backend
         self._slots = [_Slot(idx=i) for i in range(max_batch)]
         self._tbl = np.zeros((max_batch, self.max_blocks_per_seq), np.int32)
         self._waiting: collections.deque = collections.deque()
@@ -291,22 +269,14 @@ class Engine:
                     f"exceeds hbm_budget_bytes={hbm_budget_bytes / 1e6:.1f}MB"
                     f" ({detail}); reduce num_blocks (kv_pool_bytes scales "
                     f"linearly with it) or max_batch")
-        pools_init = getattr(model, "init_paged_pools", None)
-        if pools_init is None:
-            pools_init = model.llama.init_paged_pools
-        self.k_pools, self.v_pools = pools_init(num_blocks, block_size)
-        # recurrent-family slot residency: per-SSD-layer state dicts,
-        # max_batch wide, scattered into by the prefill program and
+        # the cache's device arrays: written by the prefill programs and
         # threaded through the decode scan (donated, updated in place)
-        self._ssd_state = (model.init_recurrent_slots(max_batch)
-                           if self._recurrent else ())
-        self._ssd_prefill_fns: Dict[int, object] = {}
+        self.backend.device = self.backend.init_device(model)
         # dispatch staging (host-dispatch overlap): device copies of the
         # decode call's scheduler inputs, reused while the scheduler state
         # they snapshot is unchanged — steady-state decode then uploads
         # NOTHING per call (the lengths vector advances ON DEVICE and is
         # re-staged from the program's own output)
-        self.dispatch_staging = bool(dispatch_staging)
         self._sched_version = 0
         self._staged = None                    # (version, tbl, lengths, ...)
         self._last_dispatch_t: Optional[float] = None
@@ -388,7 +358,8 @@ class Engine:
         logits for a full-width decode chunk step; activations + attention
         scores + logits at the largest prefill bucket on the widest ladder
         rung.  ``analysis.lint_memory`` on the lowered programs is the
-        exact cross-check (``bench.py --preset serve --mem``)."""
+        exact cross-check (``tests/test_serving.py`` runs it on the decode
+        chunk)."""
         import numpy as np
 
         cfg = self.cfg
@@ -451,21 +422,20 @@ class Engine:
             self._req_counter += 1
             req.request_id = f"req-{self._req_counter}"
         P = len(req.prompt_ids)
-        if self._uses_pages:
-            # block-granular capacity checks only bind when the model's
-            # cache actually pages (a pure-recurrent sequence has no block
-            # chain and no per-slot KV capacity to exceed)
-            if (P + req.max_new_tokens) > \
-                    self.max_blocks_per_seq * self.block_size:
-                raise ValueError(
-                    f"prompt ({P}) + max_new_tokens ({req.max_new_tokens}) "
-                    f"exceeds the per-slot capacity "
-                    f"{self.max_blocks_per_seq * self.block_size}")
-            if self._bucket(P) // self.block_size > self.num_blocks - 1:
-                raise ValueError(
-                    f"prompt needs {self._bucket(P) // self.block_size} "
-                    f"blocks but the pool only has {self.num_blocks - 1} "
-                    f"usable; raise num_blocks")
+        # block-granular capacity checks only bind when the model's cache
+        # actually pages (a pure-recurrent sequence needs zero blocks: no
+        # block chain and no per-slot KV capacity to exceed)
+        if self.backend.blocks_for(P + req.max_new_tokens) > \
+                self.max_blocks_per_seq:
+            raise ValueError(
+                f"prompt ({P}) + max_new_tokens ({req.max_new_tokens}) "
+                f"exceeds the per-slot capacity "
+                f"{self.max_blocks_per_seq * self.block_size}")
+        need = self.backend.blocks_for(self._bucket(P))
+        if need > self.num_blocks - 1:
+            raise ValueError(
+                f"prompt needs {need} blocks but the pool only has "
+                f"{self.num_blocks - 1} usable; raise num_blocks")
         req._queued_t = time.perf_counter()
         self._obs_mark(req, "queued", prompt_len=P)
         self._waiting.append(req)
@@ -522,43 +492,34 @@ class Engine:
         self._ensure_decode_blocks(k)
         self._dispatch_chunk(k)
 
-    # -- block pool (delegated to the CacheBackend's paged side) ------------
+    # -- block pool (delegated to the CacheBackend) -------------------------
     # The engine's historical introspection surface (_free/_ref/_index/
-    # _hash_of/_lru) stays readable — tests and tools poke these directly —
-    # but the structures now LIVE on the backend.
+    # _hash_of/_lru) stays readable where the backend is a block pool —
+    # tests poke these directly — but the structures LIVE on the backend.
 
     @property
     def _free(self):
-        return self._pages._free
+        return self.backend._free
 
     @property
     def _ref(self):
-        return self._pages._ref
+        return self.backend._ref
 
     @property
     def _index(self):
-        return self._pages._index
+        return self.backend._index
 
     @property
     def _hash_of(self):
-        return self._pages._hash_of
+        return self.backend._hash_of
 
     @property
     def _lru(self):
-        return self._pages._lru
+        return self.backend._lru
 
     def _available(self) -> int:
         """Blocks an allocation can claim: truly free + ref-0 cached."""
-        return self._pages.available()
-
-    def _alloc_block(self) -> Optional[int]:
-        return self._pages.alloc()
-
-    def _free_block(self, b: int):
-        self._pages.release(b)
-
-    def _acquire_cached(self, h: bytes) -> Optional[int]:
-        return self._pages.gather(h)
+        return self.backend.available()
 
     def _register_prompt_blocks(self, slot: _Slot):
         """Publish a slot's cacheable prompt blocks in the hash index.
@@ -569,7 +530,7 @@ class Engine:
         read garbage)."""
         if not self.prefix_cache:
             return
-        self._pages.register(slot.hashes, slot.blocks)
+        self.backend.register(slot.hashes, slot.blocks)
 
     def _pick_chunk(self, active) -> int:
         """Largest power-of-two chunk within the LONGEST remaining budget.
@@ -615,18 +576,14 @@ class Engine:
             P = len(req.prompt_ids)
             hashes = (prefix_block_hashes(req.prompt_ids, bs)
                       if self.prefix_cache else [])
-            n_hit = 0
-            for h in hashes:
-                if h not in self._index:
-                    break
-                n_hit += 1
+            n_hit = self.backend.lookup_chain(hashes)
             self.stats["prefix_lookup_blocks"] += len(hashes)
             chunked = (self.prefill_chunk is not None
                        and P - n_hit * bs > self.prefill_chunk)
             if n_hit == 0 and not chunked:
                 # -- path A: dense batched prefill of the whole prompt
                 Pb = self._bucket(P)
-                n_blocks = Pb // bs if self._uses_pages else 0
+                n_blocks = self.backend.blocks_for(Pb)
                 if n_blocks > self.num_blocks - 1:
                     # an evicted request's merged prompt outgrew the whole
                     # pool: no schedule can ever run it — fail loudly
@@ -636,10 +593,9 @@ class Engine:
                 if self._available() < n_blocks:
                     break                  # pool pressure: stop admitting
                 self._waiting.popleft()
-                blocks = [self._alloc_block() for _ in range(n_blocks)]
+                blocks = [self.backend.alloc() for _ in range(n_blocks)]
                 self._admit_counter += 1
-                if self._rstate is not None:
-                    self._rstate.acquire_slot(slot.idx)
+                self.backend.acquire_slot(slot.idx)
                 slot.req = req
                 slot.length = P
                 slot.blocks = blocks
@@ -654,7 +610,7 @@ class Engine:
                 # trash block 0 instead, which the length mask never attends)
                 needed = -(-slot.length // bs)
                 while len(slot.blocks) > max(needed, 1):
-                    self._free_block(slot.blocks.pop())
+                    self.backend.release(slot.blocks.pop())
                 self._write_tbl_row(slot)
                 # eager registration is safe for path A: this admission's
                 # prefill dispatches within this _admit call, and any hit
@@ -670,17 +626,18 @@ class Engine:
             # -- path B: prefix-hit suffix and/or chunked prefill — admit
             # the slot now; its chunks dispatch in _advance_prefills,
             # interleaved with decode rounds
-            hit_blocks = [self._acquire_cached(h) for h in hashes[:n_hit]]
+            hit_blocks = [self.backend.gather(h) for h in hashes[:n_hit]]
             n_sblocks = -(-P // bs) - n_hit
             if self._available() < n_sblocks:
                 # roll the hit refs back and stop admitting (the request
                 # stays at the queue head for the next round)
                 for b in hit_blocks:
-                    self._free_block(b)
+                    self.backend.release(b)
                 break
             self._waiting.popleft()
-            suffix_blocks = [self._alloc_block() for _ in range(n_sblocks)]
+            suffix_blocks = [self.backend.alloc() for _ in range(n_sblocks)]
             self._admit_counter += 1
+            self.backend.acquire_slot(slot.idx)
             slot.req = req
             slot.length = n_hit * bs       # context already resident
             slot.blocks = hit_blocks + suffix_blocks
@@ -703,14 +660,9 @@ class Engine:
         for entry in admitted:
             by_bucket.setdefault(entry[2], []).append(entry)
         for Pb, group in by_bucket.items():
-            if self._recurrent:
-                # recurrent-family prefill is B=1: the program scatters one
-                # slot's state row (no batched scatter form yet)
-                for entry in group:
-                    self._ssd_prefill_one(entry, Pb)
-                continue
             while group:
-                n = 4 if len(group) >= 4 else (2 if len(group) >= 2 else 1)
+                n = next(r for r in self.backend.prefill_ladder
+                         if r <= len(group))
                 self._prefill_batch(group[:n], Pb)
                 group = group[n:]
         for slot, req, *_ in admitted:
@@ -768,8 +720,8 @@ class Engine:
         self._obs_dispatched(req, t0)
         with obs.span("serve.prefill-chunk", cat="serve",
                       args={"bucket": Cb, "final": final}):
-            self._first_buf, self._last_dev, self.k_pools, self.v_pools = fn(
-                self._params, self._buffers, self.k_pools, self.v_pools,
+            self._first_buf, self._last_dev, self.backend.device = fn(
+                self._params, self._buffers, self.backend.device,
                 self._last_dev, jnp.asarray(slot.idx, jnp.int32),
                 jnp.asarray(ids_row),
                 jnp.asarray(self._tbl[slot.idx].copy()),
@@ -807,7 +759,7 @@ class Engine:
         on pressure).  Writes past a finished sequence's window land in the
         trash block (unallocated table entries are 0) or its own about-to-be
         -freed blocks — never in another sequence's memory."""
-        if not self._uses_pages:
+        if not self.backend.blocks_for(1):
             return                 # recurrent state never grows: no blocks
         for slot in sorted((s for s in self._slots if s.req is not None),
                            key=lambda s: s.admit_seq):
@@ -818,7 +770,7 @@ class Engine:
             w = min(k, max(slot.req.max_new_tokens - slot.out_count, 1))
             need_idx = (slot.length + w - 1) // self.block_size
             while slot.req is not None and need_idx >= len(slot.blocks):
-                b = self._alloc_block()
+                b = self.backend.alloc()
                 if b is not None:
                     slot.blocks.append(b)
                     continue
@@ -875,9 +827,9 @@ class Engine:
 
     def _release(self, slot: _Slot):
         for b in slot.blocks:
-            self._free_block(b)      # shared blocks just drop a ref
-        if self._rstate is not None and slot.req is not None:
-            self._rstate.release_slot(slot.idx)
+            self.backend.release(b)  # shared blocks just drop a ref
+        if slot.req is not None:
+            self.backend.release_slot(slot.idx)
         self._sched_version += 1
         slot.req = None
         slot.length = 0
@@ -893,14 +845,14 @@ class Engine:
         fn = self._prefill_fns.get((Pb, n))
         if fn is None:
             fn = self._prefill_fns[(Pb, n)] = jax.jit(
-                self._build_prefill(Pb, n), donate_argnums=(2, 3, 4, 13))
+                self._build_prefill(Pb, n), donate_argnums=(2, 3, 12))
         return fn
 
     def _get_decode_fn(self, k: int):
         fn = self._decode_fns.get(k)
         if fn is None:
             fn = self._decode_fns[k] = jax.jit(
-                self._build_decode(k), donate_argnums=(2, 3, 6, 11))
+                self._build_decode(k), donate_argnums=(2, 5, 10))
         return fn
 
     def _get_chunk_fn(self, Cb: int, final: bool):
@@ -908,11 +860,11 @@ class Engine:
         if fn is None:
             fn = self._chunk_fns[(Cb, final)] = jax.jit(
                 self._build_chunk_prefill(Cb, final),
-                donate_argnums=(2, 3, 4, 14))
+                donate_argnums=(2, 3, 13))
         return fn
 
     def _build_chunk_prefill(self, Cb: int, final: bool):
-        """B=1 chunk prefill over the paged pools: write a ``Cb``-token
+        """B=1 chunk prefill over the cache's blocks: write a ``Cb``-token
         chunk at the slot's block-aligned context offset and attend
         context + chunk in one gather (``paged_chunk_attention_fn``).  Only
         the FINAL chunk computes an output: the first sampled token at the
@@ -923,17 +875,14 @@ class Engine:
         trash block absorbs (final)."""
         from ..jit import functional_call
 
-        model = self.model
+        model, backend = self.model, self.backend
 
-        def chunk(params, buffers, k_pools, v_pools, last, sidx, ids,
-                  tbl_row, ctx, n_valid, key, temp, top_k, top_p,
-                  firstbuf, fidx0):
-            cache = {"k": k_pools, "v": v_pools,
-                     "block_table": tbl_row[None, :], "lengths": ctx[None]}
+        def chunk(params, buffers, device, last, sidx, ids, tbl_row, ctx,
+                  n_valid, key, temp, top_k, top_p, firstbuf, fidx0):
+            cache = backend.step_cache(device, tbl_row[None, :], ctx[None])
             out = functional_call(model, params, buffers, ids[None, :],
                                   cache=cache, rng_key=key)
             logits, new_cache = out[0], out[-1]
-            k_pools, v_pools = new_cache["k"], new_cache["v"]
             if final:
                 lg = jnp.take_along_axis(
                     logits, (n_valid - 1)[None, None, None], axis=1)[:, 0]
@@ -942,13 +891,13 @@ class Engine:
                 last = last.at[sidx].set(nxt[0])
                 firstbuf = jax.lax.dynamic_update_slice(
                     firstbuf, nxt, (fidx0,))
-            return firstbuf, last, k_pools, v_pools
+            return firstbuf, last, backend.take_device(new_cache)
 
         return chunk
 
     def _prefill_batch(self, group, Pb: int):
         """Dense-causal prefill of ``n`` same-bucket requests in ONE call;
-        K/V scattered into the paged pools, first tokens sampled and
+        the backend writes what it left in the cache, first tokens sampled and
         scattered into the device-resident last-token vector in-program.
         Dispatched asynchronously; the ledger materializes the sampled
         tokens at the next sync."""
@@ -974,8 +923,8 @@ class Engine:
         t0 = time.perf_counter()
         with obs.span("serve.prefill", cat="serve",
                       args={"bucket": Pb, "n": n}):
-            self._first_buf, self._last_dev, self.k_pools, self.v_pools = fn(
-                self._params, self._buffers, self.k_pools, self.v_pools,
+            self._first_buf, self._last_dev, self.backend.device = fn(
+                self._params, self._buffers, self.backend.device,
                 self._last_dev, jnp.asarray(sidx), jnp.asarray(ids),
                 jnp.asarray(blocks), jnp.asarray(P), rnd.next_key(),
                 jnp.asarray(temps), jnp.asarray(top_ks), jnp.asarray(top_ps),
@@ -996,23 +945,15 @@ class Engine:
     def _build_prefill(self, Pb: int, n: int):
         from ..jit import functional_call
 
-        model = self.model
+        model, backend = self.model, self.backend
 
-        def prefill(params, buffers, k_pools, v_pools, last, sidx, ids,
-                    blocks, P, key, temps, top_ks, top_ps, firstbuf, fidx0):
-            from ..kernels.decode_attention import write_paged_prefill
-
-            cache = model.init_cache(n, Pb)
+        def prefill(params, buffers, device, last, sidx, ids, blocks, P,
+                    key, temps, top_ks, top_ps, firstbuf, fidx0):
+            cache = backend.prefill_cache(model.init_cache(n, Pb), P)
             out = functional_call(model, params, buffers, ids, cache=cache,
                                   rng_key=key)
             logits, new_cache = out[0], out[-1]
-            k_pools = list(k_pools)
-            v_pools = list(v_pools)
-            for li, (k_c, v_c) in enumerate(new_cache["kv"]):
-                for j in range(n):
-                    k_pools[li], v_pools[li] = write_paged_prefill(
-                        k_pools[li], v_pools[li], blocks[j],
-                        k_c[j, :Pb], v_c[j, :Pb])
+            device = backend.write_prefill(device, new_cache, sidx, blocks)
             # causality makes row j's logits at P[j]-1 independent of the
             # padded tail, so the batched result matches the n=1 program
             lg = jnp.take_along_axis(
@@ -1021,139 +962,9 @@ class Engine:
                                 temps, top_ks, top_ps)            # [n]
             last = last.at[sidx].set(nxt)
             firstbuf = jax.lax.dynamic_update_slice(firstbuf, nxt, (fidx0,))
-            return firstbuf, last, tuple(k_pools), tuple(v_pools)
+            return firstbuf, last, device
 
         return prefill
-
-    # -- recurrent-family programs (SSD / hybrid stacks) --------------------
-
-    def _get_ssd_prefill_fn(self, Pb: int):
-        fn = self._ssd_prefill_fns.get(Pb)
-        if fn is None:
-            fn = self._ssd_prefill_fns[Pb] = jax.jit(
-                self._build_ssd_prefill(Pb),
-                donate_argnums=(2, 3, 4, 5, 14))
-        return fn
-
-    def _get_ssd_decode_fn(self, k: int):
-        fn = self._decode_fns.get(("ssd", k))
-        if fn is None:
-            fn = self._decode_fns[("ssd", k)] = jax.jit(
-                self._build_ssd_decode(k), donate_argnums=(2, 3, 4, 7, 12))
-        return fn
-
-    def _build_ssd_prefill(self, Pb: int):
-        """B=1 prefill for a model with recurrent layers: dense forward
-        over the padded prompt with ``n_valid`` masking (exact — zeroed
-        projections are no-ops on the scan), then scatter the resulting
-        per-layer decode state into the slot's row of the engine's state
-        arrays; hybrid attention layers additionally scatter their K/V
-        into the paged pools exactly like the attention-family program."""
-        from ..jit import functional_call
-
-        model = self.model
-
-        def prefill(params, buffers, ssd_states, k_pools, v_pools, last,
-                    sidx, ids, blocks, n_valid, key, temp, top_k, top_p,
-                    firstbuf, fidx0):
-            from ..kernels.decode_attention import write_paged_prefill
-
-            cache = model.init_cache(1, Pb)
-            cache["n_valid"] = n_valid
-            out = functional_call(model, params, buffers, ids[None, :],
-                                  cache=cache, rng_key=key)
-            logits, new_cache = out[0], out[-1]
-            new_states = tuple(
-                {kk: cur[kk].at[sidx].set(st[kk][0]) for kk in cur}
-                for cur, st in zip(ssd_states, new_cache["ssd"]))
-            k_pools = list(k_pools)
-            v_pools = list(v_pools)
-            for ai, (k_c, v_c) in enumerate(new_cache["kv"]):
-                k_pools[ai], v_pools[ai] = write_paged_prefill(
-                    k_pools[ai], v_pools[ai], blocks,
-                    k_c[0, :Pb], v_c[0, :Pb])
-            lg = jnp.take_along_axis(
-                logits, (n_valid - 1)[None, None, None], axis=1)[:, 0]
-            nxt = _sample_batch(lg, jax.random.fold_in(key, 1),
-                                temp[None], top_k[None], top_p[None])
-            last = last.at[sidx].set(nxt[0])
-            firstbuf = jax.lax.dynamic_update_slice(firstbuf, nxt, (fidx0,))
-            return firstbuf, last, new_states, tuple(k_pools), tuple(v_pools)
-
-        return prefill
-
-    def _ssd_prefill_one(self, entry, Pb: int):
-        slot, req, _Pb, ids_row, blocks_row, P = entry
-        from ..framework import random as rnd
-
-        fn = self._get_ssd_prefill_fn(Pb)
-        if self._first_idx + 1 > self._first_seg:
-            self._full_first_bufs.append(self._first_buf)
-            self._first_buf = jnp.zeros((self._first_seg,), jnp.int32)
-            self._first_idx = 0
-        fidx0 = self._first_idx
-        self._first_idx += 1
-        self._obs_mark(req, "prefill", bucket=Pb, batch=1)
-        t0 = time.perf_counter()
-        self._obs_dispatched(req, t0)
-        with obs.span("serve.prefill", cat="serve",
-                      args={"bucket": Pb, "n": 1}):
-            (self._first_buf, self._last_dev, self._ssd_state, self.k_pools,
-             self.v_pools) = fn(
-                self._params, self._buffers, self._ssd_state, self.k_pools,
-                self.v_pools, self._last_dev,
-                jnp.asarray(slot.idx, jnp.int32),
-                jnp.asarray(ids_row), jnp.asarray(blocks_row),
-                jnp.asarray(P, jnp.int32), rnd.next_key(),
-                jnp.asarray(req.temperature, jnp.float32),
-                jnp.asarray(req.top_k, jnp.int32),
-                jnp.asarray(req.top_p, jnp.float32),
-                self._first_buf, jnp.asarray(fidx0, jnp.int32))
-        dt = time.perf_counter() - t0                    # dispatch cost only
-        req._prefill_dt = dt
-        self._pending.append(
-            ("prefill", req, len(self._full_first_bufs), fidx0))
-        self.stats["prefills"] += 1
-        self.stats["prefill_time"] += dt
-        self.stats["prefill_tokens"] += Pb
-        self.stats["generated_tokens"] += 1
-        obs.registry().counter(
-            "serve.prefill_tokens", **self._obs_labels()).inc(Pb)
-
-    def _build_ssd_decode(self, k: int):
-        """The decode-chunk program with the slot-state arrays threaded
-        through the scan alongside the (possibly empty) paged pools — the
-        model's serving forward advances both; inactive slots hold their
-        state bit-exactly via the ``lengths == 0`` mask."""
-        from ..jit import functional_call
-
-        model = self.model
-
-        def decode(params, buffers, ssd_states, k_pools, v_pools, tbl,
-                   lengths, last, key, temps, top_ks, top_ps, tokbuf, row0):
-            def substep(carry, i):
-                st, kp, vp, lens, lst = carry
-                cache = {"ssd": st, "k": kp, "v": vp, "block_table": tbl,
-                         "lengths": lens}
-                out = functional_call(model, params, buffers, lst[:, None],
-                                      cache=cache,
-                                      rng_key=jax.random.fold_in(key, 2 * i))
-                logits, new_cache = out[0], out[-1]
-                nxt = _sample_batch(logits[:, 0],
-                                    jax.random.fold_in(key, 2 * i + 1),
-                                    temps, top_ks, top_ps)
-                lst = jnp.where(lens > 0, nxt, lst)
-                return (new_cache["ssd"], new_cache["k"], new_cache["v"],
-                        new_cache["lengths"], lst), lst
-
-            (st, kp, vp, lens, lst), toks = jax.lax.scan(
-                substep, (ssd_states, k_pools, v_pools, lengths, last),
-                jnp.arange(k))
-            tokbuf = jax.lax.dynamic_update_slice(
-                tokbuf, toks, (row0, jnp.zeros((), row0.dtype)))
-            return tokbuf, lst, st, kp, vp, lens
-
-        return decode
 
     def _dispatch_chunk(self, k: int):
         with obs.span("serve.dispatch", cat="serve") as sp:
@@ -1180,7 +991,7 @@ class Engine:
         # inputs are bit-reusable device arrays — the lengths vector was
         # advanced ON DEVICE by the previous chunk and rides back in, so
         # the call uploads nothing
-        staged = (self.dispatch_staging and self._staged is not None
+        staged = (self._staged is not None
                   and self._staged[0] == self._sched_version)
         if staged:
             _, tbl_dev, len_dev, temps_dev, topk_dev, topp_dev = self._staged
@@ -1221,29 +1032,18 @@ class Engine:
                 **self._obs_labels()).observe(gap * 1e3)
         with obs.span("serve.decode-chunk", cat="serve",
                       args={"k": k, "staged": staged}):
-            if self._recurrent:
-                fn = self._get_ssd_decode_fn(k)
-                (self._tok_buf, lst, self._ssd_state, self.k_pools,
-                 self.v_pools, lens_out) = fn(
-                    self._params, self._buffers, self._ssd_state,
-                    self.k_pools, self.v_pools, tbl_dev, len_dev,
-                    self._last_dev, rnd.next_key(), temps_dev, topk_dev,
-                    topp_dev, self._tok_buf, jnp.asarray(row0, jnp.int32))
-            else:
-                fn = self._get_decode_fn(k)
-                (self._tok_buf, lst, self.k_pools, self.v_pools,
-                 lens_out) = fn(
-                    self._params, self._buffers, self.k_pools, self.v_pools,
-                    tbl_dev, len_dev, self._last_dev, rnd.next_key(),
-                    temps_dev, topk_dev, topp_dev,
-                    self._tok_buf, jnp.asarray(row0, jnp.int32))
+            fn = self._get_decode_fn(k)
+            self._tok_buf, lst, self.backend.device, lens_out = fn(
+                self._params, self._buffers, self.backend.device,
+                tbl_dev, len_dev, self._last_dev, rnd.next_key(),
+                temps_dev, topk_dev, topp_dev,
+                self._tok_buf, jnp.asarray(row0, jnp.int32))
         self._last_dev = lst
         self._last_dispatch_t = time.perf_counter()
-        if self.dispatch_staging:
-            # version is captured BEFORE the post-chunk finish releases
-            # below — a finish bumps it, correctly invalidating this entry
-            self._staged = (self._sched_version, tbl_dev, lens_out,
-                            temps_dev, topk_dev, topp_dev)
+        # version is captured BEFORE the post-chunk finish releases below —
+        # a finish bumps it, correctly invalidating this entry
+        self._staged = (self._sched_version, tbl_dev, lens_out,
+                        temps_dev, topk_dev, topp_dev)
         self.stats["decode_time"] += time.perf_counter() - t0
         self.stats["decode_steps"] += k
         self.stats["decode_calls"] += 1
@@ -1267,16 +1067,13 @@ class Engine:
     def _build_decode(self, k: int):
         from ..jit import functional_call
 
-        model = self.model
+        model, backend = self.model, self.backend
 
-        def decode(params, buffers, k_pools, v_pools, tbl, lengths, last,
-                   key, temps, top_ks, top_ps, tokbuf, row0):
-            B = temps.shape[0]
-
+        def decode(params, buffers, device, tbl, lengths, last, key, temps,
+                   top_ks, top_ps, tokbuf, row0):
             def substep(carry, i):
-                kp, vp, lens, lst = carry
-                cache = {"k": kp, "v": vp, "block_table": tbl,
-                         "lengths": lens}
+                dev, lens, lst = carry
+                cache = backend.step_cache(dev, tbl, lens)
                 out = functional_call(model, params, buffers, lst[:, None],
                                       cache=cache,
                                       rng_key=jax.random.fold_in(key, 2 * i))
@@ -1285,29 +1082,29 @@ class Engine:
                                     jax.random.fold_in(key, 2 * i + 1),
                                     temps, top_ks, top_ps)
                 # inactive slots (lengths 0) hold their state: the model's
-                # cached forward leaves their length at 0 and their writes
-                # land in the trash block
+                # cached forward leaves their length at 0, their writes land
+                # in the trash block and their slot state stays bit-exact
                 lst = jnp.where(lens > 0, nxt, lst)
-                return (new_cache["k"], new_cache["v"],
+                return (backend.take_device(new_cache),
                         new_cache["lengths"], lst), lst
 
-            (kp, vp, lens, lst), toks = jax.lax.scan(
-                substep, (k_pools, v_pools, lengths, last), jnp.arange(k))
+            (dev, lens, lst), toks = jax.lax.scan(
+                substep, (device, lengths, last), jnp.arange(k))
             tokbuf = jax.lax.dynamic_update_slice(
                 tokbuf, toks, (row0, jnp.zeros((), row0.dtype)))
             # final lengths ride back out so dispatch staging can reuse
             # them as the NEXT chunk's input without a host round trip
-            return tokbuf, lst, kp, vp, lens
+            return tokbuf, lst, dev, lens
 
         return decode
 
     def _decode_dummy_args(self):
-        """Throwaway inputs of a (non-recurrent) decode-chunk program:
-        lengths 0, so the trash block absorbs every write."""
+        """Throwaway inputs of a decode-chunk program: lengths 0, so the
+        trash block absorbs every write and every slot holds its state."""
         from ..framework import random as rnd
 
         zeros = np.zeros((self.max_batch,), np.int32)
-        return (self._params, self._buffers, self.k_pools, self.v_pools,
+        return (self._params, self._buffers, self.backend.device,
                 jnp.asarray(self._tbl.copy()), jnp.asarray(zeros),
                 jnp.asarray(zeros), rnd.next_key(),
                 jnp.asarray(zeros, jnp.float32), jnp.asarray(zeros),
@@ -1337,58 +1134,24 @@ class Engine:
         """Run the ladder; returns how many engine programs it called."""
         from ..framework import random as rnd
 
-        zeros = np.zeros((self.max_batch,), np.int32)
         n_prog = 0
         k = 1
         while k <= self.decode_chunk:
-            if self._recurrent:
-                fn = self._get_ssd_decode_fn(k)
-                (buf, _lst, self._ssd_state, self.k_pools, self.v_pools,
-                 _lens) = fn(
-                    self._params, self._buffers, self._ssd_state,
-                    self.k_pools, self.v_pools, jnp.asarray(self._tbl),
-                    jnp.asarray(zeros), jnp.asarray(zeros), rnd.next_key(),
-                    jnp.asarray(zeros, jnp.float32), jnp.asarray(zeros),
-                    jnp.ones((self.max_batch,), jnp.float32),
-                    jnp.zeros((self._tok_seg_rows, self.max_batch),
-                              jnp.int32),
-                    jnp.asarray(0, jnp.int32))
-            else:
-                fn = self._get_decode_fn(k)
-                buf, _lst, self.k_pools, self.v_pools, _lens = fn(
-                    *self._decode_dummy_args())
+            buf, _lst, self.backend.device, _lens = self._get_decode_fn(k)(
+                *self._decode_dummy_args())
             jax.block_until_ready(buf)
             n_prog += 1
             k *= 2
-        if self._recurrent:
-            for Pb in self.prefill_buckets:
-                fn = self._get_ssd_prefill_fn(Pb)
-                n_blk = Pb // self.block_size if self._uses_pages else 0
-                (_buf, self._last_dev, self._ssd_state, self.k_pools,
-                 self.v_pools) = fn(
-                    self._params, self._buffers, self._ssd_state,
-                    self.k_pools, self.v_pools, self._last_dev,
-                    jnp.asarray(0, jnp.int32), jnp.zeros((Pb,), jnp.int32),
-                    jnp.zeros((n_blk,), jnp.int32),
-                    jnp.asarray(1, jnp.int32), rnd.next_key(),
-                    jnp.asarray(0.0, jnp.float32),
-                    jnp.asarray(0, jnp.int32),
-                    jnp.asarray(1.0, jnp.float32),
-                    jnp.zeros((self._first_seg,), jnp.int32),
-                    jnp.asarray(0, jnp.int32))
-                n_prog += 1
-            jax.block_until_ready(self._ssd_state)
-            return n_prog
         for Pb in self.prefill_buckets:
-            for n in (1, 2, 4):
+            for n in sorted(self.backend.prefill_ladder):
                 if n > self.max_batch:
                     break
                 fn = self._get_prefill_fn(Pb, n)
-                _buf, self._last_dev, self.k_pools, self.v_pools = fn(
-                    self._params, self._buffers, self.k_pools, self.v_pools,
+                _buf, self._last_dev, self.backend.device = fn(
+                    self._params, self._buffers, self.backend.device,
                     self._last_dev, jnp.zeros((n,), jnp.int32),
                     jnp.zeros((n, Pb), jnp.int32),
-                    jnp.zeros((n, Pb // self.block_size), jnp.int32),
+                    jnp.zeros((n, self.backend.blocks_for(Pb)), jnp.int32),
                     jnp.ones((n,), jnp.int32), rnd.next_key(),
                     jnp.zeros((n,), jnp.float32),
                     jnp.zeros((n,), jnp.int32), jnp.ones((n,), jnp.float32),
@@ -1404,8 +1167,8 @@ class Engine:
                 variants.append((self._bucket(self.prefill_chunk), False))
             for Cb, final in variants:
                 fn = self._get_chunk_fn(Cb, final)
-                _b, self._last_dev, self.k_pools, self.v_pools = fn(
-                    self._params, self._buffers, self.k_pools, self.v_pools,
+                _b, self._last_dev, self.backend.device = fn(
+                    self._params, self._buffers, self.backend.device,
                     self._last_dev, jnp.asarray(0, jnp.int32),
                     jnp.zeros((Cb,), jnp.int32),
                     jnp.zeros((self.max_blocks_per_seq,), jnp.int32),
@@ -1415,7 +1178,7 @@ class Engine:
                     jnp.zeros((self._first_seg,), jnp.int32),
                     jnp.asarray(0, jnp.int32))
                 n_prog += 1
-        jax.block_until_ready(self.k_pools)
+        jax.block_until_ready(self.backend.device)
         return n_prog
 
     # -- deferred-sync materialization --------------------------------------

@@ -1,41 +1,56 @@
-"""CacheBackend — the seam that makes the serving tier model-agnostic.
+"""CacheBackend — what a sequence's cache IS, on the host and on the device.
 
 A sequence's "cache" used to mean one thing: a chain of paged KV blocks.
 The SSD model family (``models/ssd.py``) breaks that assumption — its decode
 state is a CONSTANT-size per-layer tensor, so there is nothing to page, hash
-or grow.  This module carves the cache policy out of the engine behind one
-protocol, with two concrete backends:
+or grow.  This module holds the whole cache policy behind one protocol, so
+the engine keeps the scheduler and ONE family of programs and knows no
+cache kind by name:
 
-- :class:`PagedKV` — the existing refcounted block pool + vLLM-style prefix
-  cache, extracted from the engine verbatim (behavior-identical; the engine
-  delegates its ``_free``/``_ref``/``_index``/``_hash_of``/``_lru``
-  attributes here so existing tests and tools keep working).
+- :class:`PagedKV` — the refcounted block pool + vLLM-style prefix cache,
+  and the per-layer K/V pools those blocks address.
 - :class:`RecurrentState` — fixed per-slot state residency: ``alloc`` is a
   no-op returning zero blocks, ``seq_bytes`` is FLAT in context length, and
-  prefix caching / block hashing are structurally unsupported (the router
-  degrades to headroom+load scoring).
+  prefix caching / block hashing / chunked prefill are structurally
+  unsupported (the router degrades to headroom+load scoring); on the device,
+  one state dict per SSD layer, ``max_slots`` wide.
+- :class:`HybridCache` — both at once for a stack that mixes attention and
+  SSD layers: every verb is answered by composing the two parts.
 
-A hybrid stack (attention + SSD layers) composes both: block bookkeeping for
-its attention layers rides the paged side while the SSD layers' bytes ride
-the state side — one :class:`CacheBackend` answers for the whole model.
+**Host side.** The verbs ``alloc`` / ``append`` / ``gather`` / ``release`` /
+``acquire_slot`` / ``release_slot`` / ``migrate`` / ``plan_bytes`` are what
+the engine, ``memory_plan()``, the prefix cache and the router go through;
+``migrate`` only PLANS today (the byte/unit manifest a future disaggregated
+tier would ship — ROADMAP item 1).
 
-The protocol verbs (``alloc`` / ``append`` / ``gather`` / ``release`` /
-``migrate`` / ``plan_bytes``) are what the engine, ``memory_plan()``, the
-prefix cache, and the router go through; ``migrate`` only PLANS today (the
-byte/unit manifest a future disaggregated tier would ship — ROADMAP item 1).
+**Device side.** The backend also owns the cache's device arrays, as ONE
+pytree in ``device`` (a dict keyed like the model's serving cache: ``k`` and
+``v`` for pools, ``ssd`` for slot states — an attention-only model's pytree
+holds its pools and nothing else).  ``init_device`` builds it from the
+model; inside a program ``step_cache`` turns it into the ``cache`` dict the
+model's forward takes for a decode sub-step or a prefill chunk,
+``take_device`` reads the new arrays back out of the forward's
+``new_cache``, and ``write_prefill`` moves a dense prefill's ``new_cache``
+into the admitted slots.  ``prefill_ladder`` says how many same-bucket
+prompts one prefill call may take.  The engine donates ``device`` through
+every program and stores what comes back.
 
-Backends are constructed from a model's ``cache_spec()`` dict (see
+Backends are constructed from a model's ``cache_spec()`` dict (every model
+the engine serves answers it: ``LlamaForCausalLM.cache_spec``,
 ``SSDForCausalLM.cache_spec``): per-layer kinds plus the two byte
 quantities — ``kv_bytes_per_token_layer`` and ``state_bytes_per_slot`` —
 that fully determine footprint arithmetic without any model knowledge.
+``KINDS`` maps a layer kind to the backend that caches it: a new kind of
+cache is one class here and one entry there.
 """
 
 from __future__ import annotations
 
 import collections
-from typing import Dict, List, Optional
+from typing import Callable, Dict, List, Optional, Tuple
 
-__all__ = ["CacheBackend", "PagedKV", "RecurrentState", "make_backend"]
+__all__ = ["CacheBackend", "PagedKV", "RecurrentState", "HybridCache",
+           "KINDS", "make_backend"]
 
 
 class CacheBackend:
@@ -45,6 +60,15 @@ class CacheBackend:
 
     kind: str = "abstract"
     supports_prefix_cache: bool = False
+    # chunked / suffix prefill rides a block-aligned context offset
+    supports_chunked_prefill: bool = False
+    # sizes of one prefill call, widest first: same-bucket admissions batch
+    # through the widest rung they fill
+    prefill_ladder: Tuple[int, ...] = (4, 2, 1)
+    # the entries of the model's serving ``cache`` dict this backend keeps
+    state_keys: Tuple[str, ...] = ()
+    # the device arrays themselves: one pytree, donated through every program
+    device: Optional[Dict] = None
 
     # -- block-granular bookkeeping (no-ops for blockless backends) ---------
 
@@ -80,6 +104,45 @@ class CacheBackend:
     def register(self, hashes: List[bytes], blocks: List[int]) -> None:
         """Publish a sequence's cacheable prefix blocks under their chain
         hashes (first writer wins)."""
+
+    def lookup_chain(self, hashes: List[bytes]) -> int:
+        """Longest consecutive resident prefix (in blocks)."""
+        return 0
+
+    # -- slot residency (no-ops for backends with nothing per slot) ---------
+
+    def acquire_slot(self, idx: int) -> None:
+        """Slot ``idx`` takes a sequence."""
+
+    def release_slot(self, idx: int) -> None:
+        """Slot ``idx`` lets its sequence go.  Exactly-once, like blocks."""
+
+    # -- device state -------------------------------------------------------
+
+    def init_device(self, model) -> Dict:
+        """The zeroed device arrays of this cache, built from ``model``."""
+        return {}
+
+    def step_cache(self, device: Dict, block_table, lengths) -> Dict:
+        """The ``cache`` the model's forward takes to advance the slots in
+        ``block_table`` / ``lengths`` (a decode sub-step, a prefill chunk)."""
+        return {**device, "block_table": block_table, "lengths": lengths}
+
+    def take_device(self, new_cache: Dict) -> Dict:
+        """The device arrays out of that forward's ``new_cache``."""
+        return {k: new_cache[k] for k in self.state_keys}
+
+    def prefill_cache(self, cache: Dict, n_valid) -> Dict:
+        """The dense ``model.init_cache`` of a whole-prompt prefill, with
+        what this cache needs to know of the prompts' true lengths
+        ``n_valid`` ([n] int32)."""
+        return cache
+
+    def write_prefill(self, device: Dict, new_cache: Dict, slots,
+                      blocks) -> Dict:
+        """Move a dense prefill's ``new_cache`` (n rows) into ``device``:
+        row j belongs to slot ``slots[j]`` and owns ``blocks[j]``."""
+        return device
 
     # -- accounting ---------------------------------------------------------
 
@@ -124,6 +187,8 @@ class PagedKV(CacheBackend):
     """
 
     kind = "paged_kv"
+    supports_chunked_prefill = True
+    state_keys = ("k", "v")
 
     def __init__(self, num_blocks: int, block_size: int,
                  bytes_per_token: int, prefix_cache: bool = True):
@@ -215,6 +280,25 @@ class PagedKV(CacheBackend):
             n += 1
         return n
 
+    def init_device(self, model) -> Dict:
+        k, v = model.init_paged_pools(self.num_blocks, self.block_size)
+        return {"k": k, "v": v}
+
+    def write_prefill(self, device, new_cache, slots, blocks):
+        """Scatter each row's bucket-padded K/V into its blocks (a freed
+        padding block's id is 0 by then: the trash block takes the tail)."""
+        from ..kernels.decode_attention import write_paged_prefill
+
+        n, n_blocks = blocks.shape
+        Pb = n_blocks * self.block_size
+        k_pools, v_pools = list(device["k"]), list(device["v"])
+        for li, (k_c, v_c) in enumerate(new_cache["kv"]):
+            for j in range(n):
+                k_pools[li], v_pools[li] = write_paged_prefill(
+                    k_pools[li], v_pools[li], blocks[j],
+                    k_c[j, :Pb], v_c[j, :Pb])
+        return {**device, "k": tuple(k_pools), "v": tuple(v_pools)}
+
     def pool_bytes(self) -> int:
         return self.num_blocks * self.block_bytes
 
@@ -241,6 +325,9 @@ class RecurrentState(CacheBackend):
 
     kind = "recurrent"
     supports_prefix_cache = False
+    # the recurrent forward masks the padded tail by ONE scalar n_valid
+    prefill_ladder = (1,)
+    state_keys = ("ssd",)
 
     def __init__(self, max_slots: int, state_bytes_per_slot: int):
         self.max_slots = max_slots
@@ -258,6 +345,18 @@ class RecurrentState(CacheBackend):
 
     def free_slots(self) -> int:
         return self.max_slots - len(self._live)
+
+    def init_device(self, model) -> Dict:
+        return {"ssd": model.init_recurrent_slots(self.max_slots)}
+
+    def prefill_cache(self, cache, n_valid):
+        # exact: projections zeroed past n_valid are no-ops on the scan
+        return {**cache, "n_valid": n_valid[0]}
+
+    def write_prefill(self, device, new_cache, slots, blocks):
+        return {**device, "ssd": tuple(
+            {name: cur[name].at[slots].set(new[name]) for name in cur}
+            for cur, new in zip(device["ssd"], new_cache["ssd"]))}
 
     def state_bytes(self) -> int:
         return self.max_slots * self.state_bytes_per_slot
@@ -288,6 +387,9 @@ class HybridCache(CacheBackend):
     def __init__(self, pages: PagedKV, state: RecurrentState):
         self.pages = pages
         self.state = state
+        self.prefill_ladder = tuple(
+            n for n in pages.prefill_ladder if n in state.prefill_ladder)
+        self.state_keys = pages.state_keys + state.state_keys
 
     def blocks_for(self, n_tokens: int) -> int:
         return self.pages.blocks_for(n_tokens)
@@ -300,6 +402,25 @@ class HybridCache(CacheBackend):
 
     def release(self, block: int) -> None:
         self.pages.release(block)
+
+    def acquire_slot(self, idx: int) -> None:
+        self.state.acquire_slot(idx)
+
+    def release_slot(self, idx: int) -> None:
+        self.state.release_slot(idx)
+
+    def init_device(self, model) -> Dict:
+        return {**self.pages.init_device(model),
+                **self.state.init_device(model)}
+
+    def prefill_cache(self, cache, n_valid):
+        return self.state.prefill_cache(
+            self.pages.prefill_cache(cache, n_valid), n_valid)
+
+    def write_prefill(self, device, new_cache, slots, blocks):
+        return self.state.write_prefill(
+            self.pages.write_prefill(device, new_cache, slots, blocks),
+            new_cache, slots, blocks)
 
     def pool_bytes(self) -> int:
         return self.pages.pool_bytes()
@@ -320,6 +441,22 @@ class HybridCache(CacheBackend):
                 "units": p["units"] + s["units"]}
 
 
+def _paged(spec, num_blocks, block_size, max_slots, prefix_cache):
+    return PagedKV(num_blocks, block_size,
+                   spec["kv_layers"] * spec["kv_bytes_per_token_layer"],
+                   prefix_cache=prefix_cache)
+
+
+def _recurrent(spec, num_blocks, block_size, max_slots, prefix_cache):
+    return RecurrentState(max_slots, spec["state_bytes_per_slot"])
+
+
+# layer kind (an entry of ``cache_spec()["kinds"]``) -> the backend that
+# caches layers of that kind
+KINDS: Dict[str, Callable[..., CacheBackend]] = {
+    "attention": _paged, "ssd": _recurrent}
+
+
 def make_backend(spec: Dict, num_blocks: int, block_size: int,
                  max_slots: int, prefix_cache: bool = True) -> CacheBackend:
     """Build the backend a model's ``cache_spec()`` calls for.
@@ -327,16 +464,10 @@ def make_backend(spec: Dict, num_blocks: int, block_size: int,
     All-attention -> :class:`PagedKV` (prefix cache as configured);
     all-SSD -> :class:`RecurrentState`; mixed -> :class:`HybridCache`
     (prefix cache forced off — see the class docstring)."""
-    kinds = spec["kinds"]
-    has_kv = any(k == "attention" for k in kinds)
-    has_state = any(k == "ssd" for k in kinds)
-    if has_kv:
-        pages = PagedKV(num_blocks, block_size,
-                        spec["kv_layers"] * spec["kv_bytes_per_token_layer"],
-                        prefix_cache=prefix_cache and not has_state)
-    if not has_state:
-        return pages
-    state = RecurrentState(max_slots, spec["state_bytes_per_slot"])
-    if not has_kv:
-        return state
-    return HybridCache(pages, state)
+    unknown = set(spec["kinds"]) - set(KINDS)
+    if unknown:
+        raise ValueError(f"no cache backend for layer kinds {sorted(unknown)}")
+    present = [k for k in KINDS if k in spec["kinds"]]
+    parts = [KINDS[k](spec, num_blocks, block_size, max_slots,
+                      prefix_cache and len(present) == 1) for k in present]
+    return parts[0] if len(parts) == 1 else HybridCache(*parts)

@@ -235,7 +235,7 @@ class Router:
             return 0
         if not eng.prefix_cache:
             return 0
-        return eng._pages.lookup_chain(
+        return eng.backend.lookup_chain(
             prefix_block_hashes(prompt_ids, eng.block_size))
 
     @staticmethod

@@ -56,7 +56,6 @@ check tiny   600 --steps 2
 check ocr    600
 check moe    600
 check decode 600
-check serve  600
 # small/base are compile-only on CPU: cost-analyse, skip the timed run
 check small  600 --audit-only
 check base   900 --audit-only

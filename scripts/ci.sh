@@ -9,9 +9,6 @@
 #   schedule_gate pipeline-schedule matrix + host self-lint
 #   reshard_gate  resharding property suite + plan-peak audit vs
 #                 scripts/RESHARD_BASELINE.json
-#   ssd_gate      SSD family: kernel bit-identity, RecurrentState serve
-#                 parity, memory_plan honesty, flat-footprint invariant
-#                 vs scripts/SSD_BASELINE.json
 #   overlap_gate  collective-overlap analyzer (exposed all-gather drop
 #                 >= 50% + counts) vs scripts/OVERLAP_BASELINE.json
 #   tune_gate     static auto-parallel tuner (chosen >= hand-picked by
@@ -54,8 +51,6 @@ stage lint_gate     ./scripts/lint_gate.sh
 stage mem_gate      ./scripts/mem_gate.sh
 stage schedule_gate ./scripts/schedule_gate.sh
 stage reshard_gate  ./scripts/reshard_gate.sh
-stage serve_gate    ./scripts/serve_gate.sh
-stage ssd_gate      ./scripts/ssd_gate.sh
 stage overlap_gate  ./scripts/overlap_gate.sh
 stage tune_gate     ./scripts/tune_gate.sh
 stage obs_gate      ./scripts/obs_gate.sh

@@ -62,7 +62,6 @@ check tiny   600 --steps 2
 check ocr    600
 check moe    600
 check decode 600
-check serve  600
 # small/base are compile-only on CPU: lint the lowered step, skip the run
 check small  600 --audit-only
 check base   900 --audit-only

@@ -12,8 +12,8 @@
 #
 # mem-remat-candidate is advisory: reported, never gated.  Two absolute
 # invariants fail regardless of baseline: the liveness peak must agree with
-# XLA's own memory_analysis() within 10% on every preset program (including
-# the serve prefill program), and mem_codes must be present at all (a
+# XLA's own memory_analysis() within 10% on every preset program, and
+# mem_codes must be present at all (a
 # mem_error in the BENCH line means the sweep itself broke).
 #
 # Defect injection (proves the gate can fail):
@@ -80,7 +80,6 @@ check tiny   600 --steps 2
 check ocr    600
 check moe    600
 check decode 600
-check serve  600
 # small/base are compile-only on CPU: mem-lint the lowered step, skip the run
 check small  600 --audit-only
 check base   900 --audit-only

@@ -60,16 +60,6 @@ line=$(timeout 2400 python bench.py --device tpu --pp 4 --pp-runtime mpmd \
        --pp-schedule zb --otrace chiprun_out/revival_otrace.json --otrace-xla \
        2>/dev/null | tail -1)
 [ -n "$line" ] && echo "$line" >> "$OUT" && echo "$line" | head -c 200 >&2 && echo >&2
-echo "[revival] serve (post-rework)" >&2
-line=$(timeout 2400 python bench.py --preset serve --device tpu 2>/dev/null | tail -1)
-[ -n "$line" ] && echo "$line" >> "$OUT" && echo "$line" | head -c 200 >&2 && echo >&2
-# serving-tier arrival traces (prefix cache + chunked prefill): CPU-proxy
-# ratios are in SERVE_BASELINE.json; these put the TPU numbers next to them
-for trace in shared_prefix long_prompt; do
-    echo "[revival] serve --trace $trace" >&2
-    line=$(timeout 2400 python bench.py --preset serve --device tpu --trace $trace 2>/dev/null | tail -1)
-    [ -n "$line" ] && echo "$line" >> "$OUT" && echo "$line" | head -c 200 >&2 && echo >&2
-done
 echo "[revival] sampling smoke" >&2
 timeout 1200 env -u JAX_PLATFORMS python - <<'PY' >&2
 import numpy as np, sys

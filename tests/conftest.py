@@ -30,3 +30,19 @@ def pytest_configure(config):
     config.addinivalue_line(
         "markers", "slow: long-running tests excluded from the tier-1 "
         "selection (-m 'not slow')")
+
+
+import pytest
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _global_mesh_stays_in_its_file():
+    """A file that sets the process-global mesh (``fleet.init``,
+    ``set_global_mesh``) keeps it to itself: the next file of the same
+    xdist worker builds its models without it (a Llama built under a stray
+    mesh carries sharding constraints, which the ONNX exporter refuses)."""
+    from paddle_tpu.distributed import mesh
+
+    before = mesh.get_mesh()
+    yield
+    mesh.set_global_mesh(before)

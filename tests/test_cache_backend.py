@@ -3,10 +3,16 @@ cache policy.  Both concrete backends (paged KV blocks, recurrent state
 slots) plus the hybrid composition must honor the same ledger discipline —
 exactly-once release, pressure-driven reclaim, honest byte accounting —
 and ``make_backend`` must pick the right policy from a model's
-``cache_spec()``.  These tests are pure host-side bookkeeping (no jit)."""
+``cache_spec()``.  These tests are pure host-side bookkeeping (no jit),
+but the last: a cache kind the tree has never heard of, defined here, is
+served through the engine by adding one entry to ``KINDS``."""
 
+import types
+
+import numpy as np
 import pytest
 
+from paddle_tpu.serving import cache_backend
 from paddle_tpu.serving.cache_backend import (
     CacheBackend, HybridCache, PagedKV, RecurrentState, make_backend)
 
@@ -182,3 +188,137 @@ class TestMakeBackend:
     def test_abstract_base_refuses_release(self):
         with pytest.raises(RuntimeError, match="blockless"):
             CacheBackend().release(3)
+
+    def test_unknown_kind_is_refused(self):
+        with pytest.raises(ValueError, match="no cache backend"):
+            make_backend(_spec(["attention", "conv"], kv_layers=1, kv_bpt=8),
+                         num_blocks=8, block_size=16, max_slots=4)
+
+
+# ----------------------------------------------------------- a third kind --
+
+class RunningSum(CacheBackend):
+    """A slot's cache is ONE int32: the sum of every token it has seen.
+    Neither pages nor the SSD state — no blocks, nothing to hash, and a
+    prefill call takes a whole rung of prompts at once."""
+
+    kind = "running_sum"
+    state_keys = ("sum",)
+
+    def __init__(self, max_slots):
+        self.max_slots = max_slots
+        self.live = set()
+
+    def acquire_slot(self, idx):
+        assert idx not in self.live
+        self.live.add(idx)
+
+    def release_slot(self, idx):
+        self.live.remove(idx)
+
+    def init_device(self, model):
+        import jax.numpy as jnp
+
+        return {"sum": jnp.zeros((self.max_slots,), jnp.int32)}
+
+    def write_prefill(self, device, new_cache, slots, blocks):
+        return {"sum": device["sum"].at[slots].set(new_cache["sum"])}
+
+    def state_bytes(self):
+        return 4 * self.max_slots
+
+
+def _toy_model(vocab=31):
+    import jax.numpy as jnp
+
+    import paddle_tpu as paddle
+    from paddle_tpu.framework.dispatch import apply_op
+    from paddle_tpu.framework.tensor import Tensor
+    from paddle_tpu.nn.initializer import Normal
+    from paddle_tpu.nn.layers import Layer
+
+    def raw(x):
+        return x._data if isinstance(x, Tensor) else x
+
+    class SumLM(Layer):
+        """Next-token logits are row ``(sum of the tokens so far) % vocab``
+        of one table."""
+
+        def __init__(self):
+            super().__init__()
+            self.config = types.SimpleNamespace(
+                vocab_size=vocab, hidden_size=8, num_attention_heads=1,
+                dtype="float32")
+            self.table = self.create_parameter(
+                [vocab, vocab], dtype="float32",
+                default_initializer=Normal(0.0, 1.0))
+
+        def cache_spec(self):
+            return {"kinds": ("running_sum",), "state_bytes_per_slot": 4,
+                    "kv_layers": 0, "kv_bytes_per_token_layer": 0}
+
+        def init_cache(self, batch_size, max_len, dtype=None):
+            return {"sum": jnp.zeros((batch_size,), jnp.int32)}
+
+        def forward(self, input_ids, position_ids=None, cache=None):
+            ids = raw(input_ids)
+            acc = raw(cache["sum"])
+            if "block_table" in cache:       # decode: one token a live slot
+                lengths = raw(cache["lengths"])
+                live = lengths > 0
+                sums = jnp.where(live, acc + ids[:, 0], acc)[:, None]
+                new_cache = {"sum": sums[:, 0], "lengths": lengths + live}
+            else:                            # prefill: pad tokens are 0
+                sums = acc[:, None] + jnp.cumsum(ids, axis=1)
+                new_cache = {"sum": sums[:, -1]}
+            logits = apply_op("toy_head", lambda t: t[sums % vocab],
+                              (self.table,), {})
+            return logits, new_cache
+
+    paddle.seed(0)
+    return SumLM()
+
+
+def test_third_cache_kind_serves_through_engine(monkeypatch):
+    """A new kind of cache is one class and one ``KINDS`` entry: the engine
+    serves it token for token like its own step-by-step reference, with
+    batched prefills, slot reuse and a mid-run admission, and nothing in
+    ``serving/__init__.py`` is patched."""
+    from paddle_tpu.serving import Engine, GenRequest
+
+    monkeypatch.setitem(
+        cache_backend.KINDS, "running_sum",
+        lambda spec, num_blocks, block_size, max_slots, prefix_cache:
+            RunningSum(max_slots))
+    model = _toy_model()
+    vocab = model.config.vocab_size
+    table = np.asarray(model.table._data)
+
+    def reference(prompt, n_new):
+        acc, out = int(prompt.sum()), []
+        for _ in range(n_new):
+            out.append(int(np.argmax(table[acc % vocab])))
+            acc += out[-1]
+        return out
+
+    eng = Engine(model, max_batch=4, num_blocks=4, block_size=16,
+                 prefill_buckets=(16, 32), decode_chunk=4)
+    assert isinstance(eng.backend, RunningSum)
+    assert not eng.prefix_cache and eng.prefill_chunk is None
+    rng = np.random.default_rng(0)
+    lengths = (5, 9, 12, 3, 20, 27, 7)       # 7 requests on 4 slots
+    news = (6, 3, 9, 1, 5, 8, 4)
+    prompts = [rng.integers(1, vocab, size=n).astype(np.int32)
+               for n in lengths]
+    for i, (p, n) in enumerate(zip(prompts, news)):
+        eng.add_request(GenRequest(prompt_ids=p, max_new_tokens=n,
+                                   request_id=f"r{i}"))
+    outs = {o.request_id: list(o.output_ids)
+            for o in eng.run_to_completion()}
+    for i, (p, n) in enumerate(zip(prompts, news)):
+        assert outs[f"r{i}"] == reference(p, n), f"r{i}"
+    assert eng.stats["prefills"] == 7 and eng.stats["evictions"] == 0
+    assert (16, 4) in eng._prefill_fns       # the first four went as one call
+    assert eng.backend.live == set()
+    assert eng.memory_plan()["state_bytes"] == 16
+    assert eng.backend.device["sum"].shape == (4,)

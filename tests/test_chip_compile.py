@@ -177,7 +177,7 @@ def test_decode_chunk_copies_no_pool(one_chip, kernel_branch, k):
         max_position_embeddings=1024, dtype="bfloat16"))
     eng = Engine(model, max_batch=32, num_blocks=48, block_size=BS,
                  prefill_buckets=(BS,))
-    pool = eng.k_pools[0]
+    pool = eng.backend.device["k"][0]
     assert pool.shape == (48, HK, BS, D) and pool.dtype == BF16
     args = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),

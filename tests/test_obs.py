@@ -536,14 +536,57 @@ class TestServingLifecycle:
 
 class TestBubbleCrosscheck:
     def test_trace_agrees_with_analytic_pp2(self):
-        from paddle_tpu.distributed.parallel.mpmd import \
-            mpmd_bubble_crosscheck
+        """What the cross-check is for — every op span in its emitted
+        tick, co-scheduled as the linter certified — judged free of the
+        machine's load: the executor's own op stream (five traced steps of
+        the threaded pipeline) is replayed with each span's duration set to
+        the measured cost of its (kind, stage), and must then price to the
+        analytic bubble exactly.  A span the executor dropped or put in
+        another tick moves it.  The wall-clock residual itself
+        (``rel_err``) is what ``bench.py --preset obs`` reports from a
+        quiet machine."""
+        from paddle_tpu.analysis.schedule_lint import dag_bubble_fraction
+        from paddle_tpu.distributed.parallel.mpmd import (
+            mpmd_bubble_crosscheck, trace_bubble_from_events)
 
-        r = mpmd_bubble_crosscheck(n_stages=2, n_micro=4, dim=256, mb=32,
-                                   steps=5, schedule="ZB")
-        assert r["n_op_spans"] > 0
-        assert r["analytic_bubble"] > 0
-        assert r["rel_err"] <= 0.15, r
+        S, M, steps = 2, 4, 5
+        tr = obs.enable_tracing(clear=True)
+        r = mpmd_bubble_crosscheck(n_stages=S, n_micro=M, dim=256, mb=32,
+                                   steps=steps, schedule="ZB")
+        ops = [e for e in tr.events()
+               if e.get("cat") == "mpmd.op" and e.get("ph") == "X"]
+        assert r["n_op_spans"] > 0 and r["analytic_bubble"] > 0
+        # every op of every step: the five, and the compiling one before them
+        assert len(ops) == (steps + 1) * r["n_op_spans"]
+        table = trace_bubble_from_events(ops, S)["cost_table"]
+        assert all(c > 0 for c in table.values())
+
+        def priced(events):
+            return trace_bubble_from_events(
+                [{**e, "dur": table[(e["args"]["kind"], e["args"]["stage"])]}
+                 for e in events], S)["fraction"]
+
+        analytic = dag_bubble_fraction(
+            r["schedule"], S, M, cost_of=lambda k, s: table[(k, s)])
+        assert priced(ops) == pytest.approx(analytic["fraction"], rel=1e-9)
+        # the replay has teeth.  The victim is an op of the lighter stage of
+        # a tick both stages work in: dropped, its stage's busy time falls;
+        # run in a tick of its own after the last, the wall grows by its cost
+        ident = lambda e: tuple(e["args"][k]
+                                for k in ("tick", "stage", "kind", "micro"))
+        load = {}
+        for tick, stage, kind, _ in set(map(ident, ops)):
+            row = load.setdefault(tick, [0.0] * S)
+            row[stage] += table[(kind, stage)]
+        tick, row = next((t, r) for t, r in sorted(load.items()) if all(r))
+        victim = next(i for i in map(ident, ops)
+                      if i[:2] == (tick, row.index(min(row))))
+        dropped = [e for e in ops if ident(e) != victim]
+        late = [{**e, "args": {**e["args"], "tick": max(load) + 1}}
+                if ident(e) == victim else e for e in ops]
+        for broken in (dropped, late):
+            assert priced(broken) != pytest.approx(analytic["fraction"],
+                                                   rel=1e-3)
 
     @pytest.mark.slow
     def test_trace_agrees_with_analytic_pp4(self):

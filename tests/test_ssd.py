@@ -244,18 +244,29 @@ def _prompts(cfg, lengths, seed=0):
             for n in lengths]
 
 
-def test_engine_pure_ssd_matches_generate(ssd_model):
-    prompts = _prompts(ssd_model.config, (7, 13, 24))
-    eng, outs = _serve(ssd_model, prompts)
+def _device_nbytes(eng):
+    return sum(a.nbytes for a in jax.tree.leaves(eng.backend.device))
+
+
+@pytest.mark.parametrize("lengths,max_new", [
+    ((7, 13, 24), 8),
+    ((7, 13, 24, 18, 9, 21), 16),        # six on four slots: slots are reused
+])
+def test_engine_pure_ssd_matches_generate(ssd_model, lengths, max_new):
+    prompts = _prompts(ssd_model.config, lengths)
+    eng, outs = _serve(ssd_model, prompts, max_new=max_new)
     assert isinstance(eng.backend, RecurrentState)
     assert not eng.prefix_cache          # forced off: nothing to hash
-    for i, ref in enumerate(_gen_ref(ssd_model, prompts)):
+    assert len(outs) == len(prompts)
+    for i, ref in enumerate(_gen_ref(ssd_model, prompts, max_new)):
         assert np.array_equal(outs[f"r{i}"].output_ids, ref), f"r{i}"
     # O(1) residency: zero KV blocks ever claimed, every state slot released
-    assert eng._pages._ref == {}
-    assert eng._rstate._live == {}
+    assert eng.backend.available() == 0 and "k" not in eng.backend.device
+    assert eng.backend._live == {}
     plan = eng.memory_plan()
-    assert plan["kv_pool_bytes"] == 0 and plan["state_bytes"] > 0
+    assert plan["kv_pool_bytes"] == 0
+    # the plan is the allocation: what it prices is what the device holds
+    assert plan["state_bytes"] == _device_nbytes(eng) > 0
     curve = plan["per_seq_cache_bytes"]
     assert curve[4096] == curve[16384] == curve[65536]   # FLAT
 
@@ -267,9 +278,12 @@ def test_engine_hybrid_matches_generate(hybrid_model):
     for i, ref in enumerate(_gen_ref(hybrid_model, prompts)):
         assert np.array_equal(outs[f"r{i}"].output_ids, ref), f"r{i}"
     # both ledgers clean: KV blocks reclaimed AND state slots released
-    assert eng._pages._ref == {} and eng._rstate._live == {}
-    assert len(eng._free) == eng.num_blocks - 1          # block 0 is trash
-    curve = eng.memory_plan()["per_seq_cache_bytes"]
+    assert eng.backend.pages._ref == {} and eng.backend.state._live == {}
+    assert len(eng.backend.pages._free) == eng.num_blocks - 1  # 0 is trash
+    plan = eng.memory_plan()
+    assert plan["state_bytes"] > 0 and plan["kv_pool_bytes"] > 0
+    assert plan["state_bytes"] + plan["kv_pool_bytes"] == _device_nbytes(eng)
+    curve = plan["per_seq_cache_bytes"]
     assert curve[16384] > curve[4096]                    # attention share grows
 
 
@@ -307,17 +321,17 @@ def test_router_degrades_to_headroom_load_for_recurrent(ssd_model):
     assert sorted(o.request_id for o in outs) == sorted(rids)
     assert {t.replica for t in r._tracked.values()} <= {0, 1}
     # recurrent replica's ledger is clean after the storm
-    assert ssd_eng._rstate._live == {}
+    assert ssd_eng.backend._live == {}
 
 
-# ---------------------------------------------------------------- loadgen --
+# ------------------------------------------------------- arrival-paced --
 
 def test_loadgen_trace_through_recurrent_replica(ssd_model):
-    """Satellite: the load generator's arrival-paced trace drives a pure
-    RecurrentState replica end to end — every request completes, decode
-    rounds are observed, and the slot ledger is clean afterwards (no block
-    chain was ever needed)."""
-    from paddle_tpu.serving.loadgen import make_trace, run_trace
+    """Satellite: an arrival-paced trace drives a pure RecurrentState
+    replica through the ``Router`` end to end — every request completes,
+    decode rounds are observed, and the slot ledger is clean afterwards (no
+    block chain was ever needed)."""
+    import time
 
     cfg = ssd_model.config
     r = Router()
@@ -325,18 +339,36 @@ def test_loadgen_trace_through_recurrent_replica(ssd_model):
                          block_size=16, prefill_buckets=(32, 64)))
     eng = r._replicas[0]
     assert eng.backend.kind == "recurrent"
-    # long_prompt shape, scaled to the tiny buckets: prompt + new tokens
-    # must fit the 2*max_bucket context capacity per slot
-    trace = make_trace("long_prompt", cfg.vocab_size, seed=0, n_requests=6,
-                       rate_rps=200.0, long_len=48, short_len=8,
-                       max_new_tokens=4)
-    m = run_trace(r, trace)
-    assert m["completed"] == m["submitted"] == 6
-    assert m["goodput_tps"] > 0 and len(m["outputs"]) == 6
-    assert m["decode_gap_p99_ms"] >= m["decode_gap_p50_ms"] >= 0.0
+    # six Poisson arrivals at 200 req/s, decode-heavy short prompts first so
+    # decode is in flight when the long ones land; prompt + new tokens must
+    # fit the 2*max_bucket context capacity per slot
+    rng = np.random.default_rng(0)
+    trace, t = [], 0.0
+    for i in range(6):
+        n, new = (8, 16) if i % 2 == 0 else (48, 4)
+        trace.append((t, rng.integers(1, cfg.vocab_size,
+                                      size=n).astype(np.int32), new))
+        t += float(rng.exponential(1.0 / 200.0))
+    submitted, outputs = [], {}
+    t0 = time.perf_counter()
+    while trace or r.has_work():
+        now = time.perf_counter() - t0
+        while trace and trace[0][0] <= now:
+            _, ids, new = trace.pop(0)
+            submitted.append(r.submit(GenRequest(
+                prompt_ids=ids, max_new_tokens=new, temperature=0.0)))
+        if not r.has_work():
+            time.sleep(min(trace[0][0] - now, 0.01))
+            continue
+        for o in r.step():
+            outputs[o.request_id] = list(o.output_ids)
+    assert sorted(outputs) == sorted(submitted) and len(submitted) == 6
+    assert sorted(map(len, outputs.values())) == [4, 4, 4, 16, 16, 16]
+    assert eng.stats["decode_calls"] > 0
     # prefix caching is structurally unsupported: nothing was ever looked up
-    assert m["hit_rate"] == 0.0
-    assert eng._rstate._live == {} and eng._pages._ref == {}
+    assert eng.stats["prefix_lookup_blocks"] == 0
+    assert eng.stats["prefix_hit_blocks"] == 0
+    assert eng.backend._live == {} and eng.backend.available() == 0
 
 
 def test_loadgen_recurrent_headroom_beats_paged_at_long_context(ssd_model):
@@ -378,3 +410,27 @@ def test_loadgen_recurrent_headroom_beats_paged_at_long_context(ssd_model):
                   prefill_buckets=(32, 64), hbm_budget_bytes=16 << 20)
     assert wide.backend.free_slots() == 64
     assert wide.memory_plan()["total_bytes"] <= 16 << 20
+
+
+def test_8b_footprint_flat_against_linear():
+    """The headline the family exists for, priced from the configs alone
+    (no 8B parameter is made): one sequence of the SSD-8B stack holds the
+    same 403.7 MB at 4k and at 64k tokens, where Llama-3-8B's KV grows to
+    21 times that."""
+    from paddle_tpu.models import ssd_8b_config
+    from paddle_tpu.models.llama import llama3_8b_config
+    from paddle_tpu.models.ssd import ssd_cache_spec
+    from paddle_tpu.serving import make_backend
+
+    spec = ssd_cache_spec(ssd_8b_config())
+    assert spec["state_bytes_per_slot"] == 403_701_760
+    ssd = make_backend(spec, num_blocks=1, block_size=128, max_slots=1)
+    assert ssd.seq_bytes(4096) == ssd.seq_bytes(65536) == 403_701_760
+    lcfg = llama3_8b_config()
+    llama = make_backend(
+        {"kinds": ("attention",) * lcfg.num_hidden_layers,
+         "state_bytes_per_slot": 0, "kv_layers": lcfg.num_hidden_layers,
+         "kv_bytes_per_token_layer": 2 * lcfg.kv_heads * lcfg.head_dim * 2},
+        num_blocks=1, block_size=128, max_slots=1)
+    assert llama.seq_bytes(65536) == 16 * llama.seq_bytes(4096)
+    assert round(llama.seq_bytes(65536) / ssd.seq_bytes(65536), 2) == 21.28

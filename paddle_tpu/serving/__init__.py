@@ -20,7 +20,9 @@ TPU-native design:
   a ``lax.scan`` (k from a power-of-two ladder), so per-call costs amortize
   over ``k`` tokens.  A sequence whose budget ends mid-chunk simply stops
   being collected; its tail sub-steps decode into its own about-to-be-freed
-  blocks (or the trash block) and are discarded.
+  blocks (or the trash block) and are discarded.  ``decode_chunk`` caps
+  ``k``; the scheduler picks it each round from its own state
+  (``_pick_chunk``, ``K_SHORT``).
 - **Sync only when token VALUES are needed.** A host readback waits for
   everything dispatched before it, an async dispatch does not.  So the
   scheduler never reads tokens back per step — the ``last``-token
@@ -88,6 +90,17 @@ __all__ = ["Engine", "GenRequest", "RequestOutput", "prefix_block_hashes",
            "CacheBackend", "PagedKV", "RecurrentState", "make_backend"]
 
 NEG_INF = -1e30
+
+# The longest decode chunk of a streaming round (``Engine.step()``) while an
+# arrival could be admitted at once.  A request that arrives mid-``step()``
+# waits for the rest of it and holds its first token at the end of the next,
+# so the chunk sets time to first token; the price is the host's round trip
+# between chunks (7 ms against 10.5-12 ms a decode step on a v5e at
+# Mistral-7B widths), paid more often.  Ten round trips of device time a
+# chunk would give 8.  In paired chip runs (PERF.md section 6, PR 33) 8 cut
+# ``ttft_p95_ms`` from 857 to 255-283 ms but raised ``tpot_p95_ms`` by 10-12%;
+# 16 gives 431-489 ms for 2-4%: the shorter one that keeps TPOT within 5%.
+K_SHORT = 16
 
 
 def prefix_block_hashes(ids, block_size: int) -> List[bytes]:
@@ -175,6 +188,17 @@ class Engine:
     ``step()`` syncs every round (streaming semantics);
     ``run_to_completion()`` defers syncs while no active request uses eos,
     dispatching the whole schedule asynchronously.
+
+    ``decode_chunk`` is a cap on the decode steps of one compiled call, not
+    their number.  A round's chunk is the largest power of two within that
+    cap and the longest remaining budget, and within ``K_SHORT`` too in a
+    ``step()`` round during which an arrival could be admitted at once (a
+    slot is free and the pool holds nobody back): the caller polls
+    ``step()`` between tokens, and a request it adds meanwhile waits for the
+    running round.  With every slot taken, with the queue held back by the
+    pool, and in ``run_to_completion()``, only the cap and the budget bind.
+    The counter ``serve.decode_chunks{k, why}`` says which rule chose each
+    chunk.
     """
 
     def __init__(self, model, max_batch: int = 8, num_blocks: int = 256,
@@ -447,9 +471,12 @@ class Engine:
     def step(self) -> List[RequestOutput]:
         """Admit + prefill new requests, run one decode chunk, sync, and
         return any requests that finished (streaming semantics: every step
-        materializes its tokens)."""
+        materializes its tokens).  The chunk is at most ``K_SHORT`` decode
+        steps while an arrival could be admitted at once, so that a request
+        added between two calls waits for a short round; otherwise up to
+        ``decode_chunk`` (``_pick_chunk``)."""
         with obs.span("serve.step", cat="serve"):
-            self._round()
+            self._round(streaming=True)
             self._sync_pending()
             reg = obs.registry()
             lbl = self._obs_labels()
@@ -468,9 +495,11 @@ class Engine:
     def run_to_completion(self) -> List[RequestOutput]:
         """Drain the queue.  While no ACTIVE request uses eos the schedule is
         host-deterministic, so rounds are dispatched back-to-back with no
-        readback and one final sync materializes everything."""
+        readback and one final sync materializes everything.  Nobody reads
+        a token before the end and nothing arrives meanwhile, so chunks run
+        as long as ``decode_chunk`` and the budgets allow."""
         while self.has_work():
-            self._round()
+            self._round(streaming=False)
             if any(s.req is not None and s.req.eos_token_id is not None
                    for s in self._slots):
                 self._sync_pending()
@@ -479,7 +508,7 @@ class Engine:
 
     # -- scheduling ---------------------------------------------------------
 
-    def _round(self):
+    def _round(self, streaming: bool):
         self._admit()
         self._advance_prefills()
         # slots mid-chunked-prefill don't decode this round; decode rounds
@@ -488,7 +517,9 @@ class Engine:
                   if s.req is not None and s.prefill_left is None]
         if not active:
             return
-        k = self._pick_chunk(active)
+        k, why = self._pick_chunk(active, streaming)
+        obs.registry().counter("serve.decode_chunks", k=k, why=why,
+                               **self._obs_labels()).inc()
         self._ensure_decode_blocks(k)
         self._dispatch_chunk(k)
 
@@ -532,14 +563,36 @@ class Engine:
             return
         self.backend.register(slot.hashes, slot.blocks)
 
-    def _pick_chunk(self, active) -> int:
-        """Largest power-of-two chunk within the LONGEST remaining budget.
-        Short-remaining sequences stop being collected mid-chunk; their tail
-        sub-steps are wasted compute, bounded by the chunk length — the
-        trade against the ~per-call overhead the chunk amortizes."""
+    def _pick_chunk(self, active, streaming: bool) -> Tuple[int, str]:
+        """The round's chunk length and the rule that chose it (the ``why``
+        of ``serve.decode_chunks``).  Always the largest power of two within
+        the LONGEST remaining budget and ``decode_chunk``: short-remaining
+        sequences stop being collected mid-chunk, and their tail sub-steps
+        are wasted compute, bounded by the chunk length — the trade against
+        the per-call overhead the chunk amortizes.  Called after this
+        round's admission, so the scheduler's state says what an arrival
+        would meet:
+
+        - ``admissible``: a streaming round with a free slot and nobody
+          waiting.  A request added before the next ``step()`` would be
+          admitted at once and waits only for this chunk, so the chunk is
+          also held to ``K_SHORT``.
+        - ``blocked``: a streaming round with every slot taken, or with the
+          queue's head held back by the pool.  A short chunk buys no first
+          token there.
+        - ``batch``: ``run_to_completion()``.
+        """
+        cap = self.decode_chunk
+        if not streaming:
+            why = "batch"
+        elif self._waiting or all(s.req is not None for s in self._slots):
+            why = "blocked"
+        else:
+            why = "admissible"
+            cap = min(cap, K_SHORT)
         rem = max(s.req.max_new_tokens - s.out_count for s in active)
-        k = min(max(rem, 1), self.decode_chunk)
-        return 1 << (k.bit_length() - 1)
+        k = min(max(rem, 1), cap)
+        return 1 << (k.bit_length() - 1), why
 
     def _bucket(self, n: int) -> int:
         for b in self.prefill_buckets:

@@ -11,9 +11,10 @@ import numpy as np
 import pytest
 
 import paddle_tpu as paddle
+from paddle_tpu import obs
 from paddle_tpu.kernels import decode_attention as da
 from paddle_tpu.models import LlamaForCausalLM, llama_tiny_config
-from paddle_tpu.serving import Engine, GenRequest
+from paddle_tpu.serving import K_SHORT, Engine, GenRequest
 
 
 @pytest.fixture(scope="module")
@@ -430,7 +431,7 @@ def test_eviction_requeue_preserves_sampling_knobs(model):
     p = _prompts(model.config, (20,), seed=17)[0]
     eng.add_request(GenRequest(prompt_ids=p, max_new_tokens=8,
                                temperature=0.9, top_k=40, top_p=0.85))
-    eng._round()                     # admit + prefill + one chunk
+    eng._round(streaming=False)     # admit + prefill + one chunk
     slot = next(s for s in eng._slots if s.req is not None)
     eng._evict(slot)
     requeued = eng._waiting[0]
@@ -592,7 +593,7 @@ def test_prefix_refcount_shared_block_survives_owner_eviction(model):
     reqs = [GenRequest(prompt_ids=p, max_new_tokens=8) for p in prompts]
     for r in reqs:
         eng.add_request(r)
-    eng._round()                       # both admitted, prefix shared
+    eng._round(streaming=False)     # both admitted, prefix shared
     slots = [s for s in eng._slots if s.req is not None]
     assert len(slots) == 2
     shared = [b for b in slots[0].blocks if b in slots[1].blocks]
@@ -647,7 +648,7 @@ def test_evict_vs_sync_release_keeps_refcounts_consistent(model):
                  prefill_buckets=(128, 256, 512))
     for p in prompts:
         eng.add_request(GenRequest(prompt_ids=p, max_new_tokens=8))
-    eng._round()
+    eng._round(streaming=False)
     slot_a, slot_b = [s for s in eng._slots if s.req is not None]
     shared = [b for b in slot_a.blocks if b in slot_b.blocks]
     assert shared and all(eng._ref[b] == 2 for b in shared)
@@ -743,4 +744,107 @@ def test_chunked_prefill_interleaves_with_decode(model):
     assert outs["s"] == ref_s
     assert outs["l"] == ref_l
     assert eng.stats["chunk_prefills"] >= 2
+    _assert_pool_reclaimed(eng)
+
+
+# ---------------------------------------------------------------------------
+# the length of a decode chunk (serve.decode_chunks{k, why})
+# ---------------------------------------------------------------------------
+
+def _chunks_counted(eng):
+    """``{(k, why): count}`` of this engine's ``serve.decode_chunks``.  The
+    registry is the process's, so the engine gets a label of its own (the
+    router's way) and no other engine's chunks are read."""
+    return {(int(e["labels"]["k"]), e["labels"]["why"]): int(e["value"])
+            for name, e in obs.registry().snapshot().items()
+            if name.startswith("serve.decode_chunks")
+            and e["labels"].get("replica") == eng.obs_replica}
+
+
+def _todays_length(budget, cap=32):
+    """The rule before the short chunk: the largest power of two within
+    the longest remaining budget and ``decode_chunk``."""
+    return 1 << (min(budget, cap).bit_length() - 1)
+
+
+# name -> (max_batch, num_blocks, [(prompt length, max_new_tokens)], the
+# entry point, {(k, why): chunks} expected, requests left waiting and slots
+# left free by it); every engine has the default decode_chunk of 32, and a
+# budget reads one less after the prefill's token
+_CHUNK_POLICY = {
+    "free_slot": (2, 16, [(40, 41)], "step",
+                  {(K_SHORT, "admissible"): 1}, 0, 1),
+    "every_slot_taken": (2, 16, [(40, 41), (50, 41)], "step",
+                         {(_todays_length(40), "blocked"): 1}, 0, 0),
+    # two usable blocks: the first request holds one and grows into the
+    # other (its budget ends with the chunk, so its slot is free again),
+    # the second wants two for its 256-token bucket and waits
+    "pool_holds_the_queue_back": (3, 3, [(100, 33), (200, 8)], "step",
+                                  {(_todays_length(32), "blocked"): 1}, 1, 3),
+    "budget_under_k_short": (2, 16, [(40, 6)], "step",
+                             {(_todays_length(5), "admissible"): 1}, 0, 1),
+    "run_to_completion": (2, 16, [(40, 41)], "run_to_completion",
+                          {(_todays_length(40), "batch"): 1,
+                           (_todays_length(8), "batch"): 1}, 0, 2),
+}
+
+
+@pytest.mark.parametrize("case", _CHUNK_POLICY.values(),
+                         ids=_CHUNK_POLICY.keys())
+def test_chunk_length_follows_the_schedulers_state(model, case):
+    """A streaming round is held to ``K_SHORT`` decode steps only while an
+    arrival could be admitted at once; every other round keeps the length
+    the longest budget and ``decode_chunk`` give."""
+    max_batch, num_blocks, reqs, entry, expected, waiting, free = case
+    assert K_SHORT < 32                   # else no case tells the rules apart
+    eng = Engine(model, max_batch=max_batch, num_blocks=num_blocks,
+                 block_size=128, prefill_buckets=(128, 256))
+    eng.obs_replica = f"chunk-policy-{id(eng)}"
+    prompts = _prompts(model.config, [p for p, _ in reqs], seed=41)
+    for p, (_, m) in zip(prompts, reqs):
+        eng.add_request(GenRequest(prompt_ids=p, max_new_tokens=m))
+    getattr(eng, entry)()
+    assert _chunks_counted(eng) == expected
+    assert eng.stats["decode_calls"] == sum(expected.values())
+    assert eng.stats["decode_steps"] == sum(
+        k * n for (k, _), n in expected.items())
+    assert len(eng._waiting) == waiting
+    assert sum(s.req is None for s in eng._slots) == free
+
+
+def test_short_chunks_keep_greedy_outputs_token_for_token(model):
+    """Requests land between ``step()`` calls while others decode; the
+    chunk lengths the scheduler picks (short with a slot free, long with
+    none) give the tokens of ``decode_chunk=1``."""
+    cfg = model.config
+    prompts = _prompts(cfg, (17, 33, 64, 100, 40, 21), seed=43)
+    budgets = (30, 70, 12, 37, 9, 20)
+    due = (0, 0, 1, 2, 4, 5)               # step() calls before it arrives
+
+    def run(**engine_args):
+        eng = Engine(model, max_batch=3, num_blocks=32, block_size=128,
+                     prefill_buckets=(128,), **engine_args)
+        eng.obs_replica = f"short-chunks-{id(eng)}"
+        outs, n_steps, sent = {}, 0, 0
+        while sent < len(prompts) or eng.has_work():
+            while sent < len(prompts) and due[sent] <= n_steps:
+                eng.add_request(GenRequest(
+                    prompt_ids=prompts[sent], max_new_tokens=budgets[sent],
+                    request_id=f"r{sent}"))
+                sent += 1
+            for o in eng.step():
+                outs[o.request_id] = o.output_ids
+            n_steps += 1
+            assert n_steps < 400, "no progress"
+        return outs, eng
+
+    outs, eng = run()
+    picked = _chunks_counted(eng)
+    stepwise, _ = run(decode_chunk=1)
+    assert outs == stepwise
+    assert [len(outs[f"r{i}"]) for i in range(6)] == list(budgets)
+    # both rules chose chunks, and only the blocked one went past K_SHORT
+    assert {why for _, why in picked} == {"admissible", "blocked"}
+    assert max(k for k, why in picked if why == "admissible") == K_SHORT
+    assert max(k for k, why in picked if why == "blocked") > K_SHORT
     _assert_pool_reclaimed(eng)

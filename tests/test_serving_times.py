@@ -4,12 +4,13 @@ ttft_s``), the wait before the first prefill (``serve.queue_wait_ms``), the
 tokens a live request holds (``Engine.token_counts()``), ``warmup()``'s own
 gauges."""
 import time
+import types
 
 import numpy as np
 import pytest
 
 import paddle_tpu as paddle
-from paddle_tpu import obs
+from paddle_tpu import obs, serving
 from paddle_tpu.models import LlamaForCausalLM, llama_tiny_config
 from paddle_tpu.serving import Engine, GenRequest
 
@@ -101,7 +102,7 @@ def test_ttft_is_not_observed_at_dispatch(model):
     eng = _engine(model)
     (req,) = _requests(model.config, (40,), (6,))
     eng.add_request(req)
-    eng._round()                         # admit, prefill, one chunk: no sync
+    eng._round(streaming=False)     # admit, prefill, one chunk: no sync
     assert _hist("serve.queue_wait_ms")["count"] == 1
     assert _hist("serve.ttft_ms")["count"] == 0 and req._first_t == 0.0
     eng._sync_pending()
@@ -156,3 +157,30 @@ def test_decode_gaps_are_bounded(model):
     eng._decode_gaps.extend(range(2 * eng._decode_gaps.maxlen))
     assert len(eng._decode_gaps) == eng._decode_gaps.maxlen
     assert sorted(eng._decode_gaps)[0] == eng._decode_gaps.maxlen
+
+
+def test_ttft_of_a_mid_stream_arrival_is_a_short_chunk_and_its_prefill(
+        model, monkeypatch):
+    """The engine's clock counts what it has dispatched (one a decode step,
+    one a prefill), so ``ttft_s`` reads in steps: a request that arrives
+    while another streams and a slot is free waits for its prefill and one
+    chunk of ``K_SHORT``, not of ``decode_chunk``."""
+    eng = _engine(model, max_batch=3, decode_chunk=32)
+    monkeypatch.setattr(serving, "time", types.SimpleNamespace(
+        perf_counter=lambda: 1.0 + eng.stats["decode_steps"]
+        + eng.stats["prefills"], time=time.time))
+    first, late = _requests(model.config, (40, 60), (120, 40), seed=7)
+    eng.add_request(first)
+    eng.step()
+    eng.step()                           # the first request is mid-stream
+    assert eng.token_counts() == {first.request_id: 1 + 2 * serving.K_SHORT}
+    rid = eng.add_request(late)
+    eng.step()                           # its prefill and one short chunk
+    assert eng.token_counts()[rid] == 1 + serving.K_SHORT
+    outs = {}
+    while eng.has_work():
+        outs.update((o.request_id, o) for o in eng.step())
+    assert late._dispatch_t - late._queued_t == 0       # admitted at once
+    assert outs[rid].ttft_s == 1 + serving.K_SHORT
+    assert _hist("serve.ttft_ms")["sum"] == pytest.approx(
+        1e3 * sum(o.ttft_s for o in outs.values()))

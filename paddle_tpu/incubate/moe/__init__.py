@@ -243,3 +243,8 @@ class MoELayer(Layer):
     def forward(self, x):
         out, _ = self.forward_with_aux(x)
         return out
+
+from .dropless import (DroplessMoE, dropless_experts,  # noqa: E402
+                       sigmoid_topk_route)
+
+__all__ += ["DroplessMoE", "dropless_experts", "sigmoid_topk_route"]

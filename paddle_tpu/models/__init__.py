@@ -39,3 +39,8 @@ from .ernie import (  # noqa: F401
     ErnieModel,
     ernie_tiny_config,
 )
+from .mla_moe import (  # noqa: F401
+    MlaMoeConfig,
+    MlaMoeForCausalLM,
+    mla_moe_tiny_config,
+)

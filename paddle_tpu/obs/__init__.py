@@ -60,6 +60,18 @@ name                         producer / meaning
                              prefill (the wait for a slot, blocks, the step)
 ``serve.warmup_s``           gauge {replica}: seconds of ``Engine.warmup()``
 ``serve.warmup_programs``    gauge {replica}: engine programs ``warmup()`` ran
+``moe.steps`` etc.           counters {replica}: what the expert layers routed, summed
+                             over the layers: decode steps counted, ``moe.rows``,
+                             ``moe.experts_touched``, ``moe.max_expert_rows``; the
+                             same of prefill calls (``moe.prefill_*``) and
+                             ``mla.prefill_kilo_pairs``.  Counted on the device,
+                             read inside ``serve.readback`` (``LatentKV``)
+``cache.counters``           span: that read; args carry the running totals
+``cache.latent_bytes_per_token``  gauge {replica}: one latent row, a layer
+``cache.latent_blocks_live``  gauge {replica}: blocks live slots hold
+``moe.route``, ``moe.experts``  spans where the model runs eagerly, named scopes
+``mla.prefill_attn``,        inside jitted programs: the router, the grouped
+``mla.decode_attn``          expert products, expanded and absorbed attention
 ``serve.kill``               flight: injected replica kill (victim replica)
 ``serve.reroute``            flight: a harvested request re-placed after a kill
 ``store.leader-elected``     flight: replica won an election (term)

@@ -82,12 +82,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .cache_backend import (CacheBackend, PagedKV, RecurrentState,
+from .cache_backend import (CacheBackend, LatentKV, PagedKV, RecurrentState,
                             make_backend)
 from .. import obs
 
 __all__ = ["Engine", "GenRequest", "RequestOutput", "prefix_block_hashes",
-           "CacheBackend", "PagedKV", "RecurrentState", "make_backend"]
+           "CacheBackend", "PagedKV", "LatentKV", "RecurrentState",
+           "make_backend"]
 
 NEG_INF = -1e30
 
@@ -932,13 +933,14 @@ class Engine:
 
         def chunk(params, buffers, device, last, sidx, ids, tbl_row, ctx,
                   n_valid, key, temp, top_k, top_p, firstbuf, fidx0):
-            cache = backend.step_cache(device, tbl_row[None, :], ctx[None])
+            cache = {**backend.step_cache(device, tbl_row[None, :],
+                                          ctx[None]),
+                     "n_valid": n_valid[None]}
             out = functional_call(model, params, buffers, ids[None, :],
                                   cache=cache, rng_key=key)
             logits, new_cache = out[0], out[-1]
             if final:
-                lg = jnp.take_along_axis(
-                    logits, (n_valid - 1)[None, None, None], axis=1)[:, 0]
+                lg = _logits_at(logits, (n_valid - 1)[None])
                 nxt = _sample_batch(lg, jax.random.fold_in(key, 1),
                                     temp[None], top_k[None], top_p[None])
                 last = last.at[sidx].set(nxt[0])
@@ -1009,8 +1011,7 @@ class Engine:
             device = backend.write_prefill(device, new_cache, sidx, blocks)
             # causality makes row j's logits at P[j]-1 independent of the
             # padded tail, so the batched result matches the n=1 program
-            lg = jnp.take_along_axis(
-                logits, (P - 1)[:, None, None], axis=1)[:, 0]     # [n, V]
+            lg = _logits_at(logits, P - 1)                        # [n, V]
             nxt = _sample_batch(lg, jax.random.fold_in(key, 1),
                                 temps, top_ks, top_ps)            # [n]
             last = last.at[sidx].set(nxt)
@@ -1254,6 +1255,7 @@ class Engine:
                             for b in (*self._full_tok_bufs, self._tok_buf)]
                 first_segs = [np.asarray(b) for b in
                               (*self._full_first_bufs, self._first_buf)]
+                self.backend.read_counters(**self._obs_labels())
             t_read = time.perf_counter()
         with obs.span("serve.absorb", cat="serve") as sp:
             n_tok, n_ready = 0, len(self._ready)
@@ -1332,6 +1334,16 @@ class Engine:
     def _drain_ready(self) -> List[RequestOutput]:
         out, self._ready = self._ready, []
         return out
+
+
+def _logits_at(logits, pos):
+    """Row ``pos[j]`` of sequence j's logits ``[n, S, V]``.  A model that
+    was told the true lengths (``n_valid`` in its cache) may hand back that
+    one row, ``[n, 1, V]``: a wide head over every position of a long bucket
+    is gigabytes of logits of which one row a prompt is read."""
+    if logits.shape[1] == 1:
+        return logits[:, 0]
+    return jnp.take_along_axis(logits, pos[:, None, None], axis=1)[:, 0]
 
 
 def _sample_batch(logits, key, temps, top_ks, top_ps):

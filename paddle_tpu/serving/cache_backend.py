@@ -16,6 +16,11 @@ cache kind by name:
   one state dict per SSD layer, ``max_slots`` wide.
 - :class:`HybridCache` — both at once for a stack that mixes attention and
   SSD layers: every verb is answered by composing the two parts.
+- :class:`LatentKV` — paged like :class:`PagedKV` (the same blocks,
+  refcounts and prefix hashing) for latent attention: a token's cache is
+  ONE row a layer (the compressed latent and the shared rotated key), no
+  heads and no separate k and v, in one ``latent`` pool a layer; it also
+  carries the model's device-side ``counters`` to the host.
 
 **Host side.** The verbs ``alloc`` / ``append`` / ``gather`` / ``release`` /
 ``acquire_slot`` / ``release_slot`` / ``migrate`` / ``plan_bytes`` are what
@@ -32,7 +37,8 @@ model's forward takes for a decode sub-step or a prefill chunk,
 ``take_device`` reads the new arrays back out of the forward's
 ``new_cache``, and ``write_prefill`` moves a dense prefill's ``new_cache``
 into the admitted slots.  ``prefill_ladder`` says how many same-bucket
-prompts one prefill call may take.  The engine donates ``device`` through
+prompts one prefill call may take, and ``read_counters`` (called inside the
+engine's token readback) publishes what the device state counts.  The engine donates ``device`` through
 every program and stores what comes back.
 
 Backends are constructed from a model's ``cache_spec()`` dict (every model
@@ -49,8 +55,8 @@ from __future__ import annotations
 import collections
 from typing import Callable, Dict, List, Optional, Tuple
 
-__all__ = ["CacheBackend", "PagedKV", "RecurrentState", "HybridCache",
-           "KINDS", "make_backend"]
+__all__ = ["CacheBackend", "PagedKV", "LatentKV", "RecurrentState",
+           "HybridCache", "KINDS", "make_backend"]
 
 
 class CacheBackend:
@@ -143,6 +149,12 @@ class CacheBackend:
         """Move a dense prefill's ``new_cache`` (n rows) into ``device``:
         row j belongs to slot ``slots[j]`` and owns ``blocks[j]``."""
         return device
+
+    def read_counters(self, **labels) -> None:
+        """Publish to ``obs`` what the device state counts.  The engine
+        calls it inside its token readback, when everything dispatched has
+        run: a backend that keeps counters on the device reads them here
+        and waits for nothing."""
 
     # -- accounting ---------------------------------------------------------
 
@@ -315,6 +327,78 @@ class PagedKV(CacheBackend):
                            "bytes_each": self.block_bytes}]}
 
 
+class LatentKV(PagedKV):
+    """Paged latent cache: :class:`PagedKV`'s block bookkeeping (blocks,
+    refcounts, prefix-cache LRU, byte accounting) over pools that hold one
+    row a token a layer, ``rank + rope`` values wide, which every head
+    reads (``kernels/mla_attention.py`` has the layout and the writes).
+    ``bytes_per_token`` is that row's bytes summed over the layers.
+
+    The model counts on the device (``counters``, int32 sums: what its
+    expert layers routed) and the cache carries the vector through every
+    program; ``read_counters`` turns what was added since the last
+    readback into increments of the ``obs`` counters named in the model's
+    ``cache_spec()["counters"]``."""
+
+    kind = "latent_kv"
+    state_keys = ("latent", "counters")
+
+    def __init__(self, num_blocks: int, block_size: int,
+                 bytes_per_token: int, rank: int, counters=(),
+                 prefix_cache: bool = True):
+        super().__init__(num_blocks, block_size, bytes_per_token,
+                         prefix_cache=prefix_cache)
+        self.rank = rank
+        self.counters = tuple(counters)
+        self._published = [0] * len(self.counters)
+
+    def init_device(self, model) -> Dict:
+        import jax.numpy as jnp
+
+        return {"latent": model.init_latent_pools(self.num_blocks,
+                                                  self.block_size),
+                "counters": jnp.zeros((len(self.counters),), jnp.int32)}
+
+    def prefill_cache(self, cache, n_valid):
+        # the model skips the padded tail's experts and computes the logits
+        # of the last valid position only
+        return {**cache, "n_valid": n_valid}
+
+    def write_prefill(self, device, new_cache, slots, blocks):
+        from ..kernels.mla_attention import write_latent_prefill
+
+        Pb = blocks.shape[1] * self.block_size
+        pools = list(device["latent"])
+        for li, rows in enumerate(new_cache["latent"]):
+            for j in range(blocks.shape[0]):
+                pools[li] = write_latent_prefill(pools[li], blocks[j],
+                                                 rows[j, :Pb], self.rank)
+        return {"latent": tuple(pools),
+                "counters": device["counters"] + new_cache["counters"]}
+
+    def read_counters(self, **labels) -> None:
+        import numpy as np
+
+        from .. import obs
+
+        reg = obs.registry()
+        # the span carries the running totals: a trace's reader takes the
+        # difference of two of them for what a stretch of the run counted
+        with obs.span("cache.counters", cat="serve") as sp:
+            now = np.asarray(self.device["counters"]).astype(np.uint32)
+            sp.set(**{n: int(v) for n, v in zip(self.counters, now)})
+        # the int32 sums wrap; what was added since the last read does not
+        for name, d in zip(self.counters,
+                           now - np.asarray(self._published, np.uint32)):
+            if d:
+                reg.counter(name, **labels).inc(int(d))
+        self._published = now
+        per_layer = max(1, len(self.device["latent"]))
+        reg.gauge("cache.latent_bytes_per_token", **labels).set(
+            self.bytes_per_token // per_layer)
+        reg.gauge("cache.latent_blocks_live", **labels).set(len(self._ref))
+
+
 class RecurrentState(CacheBackend):
     """Constant-size per-slot decode state (the SSD layers' residency).
 
@@ -451,10 +535,18 @@ def _recurrent(spec, num_blocks, block_size, max_slots, prefix_cache):
     return RecurrentState(max_slots, spec["state_bytes_per_slot"])
 
 
+def _latent(spec, num_blocks, block_size, max_slots, prefix_cache):
+    return LatentKV(num_blocks, block_size,
+                    spec["kv_layers"] * spec["kv_bytes_per_token_layer"],
+                    rank=spec["latent_rank"],
+                    counters=spec.get("counters", ()),
+                    prefix_cache=prefix_cache)
+
+
 # layer kind (an entry of ``cache_spec()["kinds"]``) -> the backend that
 # caches layers of that kind
 KINDS: Dict[str, Callable[..., CacheBackend]] = {
-    "attention": _paged, "ssd": _recurrent}
+    "attention": _paged, "ssd": _recurrent, "latent": _latent}
 
 
 def make_backend(spec: Dict, num_blocks: int, block_size: int,

@@ -191,6 +191,86 @@ def test_decode_chunk_copies_no_pool(one_chip, kernel_branch, k):
     assert compiled.memory_analysis().temp_size_in_bytes < pool.nbytes
 
 
+# the latent-attention, sparse-expert cell (Kimi-VL-A3B widths): 16 heads,
+# latent 512 + 64 rotated, 64 experts of 1408 over hidden 2048; 32 slots,
+# 1,536 blocks of 128 tokens = pool rows of two tokens, 1,152 wide
+_LAT = (((32, 16, 512), BF16), ((32, 16, 64), BF16),
+        ((1536, 64, 1152), BF16), ((32, 64), I32), ((32,), I32))
+
+
+def test_latent_decode_and_token_write(one_chip, kernel_branch):
+    from paddle_tpu.kernels import mla_attention
+
+    text = _compile(one_chip, lambda ql, qr, p, t, n:
+                    mla_attention.latent_decode_attention(ql, qr, p, t, n,
+                                                          0.07), *_LAT)
+    assert "mla_paged_decode" in text.as_text()
+    # the token write updates the donated pool in place: no scratch
+    pool, tbl, lens = _LAT[2:]
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in (pool, tbl, lens, ((32, 576), BF16))]
+    write = jax.jit(lambda p, t, n, new: mla_attention.write_latent_token(
+        p, t, n, new, 512), donate_argnums=(0,)).lower(*args).compile()
+    assert write.memory_analysis().temp_size_in_bytes < 2 ** 20
+
+
+@pytest.mark.parametrize("b,s", [(1, 256), (4, 4096)])
+def test_latent_prefill_attention(one_chip, kernel_branch, b, s):
+    from paddle_tpu.kernels import mla_attention
+
+    text = _compile(
+        one_chip, lambda qn, qr, kn, kr, v: mla_attention.
+        mla_prefill_attention(qn, qr, kn, kr, v, 0.07),
+        ((b, s, 16, 128), BF16), ((b, s, 16, 64), BF16),
+        ((b, s, 16, 128), BF16), ((b, s, 64), BF16), ((b, s, 16, 128), BF16))
+    assert "mla_prefill_attn" in text.as_text()
+
+
+@pytest.mark.parametrize("rows", [192, 1536, 98304],
+                         ids=["decode", "prefill_256", "prefill_4x4096"])
+def test_grouped_matmul(one_chip, kernel_branch, rows):
+    """Both projections of an expert layer at a decode step's rows (32
+    slots x 6), one short prompt's and the widest prefill rung's."""
+    from paddle_tpu.kernels import grouped_matmul
+
+    for k, n in ((2048, 2816), (1408, 2048)):
+        text = _compile(one_chip, grouped_matmul.grouped_matmul,
+                        ((rows, k), BF16), ((64, k, n), BF16), ((64,), I32))
+        assert "moe_grouped_mm" in text.as_text()
+
+
+def test_latent_decode_chunk_copies_no_pool(one_chip, kernel_branch):
+    """The engine's decode program over the latent cache at the cell's
+    attention and expert widths (two layers, a small vocabulary): the
+    token write leaves the pools as ``mla_paged_decode`` reads them, so no
+    pool is copied, and both new kernels are in the program."""
+    import re
+
+    import paddle_tpu as paddle
+    from paddle_tpu.models.mla_moe import MlaMoeConfig, MlaMoeForCausalLM
+    from paddle_tpu.serving import Engine
+
+    paddle.seed(0)
+    model = MlaMoeForCausalLM(MlaMoeConfig(
+        vocab_size=1024, num_hidden_layers=2, n_routed_experts=8,
+        intermediate_size=512, max_position_embeddings=1024))
+    eng = Engine(model, max_batch=32, num_blocks=48, block_size=BS,
+                 prefill_buckets=(BS,))
+    pool = eng.backend.device["latent"][0]
+    assert pool.shape == (48, BS // 2, 1152) and pool.dtype == BF16
+    args = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        eng._decode_dummy_args())
+    compiled = eng._get_decode_fn(4).lower(*args).compile()
+    text = compiled.as_text()
+    for name in ("mla_paged_decode", "moe_grouped_mm", "moe.experts",
+                 "mla.decode_attn"):
+        assert name in text, name
+    dims = ",".join(map(str, pool.shape))
+    copies = re.findall(rf"= \w+\[{dims}\]\S* copy\(.*", text)
+    assert not copies, copies[:2]
+
+
 def test_ssd_scan(one_chip):
     # ssd_8b_config: 64 heads, state 128, head dim 64, chunk 128; seq 2048
     G, T, P, N, chunk = 64, 2048, 64, 128, 128

@@ -8,13 +8,22 @@ group to a fixed size.
 
 The Pallas kernel ``moe_grouped_mm`` tiles the rows (``tm``) and walks the
 (row tile, group) pairs that share rows, in row order (the megablocks
-schedule): a pair costs one ``[tm, K] x [K, tn]`` product and one DMA of the
-group's ``[K, tn]`` weight tile, masked to the group's rows of the tile.  A
-group with no rows is never visited, so its weights are never read: at the
-decode shape (a few rows an expert) the kernel streams the touched experts'
-weights once and is bound by those bytes; at the prefill shape (hundreds of
-rows an expert) every weight tile is reused by ``tm`` rows and the kernel is
-bound by the products.
+schedule): a pair costs one DMA of the group's ``[K, tn]`` weight tile and
+one ``[tb, K] x [K, tn]`` product for each ``tb``-row block of the tile that
+holds rows of the group (``tb`` 128, the MXU's granule; a block that holds
+none is skipped), masked to the group's rows.  So a pair pays for the rows
+the group owns, to the granule, not for the tile: one prompt of 1,024
+tokens is 6,144 rows but about 96 an expert, and a whole 512-row product a
+pair computed five masked rows for every useful one (PERF.md section 6,
+PR 36).  A group with no rows is never visited, so its weights are never
+read.  At the decode shape (a few rows an expert) and for one or two
+prompts (tens to hundreds of rows an expert) the kernel streams the touched
+experts' weights once and is bound by those bytes; at the widest prefill
+call (1,536 rows an expert) every weight tile is reused by its rows and the
+kernel is bound by the products.  ``K`` is never split, so a row's result
+does not depend on the tile or block it rode in.
+``moe.grouped_mm_programs{tm}`` (``paddle_tpu.obs``) counts the calls traced
+under each tile.
 
 The kernel is NOT in ``kernels.registry``: its block index maps read the
 schedule from scalar-prefetch data, which the static verifier cannot
@@ -30,10 +39,16 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-# rows of one tile by the number of rows: a decode step's few rows an expert
-# want a small tile (a pair's product is tm rows whatever the group holds),
-# a prefill's hundreds want the weight tile's DMA hidden behind the product
+from .. import obs
+
+# rows of one tile by the number of rows: fewer, larger tiles are fewer grid
+# steps and the next group's weight DMA runs behind more of this one's work;
+# a decode step's few rows keep the tile they always had.  What a pair
+# computes does not follow the tile: it is the _BLOCK-row blocks of it that
+# hold the group's rows (chip table by tile and block: PERF.md section 6,
+# PR 36)
 _TM_LADDER = ((4096, 512), (1024, 256), (256, 128), (0, 64))
+_BLOCK = 128
 _VMEM_LIMIT = 64 * 1024 * 1024
 
 
@@ -86,24 +101,30 @@ def _pallas_gmm(lhs, rhs, group_sizes, tm: int, interpret=False):
 
     m, k = lhs.shape
     g, _, n = rhs.shape
-    tn = _tile_n(n)
+    tn, tb = _tile_n(n), min(tm, _BLOCK)
     offsets, pair_group, pair_tile, n_pairs = _schedule(group_sizes, m, tm)
 
     def kernel(off_ref, grp_ref, tile_ref, np_ref, lhs_ref, rhs_ref, o_ref):
         p = pl.program_id(1)
+        grp = grp_ref[p]
+        lo, hi = off_ref[grp], off_ref[grp + 1]
+        for b in range(tm // tb):
+            first = tile_ref[p] * tm + b * tb
 
-        @pl.when(p < np_ref[0])
-        def _pair():
-            grp = grp_ref[p]
-            row = tile_ref[p] * tm + jax.lax.broadcasted_iota(
-                jnp.int32, (tm, tn), 0)
-            mine = (row >= off_ref[grp]) & (row < off_ref[grp + 1])
-            res = jax.lax.dot_general(
-                lhs_ref[...], rhs_ref[...].astype(lhs_ref.dtype),
-                (((1,), (0,)), ((), ())), precision=jax.lax.Precision.DEFAULT,
-                preferred_element_type=jnp.float32)
-            # the tile's other rows are another pair's: kept as they are
-            o_ref[...] = jnp.where(mine, res.astype(o_ref.dtype), o_ref[...])
+            # a live pair's blocks that hold rows of the group
+            @pl.when((p < np_ref[0]) & (first < hi) & (first + tb > lo))
+            def _block():
+                rows = slice(b * tb, (b + 1) * tb)
+                row = first + jax.lax.broadcasted_iota(jnp.int32, (tb, tn), 0)
+                res = jax.lax.dot_general(
+                    lhs_ref[rows, :], rhs_ref[...].astype(lhs_ref.dtype),
+                    (((1,), (0,)), ((), ())),
+                    precision=jax.lax.Precision.DEFAULT,
+                    preferred_element_type=jnp.float32)
+                # the block's other rows are another pair's: kept as they are
+                o_ref[rows, :] = jnp.where((row >= lo) & (row < hi),
+                                           res.astype(o_ref.dtype),
+                                           o_ref[rows, :])
 
     return pl.pallas_call(
         kernel,
@@ -145,6 +166,9 @@ def grouped_matmul(lhs, rhs, group_sizes, interpret: bool = False):
     if not ((use_pallas() or interpret) and k % 128 == 0 and n % 128 == 0):
         return _reference(lhs, rhs, group_sizes)
     tm = next(t for floor, t in _TM_LADDER if m >= floor)
+    # which tile the programs took: once a call each time a program that
+    # holds it is traced (or an eager call made), never in a compiled step
+    obs.registry().counter("moe.grouped_mm_programs", tm=tm).inc()
     pad = -m % tm
     if pad:
         lhs = jnp.pad(lhs, ((0, pad), (0, 0)))

@@ -66,6 +66,9 @@ name                         producer / meaning
                              same of prefill calls (``moe.prefill_*``) and
                              ``mla.prefill_kilo_pairs``.  Counted on the device,
                              read inside ``serve.readback`` (``LatentKV``)
+``moe.grouped_mm_programs``  counter {tm}: calls of the grouped expert product
+                             traced under each row tile (``kernels/
+                             grouped_matmul.py``); nothing in a compiled program
 ``cache.counters``           span: that read; args carry the running totals
 ``cache.latent_bytes_per_token``  gauge {replica}: one latent row, a layer
 ``cache.latent_blocks_live``  gauge {replica}: blocks live slots hold

@@ -226,11 +226,14 @@ def test_latent_prefill_attention(one_chip, kernel_branch, b, s):
     assert "mla_prefill_attn" in text.as_text()
 
 
-@pytest.mark.parametrize("rows", [192, 1536, 98304],
-                         ids=["decode", "prefill_256", "prefill_4x4096"])
+@pytest.mark.parametrize("rows", [192, 1536, 98304, 6144, 12288],
+                         ids=["decode", "prefill_256", "prefill_4x4096",
+                              "prefill_1024", "prefill_2048"])
 def test_grouped_matmul(one_chip, kernel_branch, rows):
     """Both projections of an expert layer at a decode step's rows (32
-    slots x 6), one short prompt's and the widest prefill rung's."""
+    slots x 6), one short prompt's, the widest prefill rung's, and one
+    prompt of the 1024 and of the 2048 bucket (the cell's common calls:
+    512-row tiles walked in 128-row blocks)."""
     from paddle_tpu.kernels import grouped_matmul
 
     for k, n in ((2048, 2816), (1408, 2048)):

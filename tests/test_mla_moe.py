@@ -137,6 +137,9 @@ def test_engine_logits_match_reference(kernels, monkeypatch, request):
         model, bs, buckets = _model(7, **KERNEL_WIDTHS), 32, (128,)
     else:
         model, bs, buckets = _model(7), 16, (16, 32, 64)
+    tiles = obs.registry().counter
+    took = [tiles("moe.grouped_mm_programs", tm=t) for t in (64, 128)]
+    before = [c.value for c in took]
     rng = np.random.default_rng(1)
     first = rng.integers(1, 512, size=bs + 5).astype(np.int32)
     second = np.concatenate(
@@ -157,6 +160,10 @@ def test_engine_logits_match_reference(kernels, monkeypatch, request):
         assert np.abs(logits[rid] - want).max() < TOL, rid
         assert outs[rid] == list(want.argmax(-1)), rid
     assert eng.backend._ref == {}          # every block went back
+    # the decode programs (4 slots x 2 experts a token) took the 64-row tile,
+    # the prefill programs (128 x 2 rows) the next rung; XLA's path none
+    assert all((c.value > b) == (kernels == "pallas_interpret")
+               for c, b in zip(took, before))
 
 
 def test_engine_evicts_and_resumes_through_the_latent_cache():
@@ -288,22 +295,96 @@ def test_dropless_experts_under_a_skewed_router(path):
     assert np.all(got[-5:] == 0)
 
 
-@pytest.mark.parametrize("sizes", [[10, 0, 100, 18], [0, 0, 0, 128],
-                                   [33, 31, 1, 0], [0, 0, 0, 0]])
-def test_grouped_matmul_kernel_against_a_loop(sizes):
+def _ragged(rows, top, seed, heavy=None):
+    """64 group sizes of 0..``top`` rows (edges fall anywhere in a tile)
+    that leave a trailing stretch of ``rows`` to no group; ``heavy``: one
+    group that holds most of the rows."""
+    sizes = np.random.default_rng(seed).integers(0, top + 1, size=64)
+    sizes[::9] = 0
+    if heavy is not None:
+        sizes[37] = heavy
+    assert 0 < rows - sizes.sum() < rows // 4, sizes.sum()
+    return [int(n) for n in sizes]
+
+
+# a decode step's few rows in 4 groups, then prefill shapes: 64 groups over
+# one short prompt's rows (256 x 6) and over the 1024 bucket's (1024 x 6)
+GROUPED_CASES = {
+    "skewed": (128, [10, 0, 100, 18]), "one_group": (128, [0, 0, 0, 128]),
+    "odd_edges": (128, [33, 31, 1, 0]), "no_rows": (128, [0, 0, 0, 0]),
+    "prefill_1536": (1536, _ragged(1536, 50, seed=1)),
+    "prefill_6144": (6144, _ragged(6144, 200, seed=2)),
+    "prefill_6144_one_heavy": (6144, _ragged(6144, 24, seed=3, heavy=4500)),
+}
+
+
+def _grouped_case(name):
+    rows, sizes = GROUPED_CASES[name]
     rng = np.random.default_rng(sum(sizes))
-    a = rng.normal(size=(128, 128)).astype(np.float32)
-    b = rng.normal(size=(4, 128, 256)).astype(np.float32)
-    want, r = np.zeros((128, 256), np.float32), 0
+    a = rng.normal(size=(rows, 128)).astype(np.float32)
+    b = rng.normal(size=(len(sizes), 128, 256)).astype(np.float32)
+    want, r = np.zeros((rows, 256), np.float32), 0
     for g, n in enumerate(sizes):
         want[r:r + n] = a[r:r + n] @ b[g]
         r += n
+    return (jnp.asarray(a), jnp.asarray(b), jnp.asarray(sizes, jnp.int32),
+            want)
+
+
+@pytest.mark.parametrize("case", GROUPED_CASES)
+def test_grouped_matmul_kernel_against_a_loop(case):
+    a, b, sizes, want = _grouped_case(case)
     with jax.default_matmul_precision("highest"):
         for interpret in (False, True):
-            got = grouped_matmul.grouped_matmul(
-                jnp.asarray(a), jnp.asarray(b), jnp.asarray(sizes, jnp.int32),
-                interpret=interpret)
+            got = grouped_matmul.grouped_matmul(a, b, sizes,
+                                                interpret=interpret)
             assert np.abs(np.asarray(got) - want).max() < 1e-4
+
+
+@pytest.mark.parametrize("tm", [64, 128, 256, 512])
+def test_grouped_matmul_rows_do_not_know_their_tile(tm):
+    """Whatever tile the ladder would choose: the same inputs give the same
+    rows under every tile, one block of 64 rows or 128-row blocks of which
+    those with none of the group's rows are skipped (``K`` is never split, a
+    row's sum is its own)."""
+    with jax.default_matmul_precision("highest"):
+        for case in ("skewed", "prefill_1536", "prefill_6144_one_heavy"):
+            a, b, sizes, want = _grouped_case(case)
+            rows = a.shape[0]
+            got = np.asarray(grouped_matmul._pallas_gmm(
+                jnp.pad(a, ((0, -rows % tm), (0, 0))), b, sizes, tm,
+                interpret=True))[:int(sizes.sum())]
+            assert np.abs(got - want[:len(got)]).max() < 1e-4, case
+
+
+def test_grouped_matmul_programs_counted_by_tile():
+    """The tile follows the call's rows as it always did (what a pair
+    computes no longer follows the tile: 128-row blocks of it, chip table in
+    PERF.md section 6, PR 36): a decode step's 192 rows over 64 experts keep
+    the 64-row tile, one prompt of the 1024 or 2048 bucket and the widest
+    prefill call take 512-row tiles.  ``moe.grouped_mm_programs{tm}`` counts
+    one a traced call, none a compiled one."""
+    def count(tm):
+        return obs.registry().counter("moe.grouped_mm_programs", tm=tm).value
+
+    def gmm(a, b, sizes):
+        return grouped_matmul.grouped_matmul(a, b, sizes, interpret=True)
+
+    b = jnp.zeros((64, 128, 128), jnp.float32)
+    sizes = jnp.full((64,), 3, jnp.int32)
+    before = {tm: count(tm) for tm in (64, 256, 512)}
+    decode = jax.jit(gmm)
+    decode(jnp.zeros((192, 128), jnp.float32), b, sizes)
+    decode(jnp.zeros((192, 128), jnp.float32), b, sizes)
+    # XLA's ragged product has no tile: nothing counted
+    grouped_matmul.grouped_matmul(jnp.zeros((192, 128), jnp.float32), b, sizes)
+    assert [count(tm) - n for tm, n in before.items()] == [1, 0, 0]
+    for rows in (1536, 6144, 12288, 98304):
+        jax.jit(gmm).lower(jax.ShapeDtypeStruct((rows, 128), jnp.float32),
+                           b, sizes)
+    assert [count(tm) - n for tm, n in before.items()] == [1, 1, 3]
+    assert obs.registry().snapshot()["moe.grouped_mm_programs{tm=512}"][
+        "labels"] == {"tm": 512}
 
 
 # ---------------------------------------------- (5) the backend's numbers --

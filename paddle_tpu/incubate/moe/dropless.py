@@ -16,6 +16,17 @@ dropped: an expert with no row is never read, one with most rows just has
 more row tiles.  Tokens marked invalid (a prefill bucket's padding, an
 inactive decode slot) are routed nowhere and cost no product.
 
+**A share of the experts** (``held=(first, count)``): under expert
+parallelism a chip holds ``count`` of the layer's experts.  The router and
+its bias keep every expert's output and the top-k is of all of them; the
+stacked weights are the held experts' only; a row whose expert lives
+elsewhere sorts past all groups exactly as an invalid row does, costs no
+product, and the layer returns the held experts' part of ``sum_i w_i
+E_i(x)``.  Nothing stands in for the other chips or their traffic.  With
+most rows elsewhere the sorted rows are computed a segment at a time
+(``_segment``): the workspace follows the rows this chip owns, not all the
+rows routed.
+
 Serving path only: there is no backward here (the training path's
 gradients and the optimizer's share of expert state are ROADMAP's).
 """
@@ -63,30 +74,83 @@ def sigmoid_topk_route(x, w_router, bias, top_k: int, scale: float = 1.0,
     return idx.astype(jnp.int32), w * scale
 
 
+# rows (token, choice) of one call from which a share's sorted rows are
+# computed a segment at a time, and the room a segment leaves over the
+# share's expected rows
+_SEGMENT_FLOOR = 8192
+_SEGMENT_ROOM = 2
+
+
+def _segment(rows: int, share: float) -> int:
+    """Rows of one segment of a call's ``rows`` sorted rows, of which the
+    held experts expect ``share``: the whole call where every expert is held
+    or the call is small, else the power-of-two fraction (an eighth at
+    least) that holds ``_SEGMENT_ROOM`` times the expected rows."""
+    if share >= 1.0 or rows < _SEGMENT_FLOOR:
+        return rows
+    parts = 1
+    while parts < 8 and _SEGMENT_ROOM * share * 2 * parts <= 1.0 \
+            and rows % (2 * parts) == 0:
+        parts *= 2
+    return rows // parts
+
+
 def dropless_experts(x, idx, weights, w_gate_up, w_down, valid=None,
+                     first: int = 0, num_experts=None,
                      interpret: bool = False):
-    """``sum_j weights[t, j] * E_idx[t, j](x[t])`` for SwiGLU experts stacked
-    ``w_gate_up [E, hidden, 2 * width]`` (gate first) and ``w_down [E, width,
-    hidden]``.  ``valid [T]`` bool: tokens to compute at all.  Returns the
-    output ``[T, hidden]`` in ``x``'s dtype and ``[rows, experts touched,
-    largest expert's rows]`` (float32) of the valid tokens."""
+    """``sum_j weights[t, j] * E_idx[t, j](x[t])`` over the HELD experts:
+    SwiGLU experts ``first .. first + E`` of ``num_experts`` (default: all
+    are held), stacked ``w_gate_up [E, hidden, 2 * width]`` (gate first) and
+    ``w_down [E, width, hidden]``.  ``valid [T]`` bool: tokens to compute at
+    all.  Returns the output ``[T, hidden]`` in ``x``'s dtype and ``[rows,
+    experts touched, largest expert's rows]`` (float32) of the valid tokens
+    and the held experts; a share of the experts counts a fourth, the rows
+    routed elsewhere."""
     T, k = idx.shape
     E = w_gate_up.shape[0]
     flat = idx.reshape(-1)
-    if valid is not None:
-        flat = jnp.where(jnp.repeat(valid, k), flat, E)     # sorts past all
+    live = None if valid is None else jnp.repeat(valid, k)
+    elsewhere = []
+    if num_experts is not None and (first, E) != (0, num_experts):
+        flat = flat - first
+        mine = (flat >= 0) & (flat < E)
+        elsewhere = [jnp.sum(~mine if live is None else ~mine & live)]
+        live = mine if live is None else mine & live
+    if live is not None:
+        flat = jnp.where(live, flat, E)                     # sorts past all
     order = jnp.argsort(flat, stable=True)                  # rows by expert
     sizes = jnp.bincount(flat, length=E + 1)[:E].astype(jnp.int32)
-    rows = x[order // k]
-    hidden = swiglu(grouped_matmul(rows, w_gate_up, sizes,
-                                   interpret=interpret))
-    rows = grouped_matmul(hidden, w_down, sizes, interpret=interpret)
-    back = rows[jnp.argsort(order)].reshape(T, k, -1)
-    out = jnp.sum(back.astype(jnp.float32) * weights[..., None], axis=1)
+    seg = _segment(T * k, E / (num_experts or E))
+
+    def product(rows, seg_sizes):
+        hidden = swiglu(grouped_matmul(rows, w_gate_up, seg_sizes,
+                                       interpret=interpret))
+        return grouped_matmul(hidden, w_down, seg_sizes, interpret=interpret)
+
+    if seg == T * k:
+        back = product(x[order // k], sizes)[jnp.argsort(order)]
+        out = jnp.sum(back.reshape(T, k, -1).astype(jnp.float32)
+                      * weights[..., None], axis=1)
+    else:
+        # the held rows are the first sum(sizes) of the sorted rows: only
+        # the segments that hold some of them run
+        ends = jnp.cumsum(sizes)
+        starts, w_flat = ends - sizes, weights.reshape(-1)
+
+        def one(s, out):
+            lo = s * seg
+            mine = jax.lax.dynamic_slice(order, (lo,), (seg,))
+            got = product(x[mine // k], jnp.clip(ends, lo, lo + seg)
+                          - jnp.clip(starts, lo, lo + seg))
+            return out.at[mine // k].add(got.astype(jnp.float32)
+                                         * w_flat[mine][:, None])
+
+        out = jax.lax.fori_loop(0, (ends[-1] + seg - 1) // seg, one,
+                                jnp.zeros(x.shape, jnp.float32))
     if valid is not None:
         out = jnp.where(valid[:, None], out, 0.0)
-    stats = jnp.stack([jnp.sum(sizes), jnp.sum(sizes > 0),
-                       jnp.max(sizes)]).astype(jnp.float32)
+    stats = jnp.stack([jnp.sum(sizes), jnp.sum(sizes > 0), jnp.max(sizes),
+                       *elsewhere]).astype(jnp.float32)
     return out.astype(x.dtype), stats
 
 
@@ -97,14 +161,22 @@ def _raw(x):
 class DroplessMoE(Layer):
     """The expert feed-forward of one decoder layer: ``num_experts`` routed
     SwiGLU experts of ``d_hidden``, ``top_k`` a token, and one shared SwiGLU
-    of ``num_shared * d_hidden`` that every token passes."""
+    of ``num_shared * d_hidden`` that every token passes.  ``held = (first,
+    count)``: the experts whose weights live here (default: all); the router
+    keeps ``num_experts`` outputs and the layer returns the held experts'
+    part of the routed sum (the shared expert, which every chip of the
+    deployment computes alike, is added whole)."""
 
     def __init__(self, d_model: int, d_hidden: int, num_experts: int,
                  top_k: int, num_shared: int = 0, scale: float = 1.0,
                  norm_topk: bool = True, dtype=None,
-                 initializer_range: float = 0.02):
+                 initializer_range: float = 0.02, held=None):
         super().__init__()
         self.top_k, self.scale, self.norm_topk = top_k, scale, norm_topk
+        self.num_experts = num_experts
+        self.first, n_held = held if held is not None else (0, num_experts)
+        if not 0 <= self.first <= self.first + n_held <= num_experts:
+            raise ValueError(f"held={held} of {num_experts} experts")
         init = Normal(0.0, initializer_range)
         # router and selection bias stay float32 (routing numerics)
         self.gate_weight = self.create_parameter(
@@ -112,10 +184,10 @@ class DroplessMoE(Layer):
         self.e_score_correction_bias = self.create_parameter(
             [num_experts], dtype="float32", default_initializer=Constant(0.0))
         self.w_gate_up = self.create_parameter(
-            [num_experts, d_model, 2 * d_hidden], dtype=dtype,
+            [n_held, d_model, 2 * d_hidden], dtype=dtype,
             default_initializer=init)
         self.w_down = self.create_parameter(
-            [num_experts, d_hidden, d_model], dtype=dtype,
+            [n_held, d_hidden, d_model], dtype=dtype,
             default_initializer=init)
         self.num_shared = num_shared
         if num_shared:
@@ -142,7 +214,7 @@ class DroplessMoE(Layer):
         with scope("moe.experts", h):
             y, stats = dropless_experts(
                 tokens, idx, w, _raw(self.w_gate_up), _raw(self.w_down),
-                valid=valid)
+                valid=valid, first=self.first, num_experts=self.num_experts)
             if self.num_shared:
                 y = y + swiglu(tokens @ _raw(self.shared_gate_up).astype(
                     tokens.dtype)) @ _raw(self.shared_down).astype(

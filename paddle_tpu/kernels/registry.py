@@ -80,7 +80,7 @@ def load_all() -> None:
     """Import every kernel module so its registrations run."""
     from . import adamw, flash_attention, rms_norm, ssd_scan  # noqa: F401
     from . import decode_attention  # noqa: F401  (not in package __init__)
-    from . import mla_attention  # noqa: F401
+    from . import gqa_attention, mla_attention  # noqa: F401
     from . import emit  # noqa: F401  (fusion-transformer emitted kernels)
 
 

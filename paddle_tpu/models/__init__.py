@@ -44,3 +44,8 @@ from .mla_moe import (  # noqa: F401
     MlaMoeForCausalLM,
     mla_moe_tiny_config,
 )
+from .swa_moe import (  # noqa: F401
+    SwaMoeConfig,
+    SwaMoeForCausalLM,
+    swa_moe_tiny_config,
+)

@@ -49,18 +49,17 @@ from ..incubate.moe.dropless import DroplessMoE, scope
 from ..kernels import mla_attention as mla
 from ..kernels import rope as rope_mod
 from ..kernels.rms_norm import rms_norm
-from ..kernels.swiglu import swiglu
 from ..nn.initializer import Constant, Normal
 from ..nn.layers import Layer, LayerList
+from .moe_serving import (MOE_COUNTERS, DenseMLP, last_valid_rows,
+                          moe_counts, token_validity)
+from .moe_serving import raw as _raw
 
 __all__ = ["MlaMoeConfig", "MlaMoeForCausalLM", "mla_moe_tiny_config",
            "COUNTERS"]
 
 # what ``counters`` counts, in order (``cache_spec()["counters"]``)
-COUNTERS = ("moe.steps", "moe.rows", "moe.experts_touched",
-            "moe.max_expert_rows", "moe.prefill_calls", "moe.prefill_rows",
-            "moe.prefill_experts_touched", "moe.prefill_max_expert_rows",
-            "mla.prefill_kilo_pairs")
+COUNTERS = MOE_COUNTERS + ("mla.prefill_kilo_pairs",)
 
 
 @dataclass
@@ -132,10 +131,6 @@ def mla_moe_tiny_config(**overrides) -> MlaMoeConfig:
                max_position_embeddings=512, dtype="float32")
     cfg.update(overrides)
     return MlaMoeConfig(**cfg)
-
-
-def _raw(x):
-    return x._data if isinstance(x, Tensor) else x
 
 
 def _rope_pairs(x, cos, sin, pos):
@@ -226,23 +221,6 @@ class MlaAttention(Layer):
         return self._out(jnp.einsum("bshr,rhv->bshv", o_lat, w_v)), pool
 
 
-class _DenseMLP(Layer):
-    def __init__(self, config: MlaMoeConfig):
-        super().__init__()
-        init = Normal(0.0, config.initializer_range)
-        self.gate_up_proj = self.create_parameter(
-            [config.hidden_size, 2 * config.intermediate_size],
-            dtype=config.pdtype, default_initializer=init)
-        self.down_proj = self.create_parameter(
-            [config.intermediate_size, config.hidden_size],
-            dtype=config.pdtype, default_initializer=init)
-
-    def forward(self, x):
-        x = _raw(x)
-        return swiglu(x @ _raw(self.gate_up_proj).astype(x.dtype)) \
-            @ _raw(self.down_proj).astype(x.dtype)
-
-
 class MlaMoeDecoderLayer(Layer):
     def __init__(self, config: MlaMoeConfig, index: int):
         super().__init__()
@@ -262,7 +240,7 @@ class MlaMoeDecoderLayer(Layer):
                 scale=c.routed_scaling_factor, norm_topk=c.norm_topk_prob,
                 dtype=c.pdtype, initializer_range=c.initializer_range)
         else:
-            self.mlp = _DenseMLP(c)
+            self.mlp = DenseMLP(c)
 
     def forward(self, x, cos, sin, cache=None, valid=None):
         """Returns ``(hidden, rows or pool, expert counts or None)``."""
@@ -345,15 +323,9 @@ class MlaMoeForCausalLM(Layer):
         x = jnp.take(_raw(self.embed_tokens), ids, axis=0).astype(c.dtype)
         cos, sin = _raw(self.rope_cos), _raw(self.rope_sin)
         paged = cache is not None and "block_table" in cache
-        n_valid = None if cache is None or cache.get("n_valid") is None \
-            else _raw(cache["n_valid"]).reshape(-1)
-        valid = None
+        n_valid, valid = token_validity(cache, S)
         if paged:
             tbl, lengths = _raw(cache["block_table"]), _raw(cache["lengths"])
-            if S == 1:
-                valid = (lengths > 0)[:, None]
-        if n_valid is not None:
-            valid = jnp.arange(S)[None, :] < n_valid[:, None]
         kept, counts = [], jnp.zeros((3,), jnp.float32)
         for i, layer in enumerate(self.layers):
             x, k, stats = layer(
@@ -363,25 +335,22 @@ class MlaMoeForCausalLM(Layer):
             kept.append(k)
             if stats is not None:
                 counts = counts + stats
-        if n_valid is not None:
-            x = jnp.take_along_axis(x, (n_valid - 1)[:, None, None], axis=1)
-        x = rms_norm(x, _raw(self.norm), c.rms_norm_eps)
+        x = rms_norm(last_valid_rows(x, n_valid), _raw(self.norm),
+                     c.rms_norm_eps)
         logits = Tensor(x @ _raw(self.lm_head).astype(x.dtype))
         if cache is None:
             return logits
-        inc = jnp.concatenate([jnp.ones((1,), jnp.int32),
-                               counts.astype(jnp.int32)])
-        zero = jnp.zeros((4,), jnp.int32)
         if not paged:
             n = jnp.full((B,), S) if n_valid is None else n_valid
             pairs = jnp.sum(n * n // 1024, keepdims=True).astype(jnp.int32)
             return logits, {"latent": tuple(kept),
-                            "counters": jnp.concatenate([zero, inc, pairs])}
+                            "counters": jnp.concatenate(
+                                [moe_counts(counts, False), pairs])}
         decode = S == 1
         new_cache = {
             "latent": tuple(kept), "block_table": tbl,
             "lengths": lengths + ((lengths > 0).astype(lengths.dtype)
                                   if decode else jnp.asarray(S, lengths.dtype)),
             "counters": _raw(cache["counters"]) + jnp.concatenate(
-                [inc, zero, zero[:1]] if decode else [zero, inc, zero[:1]])}
+                [moe_counts(counts, decode), jnp.zeros((1,), jnp.int32)])}
         return logits, new_cache

@@ -14,8 +14,14 @@ cache kind by name:
   prefix caching / block hashing / chunked prefill are structurally
   unsupported (the router degrades to headroom+load scoring); on the device,
   one state dict per SSD layer, ``max_slots`` wide.
-- :class:`HybridCache` — both at once for a stack that mixes attention and
-  SSD layers: every verb is answered by composing the two parts.
+- :class:`WindowKV` — a BOUNDED per-slot ring for sliding-window attention
+  layers: the last ``window`` positions of a sequence a layer, so a window
+  layer holds ``window`` tokens however long the context; the slot ledger
+  and the flat ``seq_bytes`` are :class:`RecurrentState`'s, and like
+  :class:`LatentKV` it carries the model's device-side ``counters``.
+- :class:`HybridCache` — pages and a per-slot part at once, for a stack
+  that mixes full attention with SSD layers or with window layers: every
+  verb is answered by composing the two parts.
 - :class:`LatentKV` — paged like :class:`PagedKV` (the same blocks,
   refcounts and prefix hashing) for latent attention: a token's cache is
   ONE row a layer (the compressed latent and the shared rotated key), no
@@ -56,7 +62,7 @@ import collections
 from typing import Callable, Dict, List, Optional, Tuple
 
 __all__ = ["CacheBackend", "PagedKV", "LatentKV", "RecurrentState",
-           "HybridCache", "KINDS", "make_backend"]
+           "WindowKV", "HybridCache", "KINDS", "make_backend"]
 
 
 class CacheBackend:
@@ -150,11 +156,16 @@ class CacheBackend:
         row j belongs to slot ``slots[j]`` and owns ``blocks[j]``."""
         return device
 
-    def read_counters(self, **labels) -> None:
+    def read_counters(self, gauges=None, **labels) -> None:
         """Publish to ``obs`` what the device state counts.  The engine
         calls it inside its token readback, when everything dispatched has
         run: a backend that keeps counters on the device reads them here
-        and waits for nothing."""
+        and waits for nothing.  ``gauges``: what another part of a composed
+        cache wants published beside this part's own."""
+
+    def gauges(self) -> Dict[str, float]:
+        """Host-side readings of the cache as it stands (``obs`` gauges)."""
+        return {}
 
     # -- accounting ---------------------------------------------------------
 
@@ -203,12 +214,17 @@ class PagedKV(CacheBackend):
     state_keys = ("k", "v")
 
     def __init__(self, num_blocks: int, block_size: int,
-                 bytes_per_token: int, prefix_cache: bool = True):
+                 bytes_per_token: int, prefix_cache: bool = True,
+                 write_prefill=None):
         self.num_blocks = num_blocks
         self.block_size = block_size
         # summed over KV layers: 2 (K and V) * kv_heads * head_dim * itemsize
         self.bytes_per_token = bytes_per_token
         self.supports_prefix_cache = bool(prefix_cache)
+        # how a prefilled sequence's K/V go into its blocks, where the
+        # model's pools are not in the serving layout (its cache_spec()'s
+        # ``kv_write_prefill``; same signature as ``write_paged_prefill``)
+        self._write_prefill = write_prefill
         self._ref: Dict[int, int] = {}        # block -> live-owner count
         self._index: Dict[bytes, int] = {}    # chain-hash -> block
         self._hash_of: Dict[int, bytes] = {}  # block -> registered hash
@@ -299,7 +315,9 @@ class PagedKV(CacheBackend):
     def write_prefill(self, device, new_cache, slots, blocks):
         """Scatter each row's bucket-padded K/V into its blocks (a freed
         padding block's id is 0 by then: the trash block takes the tail)."""
-        from ..kernels.decode_attention import write_paged_prefill
+        write_paged_prefill = self._write_prefill
+        if write_paged_prefill is None:
+            from ..kernels.decode_attention import write_paged_prefill
 
         n, n_blocks = blocks.shape
         Pb = n_blocks * self.block_size
@@ -310,6 +328,10 @@ class PagedKV(CacheBackend):
                     k_pools[li], v_pools[li], blocks[j],
                     k_c[j, :Pb], v_c[j, :Pb])
         return {**device, "k": tuple(k_pools), "v": tuple(v_pools)}
+
+    def gauges(self) -> Dict[str, float]:
+        return {"cache.kv_bytes_per_token": self.bytes_per_token,
+                "cache.kv_blocks_live": len(self._ref)}
 
     def pool_bytes(self) -> int:
         return self.num_blocks * self.block_bytes
@@ -327,18 +349,54 @@ class PagedKV(CacheBackend):
                            "bytes_each": self.block_bytes}]}
 
 
-class LatentKV(PagedKV):
+class _DeviceCounters:
+    """The model counts on the device (``counters``, int32 sums: what its
+    expert layers routed, the pairs its prefills attended) and the cache
+    carries the vector through every program; at a readback what was added
+    since the last one becomes increments of the ``obs`` counters named in
+    the model's ``cache_spec()["counters"]``."""
+
+    counters: Tuple[str, ...] = ()
+
+    def _init_counters(self, counters) -> None:
+        self.counters = tuple(counters)
+        self._published = [0] * len(self.counters)
+
+    def _zero_counters(self):
+        import jax.numpy as jnp
+
+        return jnp.zeros((len(self.counters),), jnp.int32)
+
+    def _publish(self, gauges: Dict[str, float], labels: Dict,
+                 in_span: bool = False) -> None:
+        import numpy as np
+
+        from .. import obs
+
+        reg = obs.registry()
+        # the span carries the running totals: a trace's reader takes the
+        # difference of two of them for what a stretch of the run counted
+        with obs.span("cache.counters", cat="serve") as sp:
+            now = np.asarray(self.device["counters"]).astype(np.uint32)
+            sp.set(**{n: int(v) for n, v in zip(self.counters, now)},
+                   **(gauges if in_span else {}))
+        # the int32 sums wrap; what was added since the last read does not
+        for name, d in zip(self.counters,
+                           now - np.asarray(self._published, np.uint32)):
+            if d:
+                reg.counter(name, **labels).inc(int(d))
+        self._published = now
+        for name, v in gauges.items():
+            reg.gauge(name, **labels).set(v)
+
+
+class LatentKV(_DeviceCounters, PagedKV):
     """Paged latent cache: :class:`PagedKV`'s block bookkeeping (blocks,
     refcounts, prefix-cache LRU, byte accounting) over pools that hold one
     row a token a layer, ``rank + rope`` values wide, which every head
     reads (``kernels/mla_attention.py`` has the layout and the writes).
-    ``bytes_per_token`` is that row's bytes summed over the layers.
-
-    The model counts on the device (``counters``, int32 sums: what its
-    expert layers routed) and the cache carries the vector through every
-    program; ``read_counters`` turns what was added since the last
-    readback into increments of the ``obs`` counters named in the model's
-    ``cache_spec()["counters"]``."""
+    ``bytes_per_token`` is that row's bytes summed over the layers.  It
+    carries the model's ``counters`` (:class:`_DeviceCounters`)."""
 
     kind = "latent_kv"
     state_keys = ("latent", "counters")
@@ -349,15 +407,12 @@ class LatentKV(PagedKV):
         super().__init__(num_blocks, block_size, bytes_per_token,
                          prefix_cache=prefix_cache)
         self.rank = rank
-        self.counters = tuple(counters)
-        self._published = [0] * len(self.counters)
+        self._init_counters(counters)
 
     def init_device(self, model) -> Dict:
-        import jax.numpy as jnp
-
         return {"latent": model.init_latent_pools(self.num_blocks,
                                                   self.block_size),
-                "counters": jnp.zeros((len(self.counters),), jnp.int32)}
+                "counters": self._zero_counters()}
 
     def prefill_cache(self, cache, n_valid):
         # the model skips the padded tail's experts and computes the logits
@@ -376,27 +431,14 @@ class LatentKV(PagedKV):
         return {"latent": tuple(pools),
                 "counters": device["counters"] + new_cache["counters"]}
 
-    def read_counters(self, **labels) -> None:
-        import numpy as np
-
-        from .. import obs
-
-        reg = obs.registry()
-        # the span carries the running totals: a trace's reader takes the
-        # difference of two of them for what a stretch of the run counted
-        with obs.span("cache.counters", cat="serve") as sp:
-            now = np.asarray(self.device["counters"]).astype(np.uint32)
-            sp.set(**{n: int(v) for n, v in zip(self.counters, now)})
-        # the int32 sums wrap; what was added since the last read does not
-        for name, d in zip(self.counters,
-                           now - np.asarray(self._published, np.uint32)):
-            if d:
-                reg.counter(name, **labels).inc(int(d))
-        self._published = now
+    def gauges(self) -> Dict[str, float]:
         per_layer = max(1, len(self.device["latent"]))
-        reg.gauge("cache.latent_bytes_per_token", **labels).set(
-            self.bytes_per_token // per_layer)
-        reg.gauge("cache.latent_blocks_live", **labels).set(len(self._ref))
+        return {"cache.latent_bytes_per_token":
+                    self.bytes_per_token // per_layer,
+                "cache.latent_blocks_live": len(self._ref)}
+
+    def read_counters(self, gauges=None, **labels) -> None:
+        self._publish({**self.gauges(), **(gauges or {})}, labels)
 
 
 class RecurrentState(CacheBackend):
@@ -412,6 +454,8 @@ class RecurrentState(CacheBackend):
     # the recurrent forward masks the padded tail by ONE scalar n_valid
     prefill_ladder = (1,)
     state_keys = ("ssd",)
+    # the entry of the model's cache that holds one state dict a layer
+    slot_key = "ssd"
 
     def __init__(self, max_slots: int, state_bytes_per_slot: int):
         self.max_slots = max_slots
@@ -438,9 +482,10 @@ class RecurrentState(CacheBackend):
         return {**cache, "n_valid": n_valid[0]}
 
     def write_prefill(self, device, new_cache, slots, blocks):
-        return {**device, "ssd": tuple(
+        key = self.slot_key
+        return {**device, key: tuple(
             {name: cur[name].at[slots].set(new[name]) for name in cur}
-            for cur, new in zip(device["ssd"], new_cache["ssd"]))}
+            for cur, new in zip(device[key], new_cache[key]))}
 
     def state_bytes(self) -> int:
         return self.max_slots * self.state_bytes_per_slot
@@ -457,16 +502,66 @@ class RecurrentState(CacheBackend):
                            "bytes_each": self.state_bytes_per_slot}]}
 
 
+class WindowKV(_DeviceCounters, RecurrentState):
+    """The window layers' residency: per slot and window layer ONE ring of
+    the last ``window`` positions' keys and values (the model's
+    ``init_window_rings``: position ``p`` at place ``p % window``, so after
+    the write of ``p`` the ring holds exactly ``p - window + 1 .. p``).
+    Bounded, so everything :class:`RecurrentState` says of a per-slot state
+    holds: no blocks, ``seq_bytes`` flat in context length, the slot ledger
+    released exactly once, one prompt a prefill call.  A prefill hands the
+    rows its ring must hold (the last ``window`` VALID positions of the
+    prompt, not of the padded bucket: the model gathers them, it knows the
+    true length) and the write puts them into the slot.  It also carries
+    the model's ``counters`` (:class:`_DeviceCounters`)."""
+
+    kind = "window_kv"
+    state_keys = ("window", "counters")
+    slot_key = "window"
+
+    def __init__(self, max_slots: int, state_bytes_per_slot: int,
+                 counters=()):
+        super().__init__(max_slots, state_bytes_per_slot)
+        self._init_counters(counters)
+
+    def init_device(self, model) -> Dict:
+        return {"window": model.init_window_rings(self.max_slots),
+                "counters": self._zero_counters()}
+
+    def prefill_cache(self, cache, n_valid):
+        # the model keeps the padded tail out of the rings and the experts
+        # and computes the logits of the last valid position only
+        return {**cache, "n_valid": n_valid}
+
+    def write_prefill(self, device, new_cache, slots, blocks):
+        device = super().write_prefill(device, new_cache, slots, blocks)
+        return {**device,
+                "counters": device["counters"] + new_cache["counters"]}
+
+    def gauges(self) -> Dict[str, float]:
+        return {"cache.window_bytes_per_slot": self.state_bytes_per_slot,
+                "cache.window_slots_live": len(self._live)}
+
+    def read_counters(self, gauges=None, **labels) -> None:
+        self._publish({**self.gauges(), **(gauges or {})}, labels,
+                      in_span=True)
+
+
 class HybridCache(CacheBackend):
-    """Paged KV for the attention layers + recurrent state for the SSD
-    layers of one hybrid stack.  Block verbs forward to the paged side;
-    byte accounting sums both; prefix caching is OFF — a prefix-cache hit
-    would restore only the attention half of the context (the SSD state
-    for those tokens is not block-addressable), which is silently wrong,
-    so the backend refuses rather than degrades."""
+    """Paged KV for the full-attention layers + a per-slot part for the
+    others of one hybrid stack: recurrent state for SSD layers
+    (:class:`RecurrentState`), bounded rings for window layers
+    (:class:`WindowKV`).  Block verbs forward to the paged side, slot verbs
+    to the per-slot side; byte accounting sums both; prefix caching is OFF —
+    a prefix-cache hit would restore only the paged half of the context (the
+    SSD state or the rings for those tokens are not block-addressable),
+    which is silently wrong, so the backend refuses rather than degrades.
+    Chunked prefill is unsupported for the same reason: a chunk's context is
+    its blocks, and the per-slot part has none."""
 
     kind = "hybrid"
     supports_prefix_cache = False
+    _device = None
 
     def __init__(self, pages: PagedKV, state: RecurrentState):
         self.pages = pages
@@ -474,6 +569,22 @@ class HybridCache(CacheBackend):
         self.prefill_ladder = tuple(
             n for n in pages.prefill_ladder if n in state.prefill_ladder)
         self.state_keys = pages.state_keys + state.state_keys
+
+    # the parts read their own entries of the one pytree
+    @property
+    def device(self):
+        return self._device
+
+    @device.setter
+    def device(self, value):
+        self._device = self.pages.device = self.state.device = value
+
+    def read_counters(self, gauges=None, **labels) -> None:
+        self.state.read_counters(
+            gauges={**self.pages.gauges(), **(gauges or {})}, **labels)
+
+    def gauges(self) -> Dict[str, float]:
+        return {**self.pages.gauges(), **self.state.gauges()}
 
     def blocks_for(self, n_tokens: int) -> int:
         return self.pages.blocks_for(n_tokens)
@@ -528,7 +639,8 @@ class HybridCache(CacheBackend):
 def _paged(spec, num_blocks, block_size, max_slots, prefix_cache):
     return PagedKV(num_blocks, block_size,
                    spec["kv_layers"] * spec["kv_bytes_per_token_layer"],
-                   prefix_cache=prefix_cache)
+                   prefix_cache=prefix_cache,
+                   write_prefill=spec.get("kv_write_prefill"))
 
 
 def _recurrent(spec, num_blocks, block_size, max_slots, prefix_cache):
@@ -543,10 +655,16 @@ def _latent(spec, num_blocks, block_size, max_slots, prefix_cache):
                     prefix_cache=prefix_cache)
 
 
+def _window(spec, num_blocks, block_size, max_slots, prefix_cache):
+    return WindowKV(max_slots, spec["state_bytes_per_slot"],
+                    counters=spec.get("counters", ()))
+
+
 # layer kind (an entry of ``cache_spec()["kinds"]``) -> the backend that
 # caches layers of that kind
 KINDS: Dict[str, Callable[..., CacheBackend]] = {
-    "attention": _paged, "ssd": _recurrent, "latent": _latent}
+    "attention": _paged, "ssd": _recurrent, "latent": _latent,
+    "window": _window}
 
 
 def make_backend(spec: Dict, num_blocks: int, block_size: int,
@@ -554,8 +672,10 @@ def make_backend(spec: Dict, num_blocks: int, block_size: int,
     """Build the backend a model's ``cache_spec()`` calls for.
 
     All-attention -> :class:`PagedKV` (prefix cache as configured);
-    all-SSD -> :class:`RecurrentState`; mixed -> :class:`HybridCache`
-    (prefix cache forced off — see the class docstring)."""
+    all-SSD -> :class:`RecurrentState`; all-latent -> :class:`LatentKV`;
+    attention mixed with SSD or with window layers -> :class:`HybridCache`
+    of the pages and that per-slot part (prefix cache forced off — see the
+    class docstring)."""
     unknown = set(spec["kinds"]) - set(KINDS)
     if unknown:
         raise ValueError(f"no cache backend for layer kinds {sorted(unknown)}")

@@ -14,7 +14,8 @@ import pytest
 
 from paddle_tpu.serving import cache_backend
 from paddle_tpu.serving.cache_backend import (
-    CacheBackend, HybridCache, PagedKV, RecurrentState, make_backend)
+    CacheBackend, HybridCache, PagedKV, RecurrentState, WindowKV,
+    make_backend)
 
 
 def _spec(kinds, state=0, kv_layers=0, kv_bpt=0):
@@ -161,9 +162,123 @@ class TestHybridCache:
         assert {u["unit"] for u in m["units"]} == {"kv_block", "slot_state"}
 
 
+# --------------------------------------------------------------- WindowKV --
+
+class _RingModel:
+    """What ``WindowKV`` asks of a model: one ring a window layer."""
+
+    def init_window_rings(self, max_slots):
+        import jax.numpy as jnp
+
+        return tuple({"k": jnp.zeros((max_slots, 2, 4, 6)),
+                      "v": jnp.zeros((max_slots, 2, 4, 3))}
+                     for _ in range(2))
+
+    def init_paged_pools(self, num_blocks, block_size):
+        import jax.numpy as jnp
+
+        pool = jnp.zeros((num_blocks, 2, block_size, 3))
+        return (pool,), (pool,)
+
+
+class TestWindowKV:
+    def test_slot_ledger_exactly_once(self):
+        be = WindowKV(2, 1000, counters=("a", "b"))
+        be.acquire_slot(1)
+        with pytest.raises(RuntimeError, match="already live"):
+            be.acquire_slot(1)
+        be.release_slot(1)
+        with pytest.raises(RuntimeError, match="double release"):
+            be.release_slot(1)
+
+    def test_bounded_whatever_the_context(self):
+        be = WindowKV(4, 1000)
+        assert be.seq_bytes(1) == be.seq_bytes(1 << 20) == 1000
+        assert be.state_bytes() == 4000 and be.pool_bytes() == 0
+        assert be.blocks_for(1 << 20) == 0 and be.alloc() is None
+        assert be.prefill_ladder == (1,)
+        assert not be.supports_prefix_cache
+        assert not be.supports_chunked_prefill
+        be.acquire_slot(0)
+        assert be.headroom_bytes() == 3000
+        assert be.gauges() == {"cache.window_bytes_per_slot": 1000,
+                               "cache.window_slots_live": 1}
+
+    def test_prefill_write_sets_the_slots_rings_and_adds_the_counts(self):
+        import jax.numpy as jnp
+
+        be = WindowKV(3, 1000, counters=("a", "b"))
+        dev = be.init_device(_RingModel())
+        assert sorted(dev) == ["counters", "window"] == sorted(be.state_keys)
+        cache = be.prefill_cache({"window": ()}, jnp.asarray([7]))
+        assert list(cache["n_valid"]) == [7]
+        new = {"window": tuple({"k": jnp.full((1, 2, 4, 6), i + 1.0),
+                                "v": jnp.full((1, 2, 4, 3), i + 5.0)}
+                               for i in range(2)),
+               "counters": jnp.asarray([3, 4], jnp.int32)}
+        dev = be.write_prefill(dev, new, jnp.asarray([2]), None)
+        dev = be.write_prefill(dev, new, jnp.asarray([0]), None)
+        k1 = np.asarray(dev["window"][1]["k"])
+        assert (k1[[0, 2]] == 2.0).all() and not k1[1].any()
+        assert list(np.asarray(dev["counters"])) == [6, 8]
+
+    def test_state_keys_round_trip_through_a_step(self):
+        """``step_cache`` hands the model every array under its key beside
+        the table and the lengths; ``take_device`` takes the same keys back
+        out of what the forward returns; a composed cache's parts read
+        their own entries of the one pytree."""
+        import jax.numpy as jnp
+
+        be = make_backend(_spec(["attention", "window", "window"],
+                                state=1000, kv_layers=1, kv_bpt=8)
+                          | {"counters": ("a",)},
+                          num_blocks=4, block_size=16, max_slots=3)
+        be.device = be.init_device(_RingModel())
+        assert be.state_keys == ("k", "v", "window", "counters")
+        assert be.state.device is be.device and be.pages.device is be.device
+        step = be.step_cache(be.device, jnp.zeros((3, 2), jnp.int32),
+                             jnp.zeros((3,), jnp.int32))
+        assert sorted(step) == sorted(be.state_keys + ("block_table",
+                                                       "lengths"))
+        back = be.take_device({**step, "lengths": step["lengths"] + 1})
+        assert sorted(back) == sorted(be.state_keys)
+        assert all(back[k] is be.device[k] for k in be.state_keys)
+
+
 # ------------------------------------------------------------ make_backend --
 
 class TestMakeBackend:
+    def test_attention_and_window_is_pages_and_rings(self):
+        def write(*a):
+            return "the model's own layout"
+
+        spec = _spec(["attention", "window", "window", "window"], state=1000,
+                     kv_layers=1, kv_bpt=8) | {"counters": ("a", "b"),
+                                               "kv_write_prefill": write}
+        be = make_backend(spec, num_blocks=8, block_size=16, max_slots=4,
+                          prefix_cache=True)
+        assert isinstance(be, HybridCache)
+        assert type(be.pages) is PagedKV and type(be.state) is WindowKV
+        assert be.pages._write_prefill is write
+        assert be.state.counters == ("a", "b") and be.state.max_slots == 4
+        # a hit would restore the full layers' half only; a chunk's context
+        # is its blocks and a ring has none
+        assert not be.supports_prefix_cache
+        assert not be.pages.supports_prefix_cache
+        assert not be.supports_chunked_prefill
+        assert be.prefill_ladder == (1,)
+        assert be.seq_bytes(16) == 16 * 8 + 1000
+        assert be.seq_bytes(1600) - be.seq_bytes(16) == 99 * 16 * 8
+        be.acquire_slot(0)
+        b = be.alloc()
+        assert be.gauges() == {
+            "cache.kv_bytes_per_token": 8, "cache.kv_blocks_live": 1,
+            "cache.window_bytes_per_slot": 1000, "cache.window_slots_live": 1}
+        be.release(b)
+        be.release_slot(0)
+        with pytest.raises(RuntimeError, match="double release"):
+            be.release_slot(0)
+
     def test_all_attention_is_paged(self):
         be = make_backend(_spec(["attention"] * 2, kv_layers=2, kv_bpt=8),
                           num_blocks=8, block_size=16, max_slots=4)

@@ -274,6 +274,81 @@ def test_latent_decode_chunk_copies_no_pool(one_chip, kernel_branch):
     assert not copies, copies[:2]
 
 
+# 64 query heads over 4 (full) or 8 (window) KV heads, keys 192 and values
+# 128 wide; 32 slots, 4,608 blocks of 128 tokens, tables of 256 blocks
+@pytest.mark.parametrize("s", [1024, 16384])
+@pytest.mark.parametrize("window,hk", [(None, 4), (128, 8)],
+                         ids=["full", "window"])
+def test_gqa_prefill_attention(one_chip, kernel_branch, window, hk, s):
+    """Both layer kinds' prefill at the cell's shortest and longest bucket:
+    64 query heads in groups of 16 or 8 a program, the score as a 64-wide and
+    a 128-wide product, the window's band of two key blocks."""
+    from paddle_tpu.kernels import gqa_attention
+
+    text = _compile(
+        one_chip, lambda q, k, v, b: gqa_attention.gqa_prefill_attention(
+            q, k, v, 0.07, window=window, sinks=b if window else None),
+        ((1, s, 64, 192), BF16), ((1, s, hk, 192), BF16),
+        ((1, s, hk, 128), BF16), ((64,), F32))
+    assert "gqa_prefill_attn" in text.as_text()
+
+
+_GQA_POOLS = (((4608, 4, 192, 128), BF16), ((4608, 4, 128, 128), BF16),
+              ((32, 256), I32), ((32,), I32))
+
+
+def test_gqa_paged_decode_and_token_write(one_chip, kernel_branch):
+    from paddle_tpu.kernels import gqa_attention
+
+    text = _compile(one_chip, lambda q, kp, vp, t, n:
+                    gqa_attention.gqa_paged_decode_attention(q, kp, vp, t, n,
+                                                             0.07),
+                    ((32, 64, 192), BF16), *_GQA_POOLS)
+    assert "gqa_paged_decode" in text.as_text()
+    # the token write updates the donated pools in place: no scratch
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in (*_GQA_POOLS, ((32, 4, 192), BF16),
+                         ((32, 4, 128), BF16))]
+    write = jax.jit(gqa_attention.write_kv_token,
+                    donate_argnums=(0, 1)).lower(*args).compile()
+    assert write.memory_analysis().temp_size_in_bytes < 2 ** 20
+
+
+def test_window_full_decode_chunk_copies_no_pool(one_chip, kernel_branch):
+    """The engine's decode program over pages and rings at the cell's
+    attention and expert widths (a full and a window expert layer after the
+    dense one, a small vocabulary): the token write leaves the pools as
+    ``gqa_paged_decode`` reads them, so no pool is copied, and the kernels
+    and scopes are in the program."""
+    import re
+
+    import paddle_tpu as paddle
+    from paddle_tpu.models.swa_moe import SwaMoeConfig, SwaMoeForCausalLM
+    from paddle_tpu.serving import Engine
+
+    paddle.seed(0)
+    model = SwaMoeForCausalLM(SwaMoeConfig(
+        vocab_size=1024, num_hidden_layers=3, hybrid_layer_pattern=(0, 1, 0),
+        moe_layer_freq=(0, 1, 1), intermediate_size=512, n_routed_experts=32,
+        experts_held=(0, 2), max_position_embeddings=1024))
+    eng = Engine(model, max_batch=32, num_blocks=48, block_size=BS,
+                 prefill_buckets=(BS,))
+    pool = eng.backend.device["k"][0]
+    assert pool.shape == (48, 4, BS * 3 // 2, 128) and pool.dtype == BF16
+    assert eng.backend.device["window"][0]["k"].shape == (32, 8, 128, 192)
+    args = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        eng._decode_dummy_args())
+    text = eng._get_decode_fn(4).lower(*args).compile().as_text()
+    for name in ("gqa_paged_decode", "moe_grouped_mm", "moe.experts",
+                 "attn.full", "attn.window"):
+        assert name in text, name
+    for p in (pool, eng.backend.device["v"][0]):
+        dims = ",".join(map(str, p.shape))
+        copies = re.findall(rf"= \w+\[{dims}\]\S* copy\(.*", text)
+        assert not copies, copies[:2]
+
+
 def test_ssd_scan(one_chip):
     # ssd_8b_config: 64 heads, state 128, head dim 64, chunk 128; seq 2048
     G, T, P, N, chunk = 64, 2048, 64, 128, 128

@@ -24,7 +24,15 @@ head.  With ``window=W`` a query block visits only the key blocks that meet
 its band ``[i - W + 1, i]`` (the grid's last axis counts band blocks, the
 index map starts at the band's first block, blocks past the diagonal are
 skipped and their fetch elided): a window layer's cost is flat in ``S``.
-The sink enters once, at the end: ``l += exp(b - m)``.
+The sink enters once, at the end: ``l += exp(b - m)``.  A prompt is padded
+to its bucket, and the kernel is told its true length ``n_valid`` (a scalar
+prefetch): a query block whose first position is at or past it is *void*,
+computes nothing in any of its steps and comes back 0.  ``n_valid`` asks
+for no mask of its own: a real row sees only keys at or before itself, all
+of them real.  Every step that runs builds the mask from positions and
+selects: a body without the select for the steps wholly under the diagonal
+was measured slower (the products bound a step, not the vector unit; the
+64-wide tail costs the MXU a pass of 128).
 
 **Paged decode of the full layers** (``gqa_paged_decode``): a block of the
 K pool holds its ``bs`` tokens' 128-wide parts, then ``bs / 2`` rows of
@@ -76,7 +84,8 @@ def _parts(d: int):
 # prefill: causal, grouped heads, optional window bound and sink
 # ---------------------------------------------------------------------------
 
-def _prefill_reference(q, k, v, sm_scale, window=None, sinks=None):
+def _prefill_reference(q, k, v, sm_scale, window=None, sinks=None,
+                       n_valid=None):
     f = jnp.float32
     B, S, H, _ = q.shape
     hk = k.shape[2]
@@ -94,7 +103,11 @@ def _prefill_reference(q, k, v, sm_scale, window=None, sinks=None):
     if sinks is not None:
         l = l + jnp.exp(b - m)
     o = jnp.einsum("bgrst,btgd->bsgrd", p / l, v.astype(f))
-    return o.reshape(B, S, H, -1).astype(v.dtype)
+    o = o.reshape(B, S, H, -1)
+    if n_valid is not None:
+        real = jnp.arange(S)[None, :] < n_valid[:, None]
+        o = jnp.where(real[:, :, None, None], o, 0.0)
+    return o.astype(v.dtype)
 
 
 def _prefill_blocks(S: int, window):
@@ -106,11 +119,34 @@ def _prefill_blocks(S: int, window):
     return min(S, 128), min(S, 512)
 
 
-def _pallas_prefill(q, k, v, sm_scale, window=None, sinks=None, block_q=None,
-                    block_k=None, interpret=False):
+def _band(i, bq: int, bk: int, window, maximum=max):
+    """``(first, last)`` key block query block ``i`` meets: from the band's
+    first block (block 0 without a window) to the diagonal's.  Shared by the
+    kernel's walk and the count of its steps (ints or arrays)."""
+    first = 0 if window is None else maximum(i * bq - (window - 1), 0) // bk
+    return first, (i * bq + bq - 1) // bk
+
+
+def prefill_pairs_run(S: int, window, n_valid):
+    """``[B]`` float32: the (query, key) pairs a query head of the grid steps
+    the kernel computes for prompts of ``n_valid [B]`` real positions in a
+    sequence of ``S``: ``block_q x block_k`` a step, over the band of every
+    query block that holds a real position.  The blocks are the kernel's
+    for ``S``, whichever path a call takes."""
+    bq, bk = _prefill_blocks(S, window)
+    i = jnp.arange(-(-S // bq))
+    first, last = _band(i, bq, bk, window, jnp.maximum)
+    live = (i * bq)[None, :] < jnp.asarray(n_valid, jnp.int32)[:, None]
+    steps = jnp.sum(jnp.where(live, last - first + 1, 0), axis=1)
+    return steps.astype(jnp.float32) * (bq * bk)
+
+
+def _pallas_prefill(q, k, v, sm_scale, window=None, sinks=None, n_valid=None,
+                    block_q=None, block_k=None, interpret=False):
     """Grid ``(B * Hk, n_q, band blocks)``; the online-softmax state lives in
     scratch, lane-replicated.  The products take the operands in their own
-    dtype (bf16 on the chip) and accumulate in float32."""
+    dtype (bf16 on the chip) and accumulate in float32.  ``n_valid`` is a
+    scalar prefetch the body reads."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -122,17 +158,15 @@ def _pallas_prefill(q, k, v, sm_scale, window=None, sinks=None, block_q=None,
     n_q = S // bq
     R = rep * bq
     parts = _parts(D)
+    if n_valid is None:
+        n_valid = jnp.full((B,), S, jnp.int32)
 
-    def first_block(i, maximum=jnp.maximum):
-        if window is None:
-            return 0
-        return maximum(i * bq - (window - 1), 0) // bk
-
-    def last_block(i):
-        return (i * bq + bq - 1) // bk
+    def band(i, maximum=jnp.maximum):
+        return _band(i, bq, bk, window, maximum)
 
     # key blocks a query block can meet: the whole causal range, or the band
-    n_steps = max(last_block(i) - first_block(i, max) + 1 for i in range(n_q))
+    n_steps = max(last - first + 1
+                  for first, last in (band(i, max) for i in range(n_q)))
 
     # rows of a program: (head of the group, position of the block)
     def rows(x):
@@ -149,12 +183,15 @@ def _pallas_prefill(q, k, v, sm_scale, window=None, sinks=None, block_q=None,
         sinks.astype(jnp.float32).reshape(hk, rep, 1, 1),
         (hk, rep, bq, 128)).reshape(hk, R, 128)
 
-    def kernel(*refs):
+    def kernel(nv_ref, *refs):
         n = len(parts)
         q_refs, k_refs = refs[:n], refs[n:2 * n]
         v_ref, sink_ref, o_ref, acc_ref, m_ref, l_ref = refs[2 * n:]
-        qi, j = pl.program_id(1), pl.program_id(2)
-        kb = first_block(qi) + j
+        g, qi, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+        first, last = band(qi)
+        kb = first + j
+        # a query block past the prompt's end is void: it computes nothing
+        live = qi * bq < nv_ref[g // hk]
 
         @pl.when(j == 0)
         def _init():
@@ -162,7 +199,7 @@ def _pallas_prefill(q, k, v, sm_scale, window=None, sinks=None, block_q=None,
             m_ref[...] = jnp.full_like(m_ref, NEG_INF)
             l_ref[...] = jnp.zeros_like(l_ref)
 
-        @pl.when(kb <= last_block(qi))
+        @pl.when(live & (kb <= last))
         def _step():
             # one MXU pass whatever the process's default precision
             dot = functools.partial(jax.lax.dot_general,
@@ -193,7 +230,7 @@ def _pallas_prefill(q, k, v, sm_scale, window=None, sinks=None, block_q=None,
             acc_ref[...] = acc_ref[...] * alpha[:, None] + dot(
                 p.astype(v_ref.dtype), v_ref[...], (((1,), (0,)), ((), ())))
 
-        @pl.when(j == n_steps - 1)
+        @pl.when(live & (j == n_steps - 1))
         def _finalize():
             m = jnp.max(m_ref[...], axis=1)
             l = jnp.max(l_ref[...], axis=1)
@@ -204,35 +241,50 @@ def _pallas_prefill(q, k, v, sm_scale, window=None, sinks=None, block_q=None,
             o_ref[...] = (acc_ref[...] * (keep / l_fin)[:, None]).astype(
                 o_ref.dtype)
 
-    def q_idx(g, i, j):
+        # zeros, not what VMEM holds: the next layer multiplies these rows'
+        # keys and values by a probability of 0, and 0 x NaN is NaN
+        @pl.when(jnp.logical_not(live) & (j == n_steps - 1))
+        def _void():
+            o_ref[...] = jnp.zeros_like(o_ref)
+
+    # The index maps do not read ``n_valid``: a void step still fetches its
+    # blocks.  Maps that repeat the last live block instead cost every step
+    # 0.17-0.23 us of scalar work, more than the fetches they save a full
+    # layer (PERF.md, PR 38).
+    def q_idx(g, i, j, nv_ref):
         return (g, i, 0, 0)
 
-    def kv_idx(g, i, j):
+    def kv_idx(g, i, j, nv_ref):
         # a repeated index elides the fetch of a block past the diagonal
-        return (g, jnp.minimum(first_block(i) + j, last_block(i)), 0)
+        first, last = band(i)
+        return (g, jnp.minimum(first + j, last), 0)
 
     assert bq & (bq - 1) == 0, "block_q must be a power of two"
     q_parts = [rows(q[..., lo:hi]) for lo, hi in parts]
     k_parts = [heads(k[..., lo:hi]) for lo, hi in parts]
     out = pl.pallas_call(
         kernel,
-        grid=(B * hk, n_q, n_steps),
-        in_specs=[pl.BlockSpec((None, None, R, hi - lo), q_idx)
-                  for lo, hi in parts]
-        + [pl.BlockSpec((None, bk, hi - lo), kv_idx) for lo, hi in parts]
-        + [pl.BlockSpec((None, bk, dv), kv_idx),
-           pl.BlockSpec((None, R, 128), lambda g, i, j: (g % hk, 0, 0))],
-        out_specs=pl.BlockSpec((None, None, R, dv), q_idx),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B * hk, n_q, n_steps),
+            in_specs=[pl.BlockSpec((None, None, R, hi - lo), q_idx)
+                      for lo, hi in parts]
+            + [pl.BlockSpec((None, bk, hi - lo), kv_idx) for lo, hi in parts]
+            + [pl.BlockSpec((None, bk, dv), kv_idx),
+               pl.BlockSpec((None, R, 128),
+                            lambda g, i, j, nv_ref: (g % hk, 0, 0))],
+            out_specs=pl.BlockSpec((None, None, R, dv), q_idx),
+            scratch_shapes=[pltpu.VMEM((R, dv), jnp.float32),
+                            pltpu.VMEM((R, 128), jnp.float32),
+                            pltpu.VMEM((R, 128), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((B * hk, n_q, R, dv), v.dtype),
-        scratch_shapes=[pltpu.VMEM((R, dv), jnp.float32),
-                        pltpu.VMEM((R, 128), jnp.float32),
-                        pltpu.VMEM((R, 128), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
         name="gqa_prefill_attn",
-    )(*q_parts, *k_parts, heads(v), sink_rows)
+    )(jnp.asarray(n_valid, jnp.int32), *q_parts, *k_parts, heads(v),
+      sink_rows)
     out = out.reshape(B, hk, n_q, rep, bq, dv).transpose(0, 2, 4, 1, 3, 5)
     return out.reshape(B, S, H, dv)
 
@@ -247,14 +299,27 @@ def _prefill_fits(q, k, v, window) -> bool:
 
 
 def gqa_prefill_attention(q, k, v, sm_scale, window=None, sinks=None,
-                          interpret=False):
+                          interpret=False, n_valid=None):
     """Causal attention of whole sequences from position 0.
 
     ``q [B, S, H, D]``, ``k [B, S, Hk, D]``, ``v [B, S, Hk, dv]``; query head
     ``g`` reads KV head ``g // (H / Hk)``.  ``window``: a query sees the
     ``window`` latest keys, itself included (None: all before it).  ``sinks
     [H]``: a bias a head that adds ``exp(b)`` to the softmax's denominator
-    and nothing to its numerator.  Returns ``[B, S, H, dv]``."""
+    and nothing to its numerator.  ``n_valid [B]`` (int32; None: ``S``): the
+    positions of each sequence that are real, the others its bucket's
+    padding.  Returns ``[B, S, H, dv]``.
+
+    The contract of ``n_valid``: rows before it are what they are without
+    it, bit for bit.  Rows at or past it are finite (given finite inputs)
+    and NOT TO BE READ: the kernel returns 0 for the query blocks that lie
+    wholly past it and computes the padded rows of the block it ends in as
+    before; the XLA reference returns 0 for every one of them.  A caller
+    may rely on "finite", on nothing else.  The padded positions' q, k and v
+    must be finite themselves: a padded key or value inside a key block
+    that real rows visit is multiplied by a probability of 0.  No step
+    carries a mask for ``n_valid``; every step that runs carries the causal
+    (and the band's) mask."""
     from . import use_pallas
 
     asked = interpret
@@ -266,9 +331,9 @@ def gqa_prefill_attention(q, k, v, sm_scale, window=None, sinks=None,
                          "kernel's blocks")
     if (use_pallas() or interpret) and ok:
         registry.ensure_admitted("gqa_prefill_attn")
-        return _pallas_prefill(q, k, v, sm_scale, window, sinks,
+        return _pallas_prefill(q, k, v, sm_scale, window, sinks, n_valid,
                                interpret=interpret)
-    return _prefill_reference(q, k, v, sm_scale, window, sinks)
+    return _prefill_reference(q, k, v, sm_scale, window, sinks, n_valid)
 
 
 # ---------------------------------------------------------------------------
@@ -589,7 +654,8 @@ def _prefill_shapes():
     sds = jax.ShapeDtypeStruct
     B, S, H, hk = 1, 256, 4, 2
     return (sds((B, S, H, 192), jnp.float32), sds((B, S, hk, 192), jnp.float32),
-            sds((B, S, hk, 128), jnp.float32), sds((H,), jnp.float32))
+            sds((B, S, hk, 128), jnp.float32), sds((H,), jnp.float32),
+            sds((B,), jnp.int32))
 
 
 def _decode_shapes():
@@ -603,12 +669,13 @@ def _decode_shapes():
 
 registry.register(
     "gqa_prefill_attn",
-    lambda: (lambda q, k, v, b: _pallas_prefill(q, k, v, 1.0, window=128,
-                                                sinks=b),
+    lambda: (lambda q, k, v, b, n: _pallas_prefill(q, k, v, 1.0, window=128,
+                                                   sinks=b, n_valid=n),
              _prefill_shapes()),
     presets=("serve",),
     description="causal flash forward, grouped heads a program, q/k wider "
-                "than v (two products), window band and sink")
+                "than v (two products), window band and sink; the query "
+                "blocks past the true length skipped")
 registry.register(
     "gqa_paged_decode",
     lambda: (lambda q, k, v, bt, ln: _pallas_paged_decode(q, k, v, bt, ln,

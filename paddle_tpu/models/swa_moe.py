@@ -36,7 +36,11 @@ only the logits at the last valid position are computed and the padded tail
 is routed nowhere.  ``counters`` (int32, in the cache): the eight of
 ``moe_serving.MOE_COUNTERS``, the rows routed to experts held elsewhere, and
 the (query, key) pairs / 1024 inside the mask of ONE full and of ONE window
-layer of the prompts a dense prefill attended.
+layer of the prompts a dense prefill attended, and the pairs / 1024 of the
+block steps ``gqa_prefill_attn`` ran for them (``_run_full``, ``_run_window``:
+inside / run is the share of the kernel's work that the mask keeps; the rest
+is the blocks' overhang at the diagonal, the band's edge and the prompt's
+end).
 
 There is no chunked prefill (a chunk's context is its blocks; a ring has
 none) and no backward.
@@ -67,7 +71,9 @@ __all__ = ["SwaMoeConfig", "SwaMoeForCausalLM", "swa_moe_tiny_config",
 # what ``counters`` counts, in order (``cache_spec()["counters"]``)
 COUNTERS = MOE_COUNTERS + ("moe.rows_elsewhere",
                            "attn.prefill_kilo_pairs_full",
-                           "attn.prefill_kilo_pairs_window")
+                           "attn.prefill_kilo_pairs_window",
+                           "attn.prefill_kilo_pairs_run_full",
+                           "attn.prefill_kilo_pairs_run_window")
 
 
 def _period(n: int) -> Tuple[int, ...]:
@@ -235,7 +241,7 @@ class SwaAttention(Layer):
                 o = gqa.gqa_prefill_attention(
                     q, k, v, scale,
                     window=c.sliding_window if self.window else None,
-                    sinks=sinks)
+                    sinks=sinks, n_valid=n_valid)
                 kept = (k, v)
                 if self.window:
                     n = jnp.full((B,), S) if n_valid is None else n_valid
@@ -421,8 +427,10 @@ class SwaMoeForCausalLM(Layer):
         if not paged:
             n = jnp.full((B,), S) if n_valid is None else n_valid
             pairs = jnp.stack([
-                jnp.sum(_pairs_in_mask(n)) / 1024,
-                jnp.sum(_pairs_in_mask(n, c.sliding_window)) / 1024])
+                jnp.sum(_pairs_in_mask(n)),
+                jnp.sum(_pairs_in_mask(n, c.sliding_window)),
+                jnp.sum(gqa.prefill_pairs_run(S, None, n)),
+                jnp.sum(gqa.prefill_pairs_run(S, c.sliding_window, n))]) / 1024
             return logits, {"kv": tuple(full), "window": tuple(rings),
                             "counters": jnp.concatenate(
                                 [moe_counts(counts, False), elsewhere,
@@ -433,4 +441,4 @@ class SwaMoeForCausalLM(Layer):
             "lengths": lengths + (lengths > 0).astype(lengths.dtype),
             "counters": _raw(cache["counters"]) + jnp.concatenate(
                 [moe_counts(counts, True), elsewhere,
-                 jnp.zeros((2,), jnp.int32)])}
+                 jnp.zeros((4,), jnp.int32)])}

@@ -276,20 +276,29 @@ def test_latent_decode_chunk_copies_no_pool(one_chip, kernel_branch):
 
 # 64 query heads over 4 (full) or 8 (window) KV heads, keys 192 and values
 # 128 wide; 32 slots, 4,608 blocks of 128 tokens, tables of 256 blocks
-@pytest.mark.parametrize("s", [1024, 16384])
+@pytest.mark.parametrize("s,lengths", [
+    pytest.param(1024, False, id="1024"),
+    pytest.param(16384, False, id="16384"),
+    pytest.param(8192, True, id="8192-n_valid")])
 @pytest.mark.parametrize("window,hk", [(None, 4), (128, 8)],
                          ids=["full", "window"])
-def test_gqa_prefill_attention(one_chip, kernel_branch, window, hk, s):
+def test_gqa_prefill_attention(one_chip, kernel_branch, window, hk, s,
+                               lengths):
     """Both layer kinds' prefill at the cell's shortest and longest bucket:
     64 query heads in groups of 16 or 8 a program, the score as a 64-wide and
-    a 128-wide product, the window's band of two key blocks."""
+    a 128-wide product, the window's band of two key blocks; and with the
+    prompt's true length as a scalar prefetch the body reads."""
     from paddle_tpu.kernels import gqa_attention
 
-    text = _compile(
-        one_chip, lambda q, k, v, b: gqa_attention.gqa_prefill_attention(
-            q, k, v, 0.07, window=window, sinks=b if window else None),
-        ((1, s, 64, 192), BF16), ((1, s, hk, 192), BF16),
-        ((1, s, hk, 128), BF16), ((64,), F32))
+    def attend(q, k, v, b, n=None):
+        return gqa_attention.gqa_prefill_attention(
+            q, k, v, 0.07, window=window, sinks=b if window else None,
+            n_valid=n)
+
+    shapes = [((1, s, 64, 192), BF16), ((1, s, hk, 192), BF16),
+              ((1, s, hk, 128), BF16), ((64,), F32)]
+    text = _compile(one_chip, attend, *shapes,
+                    *([((1,), I32)] if lengths else []))
     assert "gqa_prefill_attn" in text.as_text()
 
 

@@ -6,7 +6,8 @@ the CPU at a tiny size:
     decode through the cache past twice the window;
 (b) through ``serving.Engine``: mixed requests, chunks of 1-32 steps, an
     evicted and re-admitted request;
-(c) each kernel in interpret mode against its XLA reference;
+(c) each kernel in interpret mode against its XLA reference; the prefill
+    kernel with the prompts' true lengths (the void query blocks);
 (d) the shares of an expert layer add up to the uncut layer;
 and the spans, counters, gauges and the cell's rehearsal.
 """
@@ -272,6 +273,109 @@ def test_prefill_kernel_streams_key_blocks_and_bounds_the_band():
                                   interpret=True)
 
 
+# true lengths of four prompts in a 1,024-position bucket: inside a query
+# block (and past the first key block of a full layer), on a block's edge,
+# the whole bucket, one token
+N_VALID = (600, 512, 1024, 1)
+
+
+@pytest.mark.parametrize("hk,sinks", [(4, False), (8, True)])
+@pytest.mark.parametrize("window", [None, 128, 200])
+def test_prefill_kernel_skips_the_padding(window, hk, sinks):
+    """``n_valid`` a prompt: the rows of every query block that holds a
+    real position are the kernel's own ``n_valid=None`` rows bit for bit
+    (the same operations in the same order), the real rows are the
+    reference's of the UNPADDED prompt, the query blocks past the prompt's
+    end come back exactly 0; the reference zeroes every row at or past
+    ``n_valid``."""
+    q, k, v, b = _qkv(hk, 1024, 8, hk, B=len(N_VALID))
+    b = b if sinks else None
+    n_valid = jnp.asarray(N_VALID, jnp.int32)
+    with jax.default_matmul_precision("highest"):
+        whole = np.asarray(gqa.gqa_prefill_attention(
+            q, k, v, 0.07, window, b, interpret=True))
+        got = np.asarray(gqa.gqa_prefill_attention(
+            q, k, v, 0.07, window, b, interpret=True, n_valid=n_valid))
+        ref = np.asarray(gqa._prefill_reference(q, k, v, 0.07, window, b,
+                                                n_valid))
+        for i, n in enumerate(N_VALID):
+            live = -(-n // 128) * 128
+            assert np.array_equal(got[i, :live], whole[i, :live]), n
+            assert not got[i, live:].any() and not ref[i, n:].any(), n
+            want = np.asarray(gqa._prefill_reference(
+                q[i:i + 1, :n], k[i:i + 1, :n], v[i:i + 1, :n], 0.07, window,
+                b))[0]
+            assert np.abs(got[i, :n] - want).max() < TOL, n
+            assert np.abs(ref[i, :n] - want).max() < TOL, n
+
+
+@pytest.mark.parametrize("window", [None, 128])
+def test_prefill_kernel_reads_no_padding_into_a_real_row(window):
+    """NaN in the padded positions' q and k, and in their v from the end of
+    the last key block a real row visits: the real rows are finite and
+    unchanged.  (A padded VALUE inside a visited key block is multiplied by
+    a probability of 0 and has to be finite: that is why a void query block
+    stores zeros for the next layer, and what the docstring asks of a
+    caller.)"""
+    q, k, v, b = _qkv(2, 1024, 8, 4, B=len(N_VALID))
+    n_valid = jnp.asarray(N_VALID, jnp.int32)
+    bq, bk = gqa._prefill_blocks(1024, window)
+    pos = np.arange(1024)[None, :]
+    lens = np.asarray(N_VALID)[:, None]
+    padded = pos >= lens
+    # the end of the diagonal block of the last query block that holds a
+    # real position
+    visited = (gqa._band((lens - 1) // bq, bq, bk, window, np.maximum)[1]
+               + 1) * bk
+    nan = lambda x, where: jnp.where(                             # noqa: E731
+        jnp.asarray(where)[:, :, None, None], jnp.nan, x)
+    with jax.default_matmul_precision("highest"):
+        want = np.asarray(gqa.gqa_prefill_attention(
+            q, k, v, 0.07, window, b, interpret=True, n_valid=n_valid))
+        got = np.asarray(gqa.gqa_prefill_attention(
+            nan(q, padded), nan(k, padded), nan(v, pos >= visited), 0.07,
+            window, b, interpret=True, n_valid=n_valid))
+    for i, n in enumerate(N_VALID):
+        assert np.isfinite(got[i, :n]).all(), n
+        assert np.array_equal(got[i, :n], want[i, :n]), n
+        assert not got[i, -(-n // bq) * bq:].any(), n
+
+
+@pytest.mark.parametrize("window", [None, 128, 200, 700])
+def test_prefill_steps_run_where_the_mask_and_the_prompt_meet(window):
+    """The grid steps of a 1,024-position call, walked with the helper the
+    kernel walks them with, against the mask itself: a step runs exactly
+    where some pair of its blocks is inside the mask and its query block
+    holds a real position; and against the closed forms: a full layer (128
+    x 512) runs ``i // 4 + 1`` steps a live query block, a band of two
+    128-blocks one step for the first query block and two for the others;
+    the counter's pairs are these steps'."""
+    S = 1024
+    bq, bk = gqa._prefill_blocks(S, window)
+    i, j = np.arange(S)[:, None], np.arange(S)[None, :]
+    seen = (j <= i) if window is None else (j <= i) & (i - j < window)
+    for n in (S,) + N_VALID:
+        steps = 0
+        for qi in range(S // bq):
+            first, last = gqa._band(qi, bq, bk, window)
+            for kb in range(S // bk):
+                block = seen[qi * bq:(qi + 1) * bq, kb * bk:(kb + 1) * bk]
+                runs = qi * bq < n and first <= kb <= last
+                assert runs == (qi * bq < n and bool(block.any()))
+                steps += runs
+        live = -(-n // bq)
+        if window is None:
+            assert steps == sum(q // 4 + 1 for q in range(live))
+        elif window == 128:
+            assert steps == 2 * live - 1
+        assert float(gqa.prefill_pairs_run(S, window, jnp.asarray([n]))[0]) \
+            == steps * bq * bk
+    assert steps == 1                                     # one token
+    # the cell's bucket of 8,192: 544 steps a KV head
+    assert gqa.prefill_pairs_run(8192, None, jnp.asarray([8192]))[0] \
+        == 544 * 128 * 512
+
+
 def _filled_pools(seed, lens, hk, bs=32, nb=24, maxb=6):
     """Pools holding ``lens[b]`` tokens of slot ``b``, written a block at a
     time, and what was written in token order."""
@@ -497,7 +601,7 @@ def test_spans_counters_and_gauges_of_a_served_request():
             "serve.readback", "cache.counters"} <= {e["name"] for e in events}
     snap = obs.registry().snapshot()
     layers, k = model.n_expert_layers, cfg.num_experts_per_tok
-    assert (layers, len(COUNTERS)) == (6, 11)
+    assert (layers, len(COUNTERS)) == (6, 13)
     assert snap["moe.steps"]["value"] == 8
     assert snap["moe.prefill_calls"]["value"] == 1
     # every routed row is held here or elsewhere
@@ -540,6 +644,54 @@ def test_pairs_inside_the_mask_are_counted_by_layer_kind():
     assert got["attn.prefill_kilo_pairs_window"] == (
         128 * 129 // 2 + (1500 - 128) * 128) // 1024
     assert got["moe.prefill_calls"] == 1 and got["moe.steps"] == 0
+
+
+@pytest.mark.parametrize("kernels,n,bucket", [
+    ("xla", 300, 512), ("xla", 600, 1024), ("xla", 1000, 1024),
+    ("pallas_interpret", 130, 256)])
+def test_padded_prompt_attends_its_true_length(kernels, n, bucket, request):
+    """A prompt padded to its bucket through ``forward``: the logits at
+    ``n_valid - 1``, the full layers' K/V of the real positions and the
+    rings' rows are the unpadded prompt's (rows past ``n_valid`` come back
+    0 from attention and feed nothing real), and the counters read the
+    pairs of the block steps ``gqa_prefill_attn`` runs for it: the query
+    blocks of 128 that hold a real position, against key blocks of 512
+    (full: ``i // 4 + 1`` a query block) or 128 (window: two a query block,
+    one for the first)."""
+    if kernels == "pallas_interpret":
+        request.getfixturevalue("interpret_kernels")
+        model = _model(5, **KERNEL_WIDTHS)
+    else:
+        model = _model(3, max_position_embeddings=4096, sliding_window=128,
+                       num_hidden_layers=2)
+    be = make_backend(model.cache_spec(), 4, 128, 1)
+    ids = np.random.default_rng(n).integers(1, 512, size=(1, bucket)).astype(
+        np.int32)
+
+    def prefill(tokens, n_valid):
+        cache = be.prefill_cache(model.init_cache(1, tokens.shape[1]),
+                                 jnp.asarray([n_valid], jnp.int32))
+        return model(jnp.asarray(tokens), cache=cache)
+
+    with jax.default_matmul_precision("highest"):
+        logits, new = prefill(ids, n)
+        want_logits, want = prefill(ids[:, :n], n)
+    assert np.abs(np.asarray(logits._data) - np.asarray(want_logits._data)
+                  ).max() < TOL
+    for got_kv, want_kv in zip(new["kv"], want["kv"]):
+        for a, b in zip(got_kv, want_kv):
+            assert np.abs(np.asarray(a)[:, :n] - np.asarray(b)).max() < TOL
+    for got_ring, want_ring in zip(new["window"], want["window"]):
+        for name in ("k", "v"):
+            assert np.abs(np.asarray(got_ring[name])
+                          - np.asarray(want_ring[name])).max() < TOL
+    got = dict(zip(COUNTERS, np.asarray(new["counters"])))
+    live = -(-n // 128)
+    assert got["attn.prefill_kilo_pairs_run_full"] == sum(
+        i // (min(bucket, 512) // 128) + 1 for i in range(live)) \
+        * 128 * min(bucket, 512) // 1024
+    assert got["attn.prefill_kilo_pairs_run_window"] == (2 * live - 1) * 16
+    assert got["attn.prefill_kilo_pairs_full"] == n * (n + 1) // 2 // 1024
 
 
 # ------------------------------------------- the cell's limit, in bfloat16 --

@@ -41,19 +41,27 @@ name                         producer / meaning
                              prefill(-chunk)→first-token→decode-round→emitted
 ``serve.step``               span: one ``Engine.step()``
 ``serve.admit``              span: ``_admit()`` (args admitted, waiting)
-``serve.prefill`` etc.       spans: the jitted call only (``serve.prefill``,
-                             ``serve.prefill-chunk``, ``serve.decode-chunk``)
+``serve.prefill`` etc.       spans: the jitted call only (``serve.prefill``
+                             args bucket, n, tokens; ``serve.prefill-chunk``
+                             args bucket, final, tokens; ``serve.decode-chunk``)
 ``serve.dispatch``           span: all of ``_dispatch_chunk`` (args k, staged,
-                             live): staging, the call, the ledger
+                             live, kept, width): staging, the call, the ledger
 ``serve.readback``           span: the host blocked reading the token buffers
 ``serve.absorb``             span: the ledger walk and emits (args tokens,
                              finished)
 ``train.step``               span: one ``TrainStep.__call__``
 ``serve.queue_depth``        gauge {replica}: waiting requests after a round
-``serve.batch_occupancy``    gauge {replica}: live decode slots / max_batch
 ``serve.requests``           counter {replica}: requests emitted
 ``serve.prefix_hit_blocks``  counter {replica}: prompt blocks served from cache
-``serve.prefill_tokens``     counter {replica}: prompt tokens prefilled
+``serve.prefill_rows``       counter {use,replica}: rows the prefill programs
+                             ran: ``prompt`` (a prompt token) or ``pad`` (the
+                             bucket's padding)
+``serve.decode_slot_steps``  counter {use,replica}: a decode chunk's k x
+                             max_batch slot-steps: ``kept`` (inside a request's
+                             budget), ``tail`` (run past it to the chunk's
+                             end), ``prefilling`` (slot mid-chunked-prefill),
+                             ``empty`` (no request); ``cut`` (after an eos,
+                             moved from ``kept`` when the tokens are read)
 ``serve.decode_gap_ms``      histogram {replica}: decode-visible gap per chunk
 ``serve.ttft_ms``            histogram {replica}: queued→first token on the host
 ``serve.queue_wait_ms``      histogram {replica}: queued→dispatch of the first

@@ -147,6 +147,9 @@ class Registry:
         self._lock = threading.Lock()
         # name -> label_key -> (labels dict, instrument)
         self._families: Dict[str, Dict[str, tuple]] = {}
+        # bumped by reset(): a caller that holds instruments across calls
+        # fetches them again when it moves
+        self.generation = 0
 
     def _get(self, kind, name: str, labels: Mapping[str, object],
              factory):
@@ -219,6 +222,7 @@ class Registry:
     def reset(self) -> None:
         with self._lock:
             self._families = {}
+            self.generation += 1
 
 
 _registry = Registry()

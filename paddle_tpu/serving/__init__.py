@@ -103,6 +103,11 @@ NEG_INF = -1e30
 # 16 gives 431-489 ms for 2-4%: the shorter one that keeps TPOT within 5%.
 K_SHORT = 16
 
+# What a decode chunk's slot-steps (``serve.decode_slot_steps``) and a
+# prefill call's rows (``serve.prefill_rows``) were spent on
+_SLOT_USES = ("kept", "tail", "prefilling", "empty", "cut")
+_ROW_USES = ("prompt", "pad")
+
 
 def prefix_block_hashes(ids, block_size: int) -> List[bytes]:
     """Chain hashes of the FULL blocks of ``ids[:-1]`` — the cacheable
@@ -326,6 +331,8 @@ class Engine:
         # observability: the router stamps a replica id so registry
         # families split per replica; standalone engines stay unlabeled
         self.obs_replica: Optional[int] = None
+        # {family: ((registry generation, replica), {use: Counter})}
+        self._use_ctrs: Dict[str, tuple] = {}
 
     # -- observability -------------------------------------------------------
 
@@ -333,6 +340,24 @@ class Engine:
         if self.obs_replica is None:
             return {}
         return {"replica": self.obs_replica}
+
+    def _use_counters(self, name: str, uses: Tuple[str, ...]) -> dict:
+        """``{use: Counter}`` of the family ``name{use, replica}``, fetched
+        from the registry once per label set (again after a reset or a new
+        replica label), so a chunk pays adds and no lookups."""
+        reg = obs.registry()
+        key = (reg.generation, self.obs_replica)
+        got = self._use_ctrs.get(name)
+        if got is None or got[0] != key:
+            got = self._use_ctrs[name] = (key, {
+                u: reg.counter(name, use=u, **self._obs_labels())
+                for u in uses})
+        return got[1]
+
+    def _count_prefill_rows(self, rows: int, tokens: int) -> None:
+        c = self._use_counters("serve.prefill_rows", _ROW_USES)
+        c["prompt"].inc(tokens)
+        c["pad"].inc(rows - tokens)
 
     def _obs_mark(self, req: GenRequest, phase: str, **args) -> None:
         """Phase mark on the request's lifecycle chain.  Tracing-only
@@ -479,12 +504,8 @@ class Engine:
         with obs.span("serve.step", cat="serve"):
             self._round(streaming=True)
             self._sync_pending()
-            reg = obs.registry()
-            lbl = self._obs_labels()
-            reg.gauge("serve.queue_depth", **lbl).set(len(self._waiting))
-            reg.gauge("serve.batch_occupancy", **lbl).set(
-                sum(1 for s in self._slots if s.req is not None)
-                / max(1, self.max_batch))
+            obs.registry().gauge("serve.queue_depth",
+                                 **self._obs_labels()).set(len(self._waiting))
             return self._drain_ready()
 
     def token_counts(self) -> Dict[str, int]:
@@ -773,7 +794,7 @@ class Engine:
         t0 = time.perf_counter()
         self._obs_dispatched(req, t0)
         with obs.span("serve.prefill-chunk", cat="serve",
-                      args={"bucket": Cb, "final": final}):
+                      args={"bucket": Cb, "final": final, "tokens": take}):
             self._first_buf, self._last_dev, self.backend.device = fn(
                 self._params, self._buffers, self.backend.device,
                 self._last_dev, jnp.asarray(slot.idx, jnp.int32),
@@ -793,8 +814,7 @@ class Engine:
         self.stats["prefill_time"] += dt
         self.stats["prefill_tokens"] += Cb
         self.stats["chunk_prefills"] += 1
-        obs.registry().counter(
-            "serve.prefill_tokens", **self._obs_labels()).inc(Cb)
+        self._count_prefill_rows(Cb, take)
         if final:
             slot.out_count = 1
             self._pending.append(
@@ -973,11 +993,12 @@ class Engine:
             self._first_idx = 0
         fidx0 = self._first_idx
         self._first_idx += n
+        tokens = int(P.sum())
         for _slot, req, *_rest in group:
             self._obs_mark(req, "prefill", bucket=Pb, batch=n)
         t0 = time.perf_counter()
         with obs.span("serve.prefill", cat="serve",
-                      args={"bucket": Pb, "n": n}):
+                      args={"bucket": Pb, "n": n, "tokens": tokens}):
             self._first_buf, self._last_dev, self.backend.device = fn(
                 self._params, self._buffers, self.backend.device,
                 self._last_dev, jnp.asarray(sidx), jnp.asarray(ids),
@@ -994,8 +1015,7 @@ class Engine:
         self.stats["prefill_time"] += dt
         self.stats["prefill_tokens"] += n * Pb
         self.stats["generated_tokens"] += n
-        obs.registry().counter(
-            "serve.prefill_tokens", **self._obs_labels()).inc(n * Pb)
+        self._count_prefill_rows(n * Pb, tokens)
 
     def _build_prefill(self, Pb: int, n: int):
         from ..jit import functional_call
@@ -1022,8 +1042,9 @@ class Engine:
 
     def _dispatch_chunk(self, k: int):
         with obs.span("serve.dispatch", cat="serve") as sp:
-            staged, live = self._dispatch_decode(k)
-            sp.set(k=k, staged=staged, live=live)
+            staged, live, kept = self._dispatch_decode(k)
+            sp.set(k=k, staged=staged, live=live, kept=kept,
+                   width=self.max_batch)
 
     def _dispatch_decode(self, k: int):
         """Dispatch one k-sub-step decode chunk asynchronously and account
@@ -1031,7 +1052,13 @@ class Engine:
         finishes (a finish frees its blocks NOW — the chunk's garbage tail
         writes land before any later prefill reuses them, because device
         execution preserves dispatch order).  Returns whether the staged
-        scheduler arrays were reused and how many slots decoded."""
+        scheduler arrays were reused, how many slots decoded and how many of
+        their slot-steps fall inside their requests' budgets.
+
+        The chunk's ``k x max_batch`` slot-steps are counted by use
+        (``serve.decode_slot_steps``): ``kept`` inside a request's budget,
+        ``tail`` run past it to the chunk's end, ``prefilling`` in a slot
+        mid-chunked-prefill, ``empty`` in a slot with no request."""
         from ..framework import random as rnd
 
         # slots mid-chunked-prefill are NOT decoded: masked inactive
@@ -1102,10 +1129,15 @@ class Engine:
         self.stats["decode_steps"] += k
         self.stats["decode_calls"] += 1
         recs = []
+        kept = prefilling = 0
         for s in self._slots:
-            if s.req is None or s.prefill_left is not None:
+            if s.req is None:
+                continue
+            if s.prefill_left is not None:
+                prefilling += 1
                 continue
             take = min(k, s.req.max_new_tokens - s.out_count)
+            kept += take
             recs.append((s.req, s.idx, take))
             s.out_count += take
             s.length += k
@@ -1116,7 +1148,13 @@ class Engine:
                 self._release(s)
         self._pending.append(
             ("chunk", len(self._full_tok_bufs), row0, k, recs))
-        return staged, len(recs)
+        live = len(recs)
+        c = self._use_counters("serve.decode_slot_steps", _SLOT_USES)
+        c["kept"].inc(kept)
+        c["tail"].inc(k * live - kept)
+        c["prefilling"].inc(k * prefilling)
+        c["empty"].inc(k * (self.max_batch - live - prefilling))
+        return staged, live, kept
 
     def _build_decode(self, k: int):
         from ..jit import functional_call
@@ -1258,19 +1296,26 @@ class Engine:
                 self.backend.read_counters(**self._obs_labels())
             t_read = time.perf_counter()
         with obs.span("serve.absorb", cat="serve") as sp:
-            n_tok, n_ready = 0, len(self._ready)
+            n_tok, n_ready, cut = 0, len(self._ready), 0
             for e in self._pending:
                 if e[0] == "prefill":
                     _, req, seg, fidx = e
                     self._obs_first_token(req, t_read)
-                    self._absorb(req, [int(first_segs[seg][fidx])])
+                    self.stats["generated_tokens"] -= self._absorb(
+                        req, [int(first_segs[seg][fidx])])
                     n_tok += 1
                 else:
                     _, seg, row0, kk, recs = e
                     rows = tok_segs[seg][row0:row0 + kk]
                     for req, idx, take in recs:
-                        self._absorb(req, rows[:take, idx].tolist())
+                        cut += self._absorb(req, rows[:take, idx].tolist())
                         n_tok += take
+            if cut:
+                # counted at DISPATCH as output tokens and kept slot-steps
+                self.stats["generated_tokens"] -= cut
+                c = self._use_counters("serve.decode_slot_steps", _SLOT_USES)
+                c["kept"].inc(-cut)
+                c["cut"].inc(cut)
             if self._pending:
                 self._pending.clear()
                 self._full_tok_bufs.clear()
@@ -1285,29 +1330,29 @@ class Engine:
             self._finish_order.clear()
             sp.set(tokens=n_tok, finished=len(self._ready) - n_ready)
 
-    def _absorb(self, req: GenRequest, vals: List[int]):
+    def _absorb(self, req: GenRequest, vals: List[int]) -> int:
         """Append materialized tokens to a request, cutting at eos (the
         cut releases the slot if the request still owns one and emits the
         stop output; later ledger cells for the request are ignored).
 
-        ``generated_tokens``/``decode_steps`` were counted at DISPATCH time
-        (one per ledger cell), assuming every cell becomes an output token —
-        cells discarded here (the eos itself and everything after the cut)
-        are un-counted so throughput stats equal emitted ``output_ids``."""
+        Returns how many of ``vals`` it discarded (the eos itself and
+        everything after the cut): ``generated_tokens`` and the kept
+        slot-steps counted them at DISPATCH time, one per ledger cell, and
+        the caller un-counts them so the stats equal emitted
+        ``output_ids``."""
         for i, tok in enumerate(vals):
             if req._stopped or req._emitted:
-                self.stats["generated_tokens"] -= len(vals) - i
-                return
+                return len(vals) - i
             if req.eos_token_id is not None and tok == req.eos_token_id:
                 req._stopped = True
-                self.stats["generated_tokens"] -= len(vals) - i
                 for s in self._slots:
                     if s.req is req:
                         self._release(s)
                         break
                 self._ready.append(self._emit(req, "stop"))
-                return
+                return len(vals) - i
             req._out_vals.append(tok)
+        return 0
 
     def _emit(self, req: GenRequest, reason: str) -> RequestOutput:
         req._emitted = True

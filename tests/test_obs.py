@@ -485,12 +485,16 @@ class TestServingLifecycle:
             eng.step()
         snap = obs.registry().snapshot()
         assert snap["serve.requests"]["value"] == 2
-        assert snap["serve.prefill_tokens"]["value"] > 0
+        assert snap["serve.prefill_rows{use=prompt}"]["value"] == 20 + 45
+        assert snap["serve.prefill_rows{use=pad}"]["value"] > 0
         assert snap["serve.ttft_ms"]["count"] == 2
         assert "serve.queue_depth" in snap
-        assert "serve.batch_occupancy" in snap
+        assert snap["serve.decode_slot_steps{use=kept}"]["value"] == 2 * 3
+        assert "serve.decode_slot_steps{use=empty}" in snap
         # unlabeled: engine not owned by a router
         assert snap["serve.requests"]["labels"] == {}
+        assert snap["serve.prefill_rows{use=pad}"]["labels"] == {
+            "use": "pad"}
 
     def test_router_failover_chain_exactly_once(self, tiny_model):
         """The rid's chain spans the failover: one begin (router submit),

@@ -14,7 +14,9 @@ from benchmarks.tracered import Device, Reduced
 OFFSET = 12.345678            # trace clock - program clock, seconds
 NEW = ("engine_queue_wait_p50_ms", "engine_first_token_p50_ms",
        "engine_blocked_share", "engine_host_ms_per_step",
-       "train_dispatch_ms", "engine_warmup_s")
+       "train_dispatch_ms", "engine_warmup_s", "decode_slot_use_share",
+       "decode_slot_empty_share", "prefill_row_use_share")
+SLOT_SHARES = NEW[-3:]
 
 
 def _x(name, t0, t1, tid=7, **args):
@@ -155,6 +157,49 @@ def test_readers_on_a_tied_run(monkeypatch):
     assert read("engine_host_ms_per_step")(red, {}) == pytest.approx(4.0)
     # a training reader finds no train.step here: missing, not wrong
     assert read("train_dispatch_ms")(red, {}) is None
+
+
+def _with_slot_args(events, parent=False):
+    """Each call of ``_run()`` gains a prefill of two prompts (100 and 60
+    tokens) in a bucket of 128 and a final chunk of 200 tokens in one of
+    256; each chunk of k = 32 runs over 4 slots, 3 of them live, 80 of
+    their 96 slot-steps kept.  ``parent``: the spans as PR 38 recorded
+    them, without ``kept``, ``width`` and ``tokens``."""
+    out = []
+    for e in events:
+        if e["name"] == "serve.dispatch" and not parent:
+            e = {**e, "args": {**e["args"], "width": 4, "live": 3,
+                               "kept": 80}}
+        out.append(e)
+        if e["name"] == "serve.admit":
+            t0 = e["ts"] * 1e-6
+            tok = ({}, {}) if parent else ({"tokens": 160}, {"tokens": 200})
+            out.append(_x("serve.prefill", t0 + 2e-4, t0 + 5e-4,
+                          bucket=128, n=2, **tok[0]))
+            out.append(_x("serve.prefill-chunk", t0 + 5e-4, t0 + 8e-4,
+                          bucket=256, final=True, **tok[1]))
+    return out
+
+
+@pytest.mark.parametrize("parent", [False, True])
+def test_slot_and_row_shares_on_a_tied_run(monkeypatch, capsys, parent):
+    red, events = _run()
+    events = _with_slot_args(events, parent)
+    monkeypatch.setattr(ps, "_last", (red, ps.tie_events(red, events)))
+    got = {n: harness.layer_reader(n)(red, {"decode_steps_marked": 160})
+           for n in SLOT_SHARES}
+    if parent:          # the parent's spans carry no such args: nothing
+        assert got == dict.fromkeys(SLOT_SHARES)
+        return
+    # 5 chunks of 32 x 4 slot-steps: 5 x 80 kept, 5 x 32 in the empty slot
+    assert got["decode_slot_use_share"] == pytest.approx(400 / 640)
+    assert got["decode_slot_empty_share"] == pytest.approx(160 / 640)
+    # 5 x (160 + 200) prompt tokens in 5 x (2 x 128 + 256) rows
+    assert got["prefill_row_use_share"] == pytest.approx(1800 / 2560)
+    said = [json.loads(line) for line in capsys.readouterr().out.splitlines()]
+    decode = next(s for s in said if "decode_slot_steps" in s)
+    assert decode["decode_slot_steps"]["steps"] == 160
+    assert decode["decode_steps_marked"] == 160
 
 
 @pytest.mark.parametrize("name", NEW)

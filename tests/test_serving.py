@@ -5,6 +5,8 @@ attention) + the inference product's dynamic batching. Greedy outputs must
 be bit-identical to ``model.generate`` regardless of batching, admission
 order, or eviction."""
 
+import itertools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -789,6 +791,9 @@ _CHUNK_POLICY = {
 }
 
 
+_chunk_policy_labels = itertools.count()
+
+
 @pytest.mark.parametrize("case", _CHUNK_POLICY.values(),
                          ids=_CHUNK_POLICY.keys())
 def test_chunk_length_follows_the_schedulers_state(model, case):
@@ -799,7 +804,9 @@ def test_chunk_length_follows_the_schedulers_state(model, case):
     assert K_SHORT < 32                   # else no case tells the rules apart
     eng = Engine(model, max_batch=max_batch, num_blocks=num_blocks,
                  block_size=128, prefill_buckets=(128, 256))
-    eng.obs_replica = f"chunk-policy-{id(eng)}"
+    # a label no other case's engine had (an id() is reused once the last
+    # case's engine is freed, and the registry keeps its counts)
+    eng.obs_replica = f"chunk-policy-{next(_chunk_policy_labels)}"
     prompts = _prompts(model.config, [p for p, _ in reqs], seed=41)
     for p, (_, m) in zip(prompts, reqs):
         eng.add_request(GenRequest(prompt_ids=p, max_new_tokens=m))

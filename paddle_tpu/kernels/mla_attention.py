@@ -16,7 +16,8 @@ boundary.  Block 0 is the trash block (``serving.Engine``'s convention).
   (``q_lat = q_nope W_kvb,k^T``): scores are ``q_lat c^T + q_rope k_r^T``, the
   output ``(P c)`` is still in latent space and the caller applies
   ``W_kvb,v``.  The kernel ``mla_paged_decode`` streams exactly the live
-  blocks of a slot, one DMA a block for all heads.
+  blocks of the batch, one DMA a block for all heads, through one ring of
+  buffers that does not drain at a slot's edge.
 - **Prefill** expands ``k_nope`` and ``v`` from ``c`` and runs causal
   attention with q/k width ``nope + rope`` and v width ``v_dim``.  The
   kernel ``mla_prefill_attn`` is the streaming flash forward with the score
@@ -36,9 +37,15 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from . import registry
 
 NEG_INF = -1e30
+# latent blocks the decode kernel keeps in VMEM (at least 3): RING - 2 in
+# flight while two are in use, one scored and one attended (a block of 128
+# tokens is 147 KB in bf16).  Depths 3 to 8 ran alike on the chip: the body,
+# not the DMA, bounds a block (PERF.md section 6)
+RING = 4
 
 
 def _flag_interpret() -> bool:
@@ -151,14 +158,37 @@ def _decode_reference(q_lat, q_rope, pool, block_table, lengths, sm_scale):
     return (o * (lengths > 0)[:, None, None]).astype(q_lat.dtype)
 
 
+def _work_list(block_table, lengths, bs: int):
+    """The call's live blocks as one list, slot after slot, empty slots
+    skipped: ``(flat, ends)`` where ``flat [B * MAXB]`` holds the physical
+    block ids (entries past the last live block are unused) and ``ends[b]``
+    is one past slot ``b``'s last item, so that slot's items are
+    ``ends[b] - n_live[b] .. ends[b]``."""
+    B, maxb = block_table.shape
+    n_live = jnp.minimum((lengths + bs - 1) // bs, maxb)
+    ends = jnp.cumsum(n_live).astype(jnp.int32)
+    g = jnp.arange(B * maxb, dtype=jnp.int32)
+    owner = jnp.minimum(jnp.sum(g[:, None] >= ends[None, :], axis=1), B - 1)
+    j = jnp.clip(g - (ends - n_live)[owner], 0, maxb - 1)
+    return block_table[owner, j], ends
+
+
 def _pallas_decode(q_lat, q_rope, pool, block_table, lengths, sm_scale,
-                   interpret=False):
-    """Grid ``(B,)``; per slot a double-buffered DMA of each LIVE block
-    (table and lengths scalar-prefetched), online softmax for all heads at
-    once, the block's even tokens and then its odd ones: HBM reads are the
-    live tokens' latent rows, once.  The rotated query comes twice, padded
-    to the pair's 2 * rope lanes on either side, so that no 64-lane slice of
-    the block is taken."""
+                   interpret=False, ring=None):
+    """One program for the whole batch: the call's live blocks are one work
+    list (``_work_list``, scalar-prefetched) streamed through a ring of
+    ``ring`` VMEM buffers.  Waiting for item ``g`` starts item ``g + ring -
+    2`` whatever slot either belongs to, so only the call's first blocks
+    wait on HBM.  Per slot, online softmax for all heads at once over a
+    block's ``bs`` tokens in one set of products (bf16 operands on the chip,
+    float32 sums): even tokens are the block's rows, odd tokens the same
+    rows' second half, stacked ``[c_even; c_odd]``; the rotated keys take a
+    2 * rope-lane tile masked to each half, so no 64-lane slice is taken.
+    A step computes the next block's scores beside this block's softmax and
+    value product (a block's own chain, scores -> cross-lane max -> values,
+    is mostly MXU and reduction latency: PERF.md section 6).  Every
+    loop is a ``fori_loop``: the kernel's code does not grow with the batch
+    or the table's width."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -167,86 +197,136 @@ def _pallas_decode(q_lat, q_rope, pool, block_table, lengths, sm_scale,
     nb, half, w2 = pool.shape
     bs = 2 * half
     maxb = block_table.shape[1]
-    zero = jnp.zeros_like(q_rope)
-    q_pair = jnp.stack([jnp.concatenate([q_rope, zero], -1),
-                        jnp.concatenate([zero, q_rope], -1)], axis=1)
+    ring = ring or RING
+    flat, ends = _work_list(block_table, lengths, bs)
+    q_two = jnp.concatenate([q_rope, q_rope], -1)          # [B, H, 2 rope]
+    dot = functools.partial(jax.lax.dot_general,
+                            precision=jax.lax.Precision.DEFAULT,
+                            preferred_element_type=jnp.float32)
+    rows_t = (((1,), (1,)), ((), ()))                      # a @ b.T
 
-    def kernel(tbl_ref, len_ref, ql_ref, qr_ref, pool_hbm, o_ref, buf, sems):
-        b = pl.program_id(0)
-        L = len_ref[b]
-        n_live = jnp.minimum((L + bs - 1) // bs, maxb)
-        ql = ql_ref[0].astype(jnp.float32)                 # [H, rank]
-        qr = qr_ref[0].astype(jnp.float32)                 # [2, H, 2 rope]
+    def kernel(flat_ref, ends_ref, len_ref, ql_ref, qr_ref, pool_hbm, o_ref,
+               buf, sems):
+        total = ends_ref[B - 1]
 
-        def copy(slot, j):
-            return pltpu.make_async_copy(pool_hbm.at[tbl_ref[b, j]],
-                                         buf.at[slot], sems.at[slot])
+        def copy(g):
+            r = jax.lax.rem(g, ring)
+            return pltpu.make_async_copy(pool_hbm.at[flat_ref[g]],
+                                         buf.at[r], sems.at[r])
 
-        @pl.when(n_live > 0)
-        def _prologue():
-            copy(0, 0).start()
+        for g in range(ring - 2):                  # the call's one prologue
+            @pl.when(g < total)
+            def _start():
+                copy(g).start()
 
-        def body(j, carry):
-            slot = jax.lax.rem(j, 2)
+        # token of each pool lane of a row: 2 row + (1 on an odd-token lane)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (half, w2), 1)
+        odd = ((lane >= rank) & (lane < 2 * rank)) | (lane >= 2 * rank + rope)
+        tok = 2 * jax.lax.broadcasted_iota(jnp.int32, (half, w2), 0) + \
+            jnp.where(odd, 1, 0)
+        kr_lane = jax.lax.broadcasted_iota(jnp.int32, (half, 2 * rope), 1)
+        col = jax.lax.broadcasted_iota(jnp.int32, (H, bs), 1)
+        col_tok = jnp.where(col < half, 2 * col, 2 * (col - half) + 1)
 
-            @pl.when(j + 1 < n_live)
-            def _prefetch():
-                copy(jax.lax.rem(j + 1, 2), j + 1).start()
+        def latent(r):                                 # [bs, rank]
+            return jnp.concatenate(
+                [buf[r, :, :rank], buf[r, :, rank:2 * rank]], axis=0)
 
-            copy(slot, j).wait()
-            blk = buf[slot].astype(jnp.float32)            # [bs/2, 2W]
-            kr = blk[:, 2 * rank:]                         # [bs/2, 2 rope]
-            for odd in (0, 1):
-                acc, m_prev, l_prev = carry
-                # a dead token's weight is exactly 0, but its row may be NaN
-                row = j * bs + odd + 2 * jax.lax.broadcasted_iota(
-                    jnp.int32, (half, 1), 0)
-                c = jnp.where(row < L, blk[:, odd * rank:(odd + 1) * rank],
-                              0.0)
-                dims = (((1,), (1,)), ((), ()))
-                s = (jax.lax.dot_general(ql, c, dims,
-                                         preferred_element_type=jnp.float32)
-                     + jax.lax.dot_general(qr[odd],
-                                           jnp.where(row < L, kr, 0.0), dims,
-                                           preferred_element_type=jnp.float32))
-                pos = j * bs + odd + 2 * jax.lax.broadcasted_iota(
-                    jnp.int32, (H, half), 1)
-                s = jnp.where(pos < L, s * sm_scale, NEG_INF)
+        def one_slot(b, _):
+            L = len_ref[b]
+            n_live = jnp.minimum((L + bs - 1) // bs, maxb)
+            first = ends_ref[b] - n_live
+            ql, qr = ql_ref[b], qr_ref[b]          # [H, rank], [H, 2 rope]
+
+            def arrive(j):
+                """Wait for the slot's block j and start the ring's next item
+                (into the buffer of block j - 2, already used)."""
+                g = first + j
+
+                @pl.when(g + ring - 2 < total)
+                def _prefetch():
+                    copy(g + ring - 2).start()
+
+                copy(g).wait()
+
+                # the slot's last block: rows past the length are pool trash,
+                # possibly NaN, and 0 * NaN = NaN in the value product
+                @pl.when((j + 1) * bs > L)
+                def _mask():
+                    r = jax.lax.rem(g, ring)
+                    buf[r] = jnp.where(j * bs + tok < L, buf[r], 0)
+
+            def scores(j):                             # raw, [H, bs]
+                r = jax.lax.rem(first + j, ring)
+                kr = buf[r, :, 2 * rank:]                     # [bs/2, 2 rope]
+                k2 = jnp.concatenate([jnp.where(kr_lane < rope, kr, 0),
+                                      jnp.where(kr_lane < rope, 0, kr)],
+                                     axis=0)                  # [bs, 2 rope]
+                return dot(ql, latent(r), rows_t) + dot(qr, k2, rows_t)
+
+            def block(j, state):
+                # block j + 1's scores are computed beside block j's softmax
+                # and value product, in one stretch of straight code, so the
+                # two chains of MXU and cross-lane latency overlap.  After
+                # the slot's last block they read a buffer nobody waited for,
+                # and are dropped
+                s, acc, m_prev, l_prev = state
+
+                @pl.when(j + 1 < n_live)
+                def _next():
+                    arrive(j + 1)
+
+                s_next = scores(j + 1)
+                s = jnp.where(j * bs + col_tok < L, s * sm_scale, NEG_INF)
                 m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
                 p = jnp.exp(s - m_new[:, None])
                 alpha = jnp.exp(m_prev - m_new)
-                carry = (acc * alpha[:, None] + jax.lax.dot_general(
-                    p, c, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32),
+                c = latent(jax.lax.rem(first + j, ring))
+                return (s_next, acc * alpha[:, None] + dot(
+                    p.astype(c.dtype), c, (((1,), (0,)), ((), ()))),
                     m_new, alpha * l_prev + jnp.sum(p, axis=1))
-            return carry
 
-        acc, _, l = jax.lax.fori_loop(
-            0, n_live, body,
-            (jnp.zeros((H, rank), jnp.float32),
-             jnp.full((H,), NEG_INF, jnp.float32),
-             jnp.zeros((H,), jnp.float32)))
-        o_ref[0] = (acc / jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
+            @pl.when(n_live > 0)
+            def _first():
+                arrive(0)
 
+            _, acc, _, l = jax.lax.fori_loop(
+                0, n_live, block,
+                (scores(0), jnp.zeros((H, rank), jnp.float32),
+                 jnp.full((H,), NEG_INF, jnp.float32),
+                 jnp.zeros((H,), jnp.float32)))
+            o_ref[b] = (acc / jnp.maximum(l, 1e-30)[:, None]).astype(
+                o_ref.dtype)
+            return 0
+
+        jax.lax.fori_loop(0, B, one_slot, 0)
+
+    whole = lambda shape: pl.BlockSpec(                          # noqa: E731
+        shape, lambda i, *_: (0,) * len(shape))
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(B,),
-            in_specs=[
-                pl.BlockSpec((1, H, rank), lambda b, *_: (b, 0, 0)),
-                pl.BlockSpec((1, 2, H, 2 * rope), lambda b, *_: (b, 0, 0, 0)),
-                pl.BlockSpec(memory_space=pl.ANY),     # the pool stays in HBM
-            ],
-            out_specs=pl.BlockSpec((1, H, rank), lambda b, *_: (b, 0, 0)),
-            scratch_shapes=[pltpu.VMEM((2, half, w2), pool.dtype),
-                            pltpu.SemaphoreType.DMA((2,))],
+            num_scalar_prefetch=3,
+            grid=(1,),
+            in_specs=[whole((B, H, rank)), whole((B, H, 2 * rope)),
+                      pl.BlockSpec(memory_space=pl.ANY)],  # pool stays in HBM
+            out_specs=whole((B, H, rank)),
+            scratch_shapes=[pltpu.VMEM((ring, half, w2), pool.dtype),
+                            pltpu.SemaphoreType.DMA((ring,))],
         ),
         out_shape=jax.ShapeDtypeStruct((B, H, rank), q_lat.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="mla_paged_decode",
-    )(block_table.astype(jnp.int32), lengths.astype(jnp.int32), q_lat,
-      q_pair, pool)
+    )(flat, ends, lengths, q_lat, q_two.astype(q_lat.dtype), pool)
+
+
+# the layers of a program call the kernel with equal shapes: one trace and
+# one Mosaic lowering serve them all, where each call would trace and lower
+# its own (paid at every start, before the compile cache is consulted)
+_shared_decode = jax.jit(_pallas_decode, static_argnums=(5,),
+                         static_argnames=("interpret", "ring"))
 
 
 def latent_decode_attention(q_lat, q_rope, pool, block_table, lengths,
@@ -266,8 +346,11 @@ def latent_decode_attention(q_lat, q_rope, pool, block_table, lengths,
     if (use_pallas() or interpret) and rank % 128 == 0 \
             and (2 * rope) % 128 == 0 and pool.shape[1] % 16 == 0:
         registry.ensure_admitted("mla_paged_decode")
-        return _pallas_decode(q_lat, q_rope, pool, block_table, lengths,
-                              sm_scale, interpret=interpret)
+        # which ring the programs took: once a call each time a program that
+        # holds it is traced (or an eager call made), never in a compiled step
+        obs.registry().counter("mla.decode_programs", ring=RING).inc()
+        return _shared_decode(q_lat, q_rope, pool, block_table, lengths,
+                              sm_scale, interpret=interpret, ring=RING)
     return _decode_reference(q_lat, q_rope, pool, block_table, lengths,
                              sm_scale)
 

@@ -77,6 +77,10 @@ name                         producer / meaning
 ``moe.grouped_mm_programs``  counter {tm}: calls of the grouped expert product
                              traced under each row tile (``kernels/
                              grouped_matmul.py``); nothing in a compiled program
+``mla.decode_programs``      counter {ring}: calls of the latent decode kernel
+                             traced with each ring of block buffers
+                             (``kernels/mla_attention.py``); nothing in a
+                             compiled program
 ``cache.counters``           span: that read; args carry the running totals
 ``cache.latent_bytes_per_token``  gauge {replica}: one latent row, a layer
 ``cache.latent_blocks_live``  gauge {replica}: blocks live slots hold

@@ -214,6 +214,59 @@ def test_latent_decode_and_token_write(one_chip, kernel_branch):
     assert write.memory_analysis().temp_size_in_bytes < 2 ** 20
 
 
+def _latent_decode_kernel(one_chip, batch, table):
+    """``mla_paged_decode``'s Mosaic payload lowered at the cell's widths,
+    with a fresh closure: ``(payload, its module as text)``."""
+    import base64
+    import re
+
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+
+    from paddle_tpu.kernels import mla_attention
+
+    shapes = (((batch, 16, 512), BF16), ((batch, 16, 64), BF16),
+              ((1536, 64, 1152), BF16), ((batch, table), I32), ((batch,), I32))
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    text = jax.jit(lambda ql, qr, p, t, n: mla_attention.
+                   latent_decode_attention(ql, qr, p, t, n, 0.07)).lower(
+                       *args).as_text()
+    (payload,) = re.findall(r'tpu_custom_call\(.*backend_config = "([^"]*)"',
+                            text)
+    body = re.search(r"body\\22: \\22([A-Za-z0-9+/=]+)\\22", payload).group(1)
+    ctx = ir.Context()
+    tpu.register_dialect(ctx)
+    ctx.allow_unregistered_dialects = True
+    with ctx:
+        module = str(ir.Module.parse(base64.b64decode(body)))
+    return payload, module
+
+
+def test_latent_decode_kernel_does_not_grow(one_chip, kernel_branch):
+    """The kernel's code is the same whatever the batch or the table's
+    width: every walk over slots and blocks is a loop in the kernel, none a
+    Python loop that the trace unrolls (an unrolled walk is paid again in
+    tracing and lowering at every start, warm compile cache or not).  Only
+    the sizes differ, so the modules are equal once numbers are masked.  The
+    payload is MLIR bytecode with the ops' source locations, whose numbers
+    take one to a few bytes by their value and whose equal numbers and
+    locations are stored once, so its length moves by a percent or two
+    between sizes; one more unrolled block or slot would add a kilobyte."""
+    import re
+
+    def masked(module):
+        return re.sub(r"\d+", "0", module)
+
+    # the payload holds the caller's line: lower all from one line
+    (p37, m37), again, (p74, m74), (p64, m64) = [
+        _latent_decode_kernel(one_chip, b, t)
+        for b, t in ((32, 37), (32, 37), (32, 74), (64, 37))]
+    assert again == (p37, m37)
+    assert masked(m37) == masked(m74) == masked(m64)
+    for p in (p74, p64):
+        assert abs(len(p) - len(p37)) < len(p37) // 20
+
+
 @pytest.mark.parametrize("b,s", [(1, 256), (4, 4096)])
 def test_latent_prefill_attention(one_chip, kernel_branch, b, s):
     from paddle_tpu.kernels import mla_attention

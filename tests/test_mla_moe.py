@@ -14,6 +14,7 @@ more).  The one bfloat16 case is held to the cell's own limit in bf16 units
 in the last place, and has to fail when the experts' products are rounded to
 float8.
 """
+import functools
 import json
 import os
 import subprocess
@@ -231,6 +232,90 @@ def test_absorbed_decode_equals_expanded_attention(path):
         p /= p.sum(-1, keepdims=True)
         want = np.einsum("ht,thv->hv", p, v)
         assert np.abs(got[b] - want).max() < TOL
+
+
+# blocks of 32 tokens, tables of 6: (lengths, ring) of each case
+RING_CASES = {
+    # a slot of 5 and of 6 blocks behind a ring of 3, and of 4: slot edges
+    # crossed with a smaller ring than a slot's blocks
+    "ring_smaller_than_slots": ([150, 192, 97], 3),
+    "ring_of_4_smaller": ([150, 192, 97], 4),
+    # a ring of 8 over slots of 1-2 blocks: prefetches reach several slots on
+    "ring_larger_than_slots": ([20, 33, 64, 1, 40], 8),
+    "empty_between_and_at_both_ends": ([0, 70, 0, 0, 33, 0], 4),
+    "every_slot_empty": ([0, 0, 0], 4),
+    # a block edge, mid-block, an odd token, one token, one past an edge
+    "block_edge_mid_odd": ([64, 45, 47, 1, 65], 3),
+    "one_slot_uses_every_entry": ([192, 0, 31], 4),
+}
+
+
+@pytest.mark.parametrize("case", RING_CASES)
+def test_latent_decode_ring_against_reference(case, monkeypatch):
+    """``mla_paged_decode`` in the interpreter against ``_decode_reference``
+    in float32: the ring of block DMAs runs across slot edges and empty
+    slots, a slot's last block is cut at any token, and neither the trash
+    block nor the rows past a length are read into a result (both are NaN
+    here)."""
+    lengths, ring = RING_CASES[case]
+    monkeypatch.setattr(mla_attention, "RING", ring)
+    rng = np.random.default_rng(len(lengths) * 7 + ring)
+    B, H, rank, rope, bs, maxb = len(lengths), 4, 128, 64, 32, 6
+    lengths = np.asarray(lengths, np.int32)
+    n_live = -(-lengths // bs)
+    nb = 1 + int(n_live.sum())
+    tbl = np.zeros((B, maxb), np.int32)
+    ids = rng.permutation(np.arange(1, nb))           # blocks in any order
+    for b, at in enumerate(np.cumsum(n_live) - n_live):
+        tbl[b, :n_live[b]] = ids[at:at + n_live[b]]
+    pool = rng.normal(size=(nb, bs // 2, 2 * (rank + rope))).astype(
+        np.float32)
+    pool[0] = np.nan                                   # the trash block
+    for b in range(B):
+        if lengths[b] % bs:                            # past the length
+            blk = pool[tbl[b, n_live[b] - 1]]
+            for t in range(lengths[b] % bs, bs):
+                r, odd = t // 2, t % 2
+                blk[r, odd * rank:(odd + 1) * rank] = np.nan
+                blk[r, 2 * rank + odd * rope:2 * rank + (odd + 1) * rope] = \
+                    np.nan
+    q_lat = jnp.asarray(rng.normal(size=(B, H, rank)).astype(np.float32))
+    q_rope = jnp.asarray(rng.normal(size=(B, H, rope)).astype(np.float32))
+    args = (q_lat, q_rope, jnp.asarray(pool), jnp.asarray(tbl),
+            jnp.asarray(lengths), 0.07)
+    with jax.default_matmul_precision("highest"):
+        got = np.asarray(mla_attention.latent_decode_attention(
+            *args, interpret=True))
+        want = np.asarray(mla_attention._decode_reference(*args))
+    assert np.isfinite(got).all()
+    assert np.all(got[lengths == 0] == 0)
+    assert np.abs(got - want).max() < TOL
+
+
+def test_latent_decode_programs_counted_by_ring(monkeypatch):
+    """``mla.decode_programs{ring}`` counts one a traced call on the
+    kernel's path, none a compiled one and none on XLA's path."""
+    def count(ring):
+        return obs.registry().counter("mla.decode_programs", ring=ring).value
+
+    args = (jnp.zeros((2, 4, 128)), jnp.zeros((2, 4, 64)),
+            jnp.zeros((3, 16, 384)), jnp.asarray([[1, 2], [0, 0]]),
+            jnp.asarray([40, 0]), 0.07)
+    before = count(mla_attention.RING)
+    attend = jax.jit(functools.partial(
+        mla_attention.latent_decode_attention, interpret=True),
+        static_argnums=5)
+    attend(*args)
+    attend(*args)
+    mla_attention.latent_decode_attention(*args)      # XLA's path: no ring
+    assert count(mla_attention.RING) - before == 1
+    monkeypatch.setattr(mla_attention, "RING", 6)
+    before = count(6)
+    jax.jit(functools.partial(mla_attention.latent_decode_attention,
+                              interpret=True), static_argnums=5).lower(*args)
+    assert count(6) - before == 1
+    assert obs.registry().snapshot()["mla.decode_programs{ring=6}"][
+        "labels"] == {"ring": 6}
 
 
 def test_token_chunk_and_prefill_writes_agree():

@@ -25,7 +25,11 @@ group's positions up to itself, the *tail*, always.
   latent), a (query block, key block) step masked to each query's
   selection and tail.  The work is that of dense causal attention; the mask
   is what makes it sparse, so the kernel's roofline share of the selected
-  pairs reads what block skipping would save.
+  pairs reads what block skipping would save.  A step is 512 queries of 8
+  heads against 512 keys: each key and value block fetched serves 512
+  queries (512 FLOP a byte, over the chip's ridge), so the step waits on
+  the MXU and not on its DMAs.  A query block wholly past the prompt
+  fetches nothing.
 - ``sparse_decode_attention`` (kernel ``dsa_sparse_decode``): absorbed
   attention (``q_lat = q_nope W_k^T``, the output in latent space) of one
   token a slot over a list of 4-token groups a slot, scalar-prefetched: the
@@ -41,7 +45,8 @@ its token's key into its group's row (the first token of a group sets it),
 so a row is the mean once its group is complete.
 
 Each kernel has an XLA reference of the same signature (CPU path, oracle).
-``dsa.programs{kernel}`` (``paddle_tpu.obs``) counts the calls traced.
+``dsa.programs{kernel}`` (``paddle_tpu.obs``) counts the calls traced;
+the prefill attention's also carry the query tile it took, ``bq``.
 """
 
 from __future__ import annotations
@@ -66,8 +71,8 @@ def _flag_interpret() -> bool:
     return bool(flags.get_flag("pallas_interpret"))
 
 
-def _count(kernel: str) -> None:
-    obs.registry().counter("dsa.programs", kernel=kernel).inc()
+def _count(kernel: str, **labels) -> None:
+    obs.registry().counter("dsa.programs", kernel=kernel, **labels).inc()
 
 
 def _pallas_ok(interpret: bool) -> bool:
@@ -307,7 +312,7 @@ def _prefill_reference(q, k, v, chosen, n_valid, sm_scale):
 
 
 def _pallas_prefill(q, k, v, chosen, n_valid, sm_scale, interpret=False,
-                    bq=128, bk=512, hb=8):
+                    bq=512, bk=512, hb=8):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -315,6 +320,7 @@ def _pallas_prefill(q, k, v, chosen, n_valid, sm_scale, interpret=False,
     dv = v.shape[-1]
     bq, bk, hb = min(bq, L), min(bk, L), min(hb, H)
     n_q, n_k = L // bq, L // bk
+    _count("dsa_prefill_attn", bq=bq)
     bg = bk // POOL
     dot = functools.partial(jax.lax.dot_general,
                             precision=jax.lax.Precision.DEFAULT,
@@ -365,15 +371,26 @@ def _pallas_prefill(q, k, v, chosen, n_valid, sm_scale, interpret=False,
                 o_ref[h] = jnp.where(live, acc_ref[h] / l[:, None],
                                      0.0).astype(o_ref.dtype)
 
-    def q_idx(g, i, j, nv):
+    def blocks(i, j, nv):
+        """The (query block, key block) a step reads.  A repeated index
+        elides the fetch: past the diagonal a step reads its row's last
+        block, and a query block wholly past the prompt the last block its
+        last live row read."""
+        row = jnp.minimum(i, jnp.maximum(nv[0] - 1, 0) // bq)
+        return row, jnp.where(i > row, last_block(row),
+                              jnp.minimum(j, last_block(row)))
+
+    def o_idx(g, i, j, nv):
         return (g, i, 0)
 
+    def q_idx(g, i, j, nv):
+        return (g, blocks(i, j, nv)[0], 0)
+
     def kv_idx(g, i, j, nv):
-        # a repeated index elides the fetch of a block past the diagonal
-        return (g, jnp.minimum(j, last_block(i)), 0)
+        return (g, blocks(i, j, nv)[1], 0)
 
     def c_idx(g, i, j, nv):
-        return (i, jnp.minimum(j, last_block(i)))
+        return blocks(i, j, nv)
 
     return pl.pallas_call(
         kernel,
@@ -384,7 +401,7 @@ def _pallas_prefill(q, k, v, chosen, n_valid, sm_scale, interpret=False,
                       pl.BlockSpec((hb, bk, d), kv_idx),
                       pl.BlockSpec((hb, bk, dv), kv_idx),
                       pl.BlockSpec((bq, bg), c_idx)],
-            out_specs=pl.BlockSpec((hb, bq, dv), q_idx),
+            out_specs=pl.BlockSpec((hb, bq, dv), o_idx),
             scratch_shapes=[pltpu.VMEM((hb, bq, dv), F32),
                             pltpu.VMEM((hb, bq, 128), F32),
                             pltpu.VMEM((hb, bq, 128), F32)]),
@@ -410,7 +427,6 @@ def sparse_prefill_attention(q, k, v, chosen, n_valid, sm_scale,
     H, L, d = q.shape
     if _pallas_ok(interpret) and L % 512 == 0 and d % 128 == 0 \
             and H % min(8, H) == 0:
-        _count("dsa_prefill_attn")
         return _pallas_prefill(q, k, v, chosen, n_valid, sm_scale,
                                interpret=interpret)
     return _prefill_reference(q, k, v, chosen, n_valid, sm_scale)

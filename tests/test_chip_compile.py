@@ -430,6 +430,11 @@ def _kda_dsa_kernels():
             lambda q, k, v, c, n: dsa.sparse_prefill_attention(
                 q, k, v, c, n, 0.0625),
             [((8, L, 256), BF16)] * 3 + [((L, G), BF16), ((), I32)]),
+        "dsa_prefill_attn_32k": (
+            lambda q, k, v, c, n: dsa.sparse_prefill_attention(
+                q, k, v, c, n, 0.0625),
+            [((8, 32768, 256), BF16)] * 3 + [((32768, 8192), BF16),
+                                             ((), I32)]),
         "dsa_sparse_decode": (
             lambda q, p, t, n, g, c: dsa.sparse_decode_attention(
                 q, p, t, n, g, c, 0.0625),
@@ -439,16 +444,18 @@ def _kda_dsa_kernels():
 
 
 @pytest.mark.parametrize("name", ["kda_decode", "kda_prefill", "dsa_select",
-                                  "dsa_prefill_attn", "dsa_sparse_decode"])
+                                  "dsa_prefill_attn", "dsa_prefill_attn_32k",
+                                  "dsa_sparse_decode"])
 def test_kda_dsa_kernels(one_chip, kernel_branch, name):
     """The KDA and DSA kernels at the GLM-5.3-Flash cell's widths (64 heads
     of 128 and a float32 state; 64 heads of 256 over a 512-wide latent, an
-    indexer of 32 x 128, 513 groups a slot), a 1,024-token prompt: what the
+    indexer of 32 x 128, 513 groups a slot), a 1,024-token prompt, and the
+    DSA prefill attention at the cell's largest bucket, 32,768: what the
     chip's compiler refuses (a DMA of 2 rows of a bf16 array, a lane-padded
-    operand) it refuses here."""
+    operand, a tile past the fast memory) it refuses here."""
     fn, shapes = _kda_dsa_kernels()[name]
     compiled = _compile(one_chip, fn, *shapes)
-    assert name in compiled.as_text()
+    assert name.removesuffix("_32k") in compiled.as_text()
 
 
 def test_ssd_scan(one_chip):

@@ -17,6 +17,8 @@ float32 error of a few layers computed in another order (the reference runs
 the recurrence token by token, the program's prefill in chunks);
 kernel-level checks are tighter where the arithmetic is the same.
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -182,21 +184,60 @@ def test_selection_matches_the_reference_models(interpret_kernels):
                        .mean(1), atol=1e-6)
 
 
-def test_prefill_attention_kernel_against_its_reference(interpret_kernels):
-    """``dsa_prefill_attn`` over a prompt of 437 of 512 positions, each
-    query limited to its top 16 groups and tail, against the XLA mask."""
-    rng = np.random.default_rng(1)
-    L, H, d, n = 512, 8, 128, 437
+def _prefill_case(L, n, H=8, d=128, top=16, seed=1):
+    rng = np.random.default_rng(seed)
     chosen = dsa._select_reference(
         jnp.asarray(rng.normal(size=(L, 2, 128)), jnp.float32),
         jnp.asarray(rng.normal(size=(L, 2)), jnp.float32),
         dsa.pool_keys(jnp.asarray(rng.normal(size=(L, 128)), jnp.float32),
-                      n), n, 16)
+                      n), n, top)
     q, k, v = (jnp.asarray(rng.normal(size=(H, L, d)), jnp.float32)
                for _ in range(3))
+    return q, k, v, chosen
+
+
+@pytest.mark.parametrize("L,n", [
+    (512, 437),       # one query and one key block, the prompt ending inside
+    (2048, 1337),     # four of each; the last query block wholly past it
+    (2048, 2048),     # four of each, every block live
+])
+def test_prefill_attention_kernel_against_its_reference(L, n,
+                                                        interpret_kernels):
+    """``dsa_prefill_attn`` over a prompt of ``n`` of ``L`` positions, each
+    query limited to its top 16 groups and tail, against the XLA mask; and
+    its 512-query blocks against 128-query blocks, which add up each row's
+    key blocks in the same order: the rows agree to float32's rounding."""
+    q, k, v, chosen = _prefill_case(L, n)
     want = dsa._prefill_reference(q, k, v, chosen, n, 0.1)
     got = dsa.sparse_prefill_attention(q, k, v, chosen, n, 0.1)
     assert float(jnp.abs(got[:, :n] - want[:, :n]).max()) < 1e-5
+    narrow = dsa._pallas_prefill(q, k, v, chosen, n, 0.1, interpret=True,
+                                 bq=128)
+    assert float(jnp.abs(got[:, :n] - narrow[:, :n]).max()) < 1e-6
+    assert not np.asarray(got[:, -(-n // 512) * 512:]).any()
+
+
+def test_prefill_attention_programs_name_their_query_tile():
+    """``dsa.programs{kernel=dsa_prefill_attn}`` carries the query tile a
+    traced program took: 512 rows from the wrapper, what a caller of the
+    kernel asks for otherwise; one count a trace, none on XLA's path."""
+    def count(bq):
+        return obs.registry().counter("dsa.programs", kernel="dsa_prefill_attn",
+                                      bq=bq).value
+
+    q, k, v, chosen = _prefill_case(512, 300, H=2)
+    before = count(512), count(128)
+    attend = jax.jit(functools.partial(dsa.sparse_prefill_attention,
+                                       interpret=True), static_argnums=5)
+    attend.lower(q, k, v, chosen, 300, 0.1)
+    jax.jit(functools.partial(dsa._pallas_prefill, interpret=True, bq=128),
+            static_argnums=5).lower(q, k, v, chosen, 300, 0.1)
+    dsa.sparse_prefill_attention(q[:, :256], k[:, :256], v[:, :256],
+                                 chosen[:256, :64], 200, 0.1)   # XLA's path
+    assert (count(512), count(128)) == (before[0] + 1, before[1] + 1)
+    assert obs.registry().snapshot()[
+        "dsa.programs{bq=512,kernel=dsa_prefill_attn}"]["labels"] == {
+            "kernel": "dsa_prefill_attn", "bq": 512}
 
 
 @pytest.mark.parametrize("path", ["xla", "pallas_interpret"])
